@@ -23,16 +23,21 @@ __all__ = ["iter_eqns", "live_eqn_mask", "dead_eqns",
            "verify_stage_chain", "verify_stage_assignment"]
 
 # jax primitives that are cross-device collectives: a rank that reaches one
-# of these blocks until every peer on the axis reaches the SAME one.
-# psum2 is shard_map's check_rep rewrite of psum (same wire op); its
-# companion pbroadcast is a replication-accounting marker that lowers to
-# nothing, so it is deliberately NOT a collective here — otherwise the
-# same program would sign differently under check_rep=True vs False.
+# of these blocks until every peer on the axis reaches the SAME one. The
+# names are the installed jax's (`pmean` traces as psum + div, and
+# `psum_scatter` as reduce_scatter). Under `check_vma=True` shard_map
+# emits psum_invariant / all_gather_invariant for the same wire ops; its
+# companions pvary / pbroadcast are replication-accounting markers that
+# lower to nothing, so they are deliberately NOT collectives here —
+# otherwise the same program would sign differently under check_vma=True
+# vs False.
 COLLECTIVE_PRIMS = {
-    "psum", "psum2", "pmax", "pmin", "pmean", "ppermute",
-    "all_gather", "all_to_all", "psum_scatter", "reduce_scatter", "pgather",
+    "psum", "psum_invariant", "pmax", "pmin", "ppermute",
+    "all_gather", "all_gather_invariant", "all_to_all", "ragged_all_to_all",
+    "reduce_scatter", "pgather",
 }
-_CANONICAL_OP = {"psum2": "psum"}
+_CANONICAL_OP = {"psum_invariant": "psum",
+                 "all_gather_invariant": "all_gather"}
 
 # primitives that re-enter the host from inside the compiled program
 HOST_CALLBACK_PRIMS = {

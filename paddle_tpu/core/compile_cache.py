@@ -47,7 +47,7 @@ from . import flags as _flags
 __all__ = [
     "enabled", "cache_dir", "cache_key", "topology_fingerprint",
     "lookup", "store", "entries", "gc", "verify", "stats", "reset_stats",
-    "warm_start_report",
+    "warm_start_report", "place_jax_cache",
 ]
 
 _SCHEMA = 1
@@ -68,26 +68,38 @@ export_skips: int = 0   # programs the export path cannot serialize
 def _on_dir(value) -> None:
     global _DIR
     _DIR = str(value or "")
-    _wire_native_cache(_DIR)
+    if _DIR:
+        place_jax_cache()
 
 
-def _wire_native_cache(dirname: str) -> None:
-    """Best-effort: also point jax's own persistent compilation cache at
-    the same directory so the StableHLO→binary stage is cross-process
-    cached too (on TPU that is the dominant cost; the export blob alone
-    removes the trace). Clearing the flag UNWIRES it — a stale cache dir
-    must not keep adding write traffic to every later compile."""
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(dirname, "xla") if dirname else None)
-    except Exception:
-        pass
+# JAX's own persistent compilation cache (the StableHLO→binary stage; on
+# TPU the dominant cost — the export blob alone removes only the trace).
+# Its directory is part of the cache key, so it must not move: one fixed,
+# git-ignored path inside the checkout, unless the environment places it.
+JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+JAX_CACHE_DEFAULT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def place_jax_cache() -> str:
+    """Turn JAX's persistent compilation cache on and return its
+    directory — the ONE rule `chip_smoke.py`, `bench.py` and this package
+    share. Where `JAX_COMPILATION_CACHE_DIR` is set the cache lives there
+    and no other is ever set; otherwise at `JAX_CACHE_DEFAULT`. Nothing
+    unsets it: `FLAGS_compile_cache_dir` places the export-blob store
+    above and only switches this on."""
+    import jax
+    want = os.environ.get(JAX_CACHE_ENV) or JAX_CACHE_DEFAULT
+    # with the variable set before `import jax` this is already true
+    if jax.config.jax_compilation_cache_dir != want:
+        jax.config.update("jax_compilation_cache_dir", want)
+    return want
 
 
 _flags.watch_flag("compile_cache_dir", _on_dir)
 if _DIR:
-    _wire_native_cache(_DIR)
+    place_jax_cache()
 
 
 def enabled() -> bool:

@@ -261,10 +261,8 @@ def acquire(kind: str, jitted, args: Iterable[Any], donate: Tuple[int, ...] = ()
     next one (source `"fresh"`). Every failure path degrades to the
     fresh jitted callable — the cache can only ever save work.
 
-    NOTE: programs whose avals the export path cannot serialize (typed
-    PRNG keys, closures over opaque out-trees) count `export_skips`;
-    regimes that want cache coverage pass raw-key-data adapter programs
-    when `compile_cache.enabled()` (see TrainStep._build)."""
+    NOTE: programs the export path cannot serialize (host callbacks,
+    closures over opaque out-trees) count `export_skips`."""
     if not _cc._DIR:
         return jitted, "fresh"
     import jax

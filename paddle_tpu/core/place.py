@@ -32,10 +32,17 @@ class Place:
         return f"Place({self.device_type}:{self.device_id})"
 
     def jax_device(self):
-        devs = [d for d in jax.devices() if d.platform == self._jax_platform()]
-        if not devs:
-            # fall back to whatever the default backend exposes (CI without TPU)
-            devs = jax.devices()
+        """The `jax.Device` this place names. A platform with no device in
+        this process raises: `set_device('tpu')` on a host without a TPU
+        is an error, never the CPU under another name. (`CPUPlace` on a
+        TPU host asks for the CPU backend by name, so it still gets one.)"""
+        platform = self._jax_platform()
+        try:
+            devs = jax.devices(platform)
+        except RuntimeError as e:
+            raise RuntimeError(
+                f"{self!r} names a {platform} device and this process has "
+                f"none (default backend: {jax.default_backend()})") from e
         return devs[self.device_id % len(devs)]
 
     def _jax_platform(self):
@@ -55,10 +62,7 @@ class CUDAPlace(Place):  # accepted for API compat; maps to gpu backend if prese
 
 
 def _default_place() -> Place:
-    try:
-        plat = jax.default_backend()
-    except Exception:  # pragma: no cover
-        plat = "cpu"
+    plat = jax.default_backend()
     if plat == "tpu":
         return TPUPlace(0)
     if plat == "gpu":
@@ -87,8 +91,9 @@ def set_device(device) -> Place:
             place = CPUPlace(idx)
         else:
             raise ValueError(f"unknown device {device!r}")
+    device = place.jax_device()     # raises before anything is selected
     _CURRENT_PLACE[0] = place
-    jax.config.update("jax_default_device", place.jax_device())
+    jax.config.update("jax_default_device", device)
     return place
 
 

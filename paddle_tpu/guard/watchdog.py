@@ -1,6 +1,6 @@
 """Step watchdog — converts a hung step into a typed StepStalledError.
 
-A hung XLA dispatch (wedged collective, dead tunnel, stuck host callback)
+A hung XLA dispatch (wedged collective, lost device, stuck host callback)
 blocks the calling thread in C and cannot be interrupted in place, so the
 watchdog runs each step on a dedicated runner thread and bounds the wait on
 the caller side: when the deadline expires the caller gets a

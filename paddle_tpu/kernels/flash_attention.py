@@ -44,6 +44,51 @@ _LOG2E = 1.4426950408889634
 _LN2 = 0.6931471805599453
 
 
+# Every kernel here keeps whole [S, D] streams resident in VMEM (K/V in the
+# forward and dq pass, Q/dO in the dk/dv and fused passes), so what a call
+# needs grows with S while Mosaic's scoped-VMEM default stays 16 MiB. Each
+# call therefore tells the compiler what it keeps resident, counted the way
+# the chip lays it out: the minor dim padded to 128 lanes (d=64 occupies
+# what d=128 does), the second-minor to the dtype's sublane tile, and every
+# pipelined block twice (the pipeline double-buffers). The request is twice
+# that: the program jax.grad composes around a call needed up to 1.3x what
+# the call needs alone at the geometries the dispatcher admits (compiles for
+# a described v5e, PR 22; 1.75x for the fused backward at s16384, which
+# `_bwd_path` keeps out). A v5e core has 128 MiB of VMEM; the cap leaves the
+# rest to XLA's fusions.
+_VMEM_DEFAULT = 16 * 2 ** 20
+_VMEM_MAX = 96 * 2 ** 20
+
+
+def _padded_bytes(shape, dtype):
+    """Bytes a VMEM buffer of `shape` occupies under (sublane, 128) tiling."""
+    itemsize = jnp.dtype(dtype).itemsize
+    *lead, rows, cols = (1, 1) + tuple(shape)
+    sublane = 8 * max(1, 4 // itemsize)
+    return (math.prod(lead) * -(-rows // sublane) * sublane
+            * -(-cols // 128) * 128 * itemsize)
+
+
+def _pallas(kernel, args, *, grid, in_specs, out_specs, out_shape, temps,
+            interpret, scratch_shapes=()):
+    """`pl.pallas_call` with the VMEM limit derived from its own block
+    specs. `temps` is the bytes of tile intermediates live in the kernel
+    body (scores, probabilities, accumulators)."""
+    outs = out_shape if isinstance(out_shape, tuple) else (out_shape,)
+    ospecs = out_specs if isinstance(out_specs, tuple) else (out_specs,)
+    resident = temps + sum(_padded_bytes(sc.shape, sc.dtype)
+                           for sc in scratch_shapes)
+    for spec, x in (*zip(in_specs, args), *zip(ospecs, outs)):
+        if spec.block_shape is not None:       # SMEM scalars hold no VMEM
+            resident += 2 * _padded_bytes(spec.block_shape, x.dtype)
+    params = pltpu.CompilerParams(
+        vmem_limit_bytes=max(_VMEM_DEFAULT, min(2 * resident, _VMEM_MAX)))
+    return pl.pallas_call(kernel, out_shape=out_shape, grid=grid,
+                          in_specs=in_specs, out_specs=out_specs,
+                          scratch_shapes=scratch_shapes,
+                          compiler_params=params, interpret=interpret)(*args)
+
+
 # Loop structure shared by every kernel here: the k-block (or q-block)
 # loop runs in groups of `unroll` tiles per fori_loop iteration. With one
 # tile per iteration the carry (m/l/acc or dq) serializes each tile's MXU
@@ -151,6 +196,12 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k,
 
 
 
+# live bytes per score-tile element: forward s + p in f32; backward
+# s/p/dp in f32 + ds in the input dtype
+_FWD_TILE_BYTES = 8
+_BWD_TILE_BYTES = 14
+
+
 def _pick_unroll(n_blocks, tile_bytes, cap=4 * 2 ** 20):
     """Largest U in {4, 2, 1} dividing n_blocks whose unrolled live tile
     intermediates (~tile_bytes each) stay within a VMEM stack budget."""
@@ -183,15 +234,16 @@ def _flash_fwd_bhsd(q, k, v, *, causal, block_q, block_k, interpret):
     block_q = min(block_q, s)
     block_k = min(block_k, s)
     scale = 1.0 / math.sqrt(d)
-    G = _pick_heads(bh, s, d, q.dtype.itemsize, 8 * block_q * block_k)
+    tile = _FWD_TILE_BYTES * block_q * block_k
+    G = _pick_heads(bh, s, d, q.dtype.itemsize, tile)
     # measured d64/s8192: U=2 beats U=1 (~+6%) and U=4 (VMEM pressure)
-    unroll = _pick_unroll(s // block_k, G * 8 * block_q * block_k)
+    unroll = _pick_unroll(s // block_k, G * tile)
     kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
                                block_k=block_k, seq_len=s, unroll=unroll,
                                heads=G, local_softmax=d >= 128)
     grid = (bh // G, s // block_q)
-    return pl.pallas_call(
-        kernel,
+    return _pallas(
+        kernel, (q, k, v),
         out_shape=(jax.ShapeDtypeStruct((bh, s, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, s), jnp.float32)),
         grid=grid,
@@ -202,8 +254,10 @@ def _flash_fwd_bhsd(q, k, v, *, causal, block_q, block_k, interpret):
         ],
         out_specs=(pl.BlockSpec((G, block_q, d), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((G, 1, block_q), lambda b, i: (b, 0, i))),
+        # per head: U unrolled score/probability tiles + the f32 acc carry
+        temps=G * (unroll * tile + _padded_bytes((block_q, d), jnp.float32)),
         interpret=interpret,
-    )(q, k, v)
+    )
 
 
 def _delta(g, o):
@@ -343,15 +397,15 @@ def _flash_bwd_bhsd(q, k, v, o, lse, g, *, causal, block_q, block_k,
     delta = _delta(g, o)                 # [BH, 1, S], matches lse layout
 
     full = lambda b, i: (b, 0, 0)  # noqa: E731
-    # bwd tile live set: s/p/dp f32 + ds bf16 per unrolled tile
-    unroll_q = _pick_unroll(s // block_k, 14 * block_q * block_k,
-                            cap=8 * 2 ** 20)
-    unroll_kv = _pick_unroll(s // block_q, 14 * block_q * block_k,
-                             cap=8 * 2 ** 20)
+    tile = _BWD_TILE_BYTES * block_q * block_k
+    unroll_q = _pick_unroll(s // block_k, tile, cap=8 * 2 ** 20)
+    unroll_kv = _pick_unroll(s // block_q, tile, cap=8 * 2 ** 20)
+    acc = _padded_bytes((max(block_q, block_k), d), jnp.float32)
 
-    dq = pl.pallas_call(
+    dq = _pallas(
         functools.partial(_fa_bwd_dq_kernel, scale=scale, causal=causal,
                           block_k=block_k, seq_len=s, unroll=unroll_q),
+        (q, k, v, g, lse, delta),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         grid=(bh, s // block_q),
         in_specs=[
@@ -363,12 +417,14 @@ def _flash_bwd_bhsd(q, k, v, o, lse, g, *, causal, block_q, block_k,
             pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+        temps=unroll_q * tile + acc,
         interpret=interpret,
-    )(q, k, v, g, lse, delta)
+    )
 
-    dk, dv = pl.pallas_call(
+    dk, dv = _pallas(
         functools.partial(_fa_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, seq_len=s, unroll=unroll_kv),
+        (q, k, v, g, lse, delta),
         out_shape=(jax.ShapeDtypeStruct((bh, s, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, s, d), v.dtype)),
         grid=(bh, s // block_k),
@@ -382,8 +438,9 @@ def _flash_bwd_bhsd(q, k, v, o, lse, g, *, causal, block_q, block_k,
         ],
         out_specs=(pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0))),
+        temps=unroll_kv * tile + 2 * acc,
         interpret=interpret,
-    )(q, k, v, g, lse, delta)
+    )
     return dq, dk, dv
 
 
@@ -416,35 +473,63 @@ def _flash_core_fwd(q, k, v, causal, block_q, block_k, interpret):
     return out, (q, k, v, out, lse)
 
 
+def _bwd_path(s, d, dtype, block_q, block_k):
+    """Which backward `_flash_core_bwd` takes at this geometry: "fused"
+    or "two_pass". The fused single-pass backward wins UNDER jax.grad
+    composition at both head dims (measured r5, steps/s under grad at
+    s8192: d64 148 fused vs 121 two-pass; d128 279 vs 238 — standalone
+    kernel timings said the opposite, but the grad-composed program
+    schedules the two-pass's three pallas calls worse), so it is the
+    default wherever its resident set fits; the two-pass covers everything
+    else (tests/test_flash_attention.py asserts grad parity between the
+    two)."""
+    # q/dO in and dq out, each double-buffered, + the f32 dq scratch, at
+    # the lane-padded width the chip stores
+    resident = 6 * _padded_bytes((s, d), dtype) \
+        + _padded_bytes((s, d), jnp.float32)
+    if s % block_q == 0 and s % block_k == 0 \
+            and resident < _FUSED_BWD_VMEM_CAP:
+        return "fused"
+    return "two_pass"
+
+
 def _flash_core_bwd(causal, block_q, block_k, interpret, res, g):
     q, k, v, o, lse = res
-    bh, s, d = q.shape
-    # The fused single-pass backward wins UNDER jax.grad composition at
-    # both head dims (measured r5, steps/s under grad at s8192: d64 148
-    # fused vs 121 two-pass; d128 279 vs 238 — standalone kernel timings
-    # said the opposite, but the grad-composed program schedules the
-    # two-pass's three pallas calls worse). Keep the fused default with
-    # its VMEM-residency guard; the two-pass covers everything else
-    # (tests/test_flash_attention.py asserts grad parity between the two).
-    vmem_est = (3 * q.dtype.itemsize + 4) * s * d + 8 * s
-    if s % block_q == 0 and s % block_k == 0 \
-            and vmem_est < _FUSED_BWD_VMEM_CAP:
-        return _flash_bwd_fused_bhsd(q, k, v, o, lse, g, causal=causal,
-                                     block_q=block_q, block_k=block_k,
-                                     interpret=interpret)
-    return _flash_bwd_bhsd(q, k, v, o, lse, g, causal=causal, block_q=block_q,
-                           block_k=block_k, interpret=interpret)
+    _, s, d = q.shape
+    bwd = _flash_bwd_fused_bhsd \
+        if _bwd_path(s, d, q.dtype, block_q, block_k) == "fused" \
+        else _flash_bwd_bhsd
+    return bwd(q, k, v, o, lse, g, causal=causal, block_q=block_q,
+               block_k=block_k, interpret=interpret)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 
-# resident streams for the fused backward: q/do/dq at [S, D] + f32 dq
-# scratch (k/v/dk/dv stream per k-block); stay inside scoped vmem with
-# headroom for fusions jax.grad composes around the custom call.
-# 12 MiB admits d128/s8192 (10.5 MiB resident, measured compiling + 0.51
-# MFU under grad); d256 long-seq falls to the streaming two-pass.
-_FUSED_BWD_VMEM_CAP = 12 * 2 ** 20
+# What the fused backward may keep resident: 20 MiB admits bf16 d<=128 at
+# s8192 and d256 at s4096 (16 MiB each; the d128 one measured 0.51 MFU
+# under grad in r5) and sends s16384, and fp32 from s8192, to the streaming
+# two-pass. The guard used to count d, not the 128 lanes d=64 is padded to,
+# and single buffers: it admitted bf16 d64/s16384 (needs 36 MiB) and
+# d256/s4096 (16.7 MiB) under a 16 MiB limit the compiler then refused.
+_FUSED_BWD_VMEM_CAP = 20 * 2 ** 20
+
+
+def dispatch_plan(s, d, dtype, block_q=DEFAULT_BLOCK_Q,
+                  block_k=DEFAULT_BLOCK_K):
+    """What `flash_attention_arrays` does at this geometry, as
+    (block_q, block_k, forward, backward): the predicate the dispatcher
+    itself runs, for callers that must report which path was taken.
+    forward is "pallas", or "reference" for a ragged length (the kernel
+    grid is s//block_q x s//block_k: seq must divide by BOTH blocks or
+    tail rows/keys would be silently dropped, so those take the fused XLA
+    reference, backward included); backward is `_bwd_path`'s answer."""
+    bq = min(block_q, max(128, 1 << (s - 1).bit_length()) if s < block_q else block_q)
+    bq = min(bq, s)
+    bk = min(block_k, s)
+    if s % bq or s % bk:
+        return bq, bk, "reference", "reference"
+    return bq, bk, "pallas", _bwd_path(s, d, dtype, bq, bk)
 
 
 def flash_attention_arrays(q, k, v, causal=False, block_q=DEFAULT_BLOCK_Q,
@@ -456,6 +541,7 @@ def flash_attention_arrays(q, k, v, causal=False, block_q=DEFAULT_BLOCK_Q,
             f"flash_attention requires q/k/v to share seq_len; got q={s}, "
             f"k={k.shape[1]}, v={v.shape[1]} (cross-length attention takes "
             "the fused path)")
+    # the CPU tests run the same kernels through the Pallas interpreter
     interpret = jax.default_backend() != "tpu"
 
     # dots require matching operand dtypes (e.g. fp32 KV cache against bf16
@@ -467,14 +553,9 @@ def flash_attention_arrays(q, k, v, causal=False, block_q=DEFAULT_BLOCK_Q,
     def to_bhsd(x):
         return jnp.swapaxes(x, 1, 2).reshape(b * h, s, d)
 
-    bq = min(block_q, max(128, 1 << (s - 1).bit_length()) if s < block_q else block_q)
-    bq = min(bq, s)
-    bk = min(block_k, s)
+    bq, bk, forward, _ = dispatch_plan(s, d, ct, block_q, block_k)
     qb, kb_, vb = to_bhsd(q), to_bhsd(k), to_bhsd(v)
-    # The kernel grid is s//bq q-blocks x s//bk k-blocks: seq must divide by
-    # BOTH chosen blocks or tail rows/keys would be silently dropped. Ragged
-    # lengths fall back to the fused XLA reference.
-    if s % bq or s % bk:
+    if forward == "reference":
         out = _reference_bhsd(qb, kb_, vb, causal)
     else:
         out = _flash_core(qb, kb_, vb, causal, bq, bk, interpret)
@@ -647,9 +728,10 @@ def ring_block_fwd(q, k, v, offs, *, causal, block_q, block_k, interpret):
     bh, sq, d = q.shape
     sk = k.shape[1]
     scale = 1.0 / math.sqrt(d)
-    return pl.pallas_call(
+    return _pallas(
         functools.partial(_fa_ring_fwd_kernel, scale=scale, causal=causal,
                           block_k=block_k, kv_len=sk),
+        (q, k, v, offs),
         out_shape=(jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32)),
         grid=(bh, sq // block_q),
@@ -661,8 +743,10 @@ def ring_block_fwd(q, k, v, offs, *, causal, block_q, block_k, interpret):
         ],
         out_specs=(pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i))),
+        temps=_FWD_TILE_BYTES * block_q * block_k
+        + _padded_bytes((block_q, d), jnp.float32),
         interpret=interpret,
-    )(q, k, v, offs)
+    )
 
 
 def ring_block_dq(q, k, v, do, lse, delta, offs, *, causal, block_q, block_k,
@@ -671,9 +755,10 @@ def ring_block_dq(q, k, v, do, lse, delta, offs, *, causal, block_q, block_k,
     sk = k.shape[1]
     scale = 1.0 / math.sqrt(d)
     full = lambda b, i: (b, 0, 0)  # noqa: E731
-    return pl.pallas_call(
+    return _pallas(
         functools.partial(_fa_ring_dq_kernel, scale=scale, causal=causal,
                           block_k=block_k, kv_len=sk),
+        (q, k, v, do, lse, delta, offs),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), jnp.float32),
         grid=(bh, sq // block_q),
         in_specs=[
@@ -686,8 +771,10 @@ def ring_block_dq(q, k, v, do, lse, delta, offs, *, causal, block_q, block_k,
             _smem_spec(),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+        temps=_BWD_TILE_BYTES * block_q * block_k
+        + _padded_bytes((block_q, d), jnp.float32),
         interpret=interpret,
-    )(q, k, v, do, lse, delta, offs)
+    )
 
 
 def ring_block_dkv(q, k, v, do, lse, delta, offs, *, causal, block_q, block_k,
@@ -696,9 +783,10 @@ def ring_block_dkv(q, k, v, do, lse, delta, offs, *, causal, block_q, block_k,
     sk = k.shape[1]
     scale = 1.0 / math.sqrt(d)
     full = lambda b, i: (b, 0, 0)  # noqa: E731
-    return pl.pallas_call(
+    return _pallas(
         functools.partial(_fa_ring_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, q_len=sq),
+        (q, k, v, do, lse, delta, offs),
         out_shape=(jax.ShapeDtypeStruct((bh, sk, d), jnp.float32),
                    jax.ShapeDtypeStruct((bh, sk, d), jnp.float32)),
         grid=(bh, sk // block_k),
@@ -713,8 +801,10 @@ def ring_block_dkv(q, k, v, do, lse, delta, offs, *, causal, block_q, block_k,
         ],
         out_specs=(pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0))),
+        temps=_BWD_TILE_BYTES * block_q * block_k
+        + 2 * _padded_bytes((block_k, d), jnp.float32),
         interpret=interpret,
-    )(q, k, v, do, lse, delta, offs)
+    )
 
 
 # ---- fused single-pass backward ---------------------------------------------
@@ -807,9 +897,10 @@ def _flash_bwd_fused_bhsd(q, k, v, o, lse, g, *, causal, block_q, block_k,
     # 16 MiB scoped budget once jax.grad composed copies into it
     delta = _delta(g, o)
     full = lambda b, i: (b, 0, 0)  # noqa: E731
-    return pl.pallas_call(
+    return _pallas(
         functools.partial(_fa_bwd_fused_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=s),
+        (q, k, v, g, lse, delta),
         out_shape=(jax.ShapeDtypeStruct((bh, s, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, s, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, s, d), v.dtype)),
@@ -826,5 +917,7 @@ def _flash_bwd_fused_bhsd(q, k, v, o, lse, g, *, causal, block_q, block_k,
                    pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0))),
         scratch_shapes=[pltpu.VMEM((s, d), jnp.float32)],
+        temps=_BWD_TILE_BYTES * block_q * block_k
+        + 2 * _padded_bytes((block_k, d), jnp.float32),
         interpret=interpret,
-    )(q, k, v, g, lse, delta)
+    )

@@ -172,8 +172,8 @@ class ErnieSelfAttention(nn.Layer):
             else:
                 kr, vr = kc, vc
             # mirror scaled_dot_product_attention's fused path exactly
-            # (same einsums/precision/mask value) so cached decode is
-            # bit-identical to the full-sequence forward
+            # (same einsums/precision/mask value) so cached decode agrees
+            # with the full-sequence forward to float32 rounding
             qh = jnp.swapaxes(qa, 1, 2)
             kh = jnp.swapaxes(kr, 1, 2)
             vh = jnp.swapaxes(vr, 1, 2)

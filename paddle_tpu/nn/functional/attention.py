@@ -29,7 +29,6 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
 
     seq_len = q.shape[1]
     head_dim = q.shape[-1]
-    use_flash = False
     # measured crossover on v5e (fwd+bwd): with bf16 inputs the native-dtype
     # MXU dots win from 1k up (2.2x at 1k, 2.7x at 2k, 5.7x at 8k); fp32
     # inputs keep the old 4k crossover (fp32 MXU dots were only at parity
@@ -39,14 +38,10 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
     # flash boundary), where the old 4k crossover still applies
     _promoted = jnp.result_type(q._value.dtype, k._value.dtype, v._value.dtype)
     _flash_min_seq = 1024 if _promoted == jnp.bfloat16 else 4096
-    if mask_arr is None and dropout_p == 0.0 and seq_len >= _flash_min_seq \
-            and k.shape[1] == seq_len and v.shape[1] == seq_len \
-            and head_dim in (64, 128, 256):
-        try:
-            import jax as _j
-            use_flash = any(d.platform == "tpu" for d in _j.devices())
-        except Exception:
-            use_flash = False
+    use_flash = mask_arr is None and dropout_p == 0.0 \
+        and seq_len >= _flash_min_seq \
+        and k.shape[1] == seq_len and v.shape[1] == seq_len \
+        and head_dim in (64, 128, 256) and jax.default_backend() == "tpu"
     if use_flash:
         from ...kernels.flash_attention import flash_attention
         return flash_attention(q, k, v, causal=is_causal)

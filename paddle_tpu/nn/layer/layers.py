@@ -274,8 +274,8 @@ class Layer:
     # static program (jit.to_static machinery) — the repeated per-op tape
     # becomes ONE fwd executable + ONE vjp executable. This is the eager
     # hot loop's answer to the reference's dygraph program-desc caching
-    # (imperative/tracer.cc:172): on a remote/tunnel device the per-op
-    # RTTs dominate eager stepping, and capture removes all but one.
+    # (imperative/tracer.cc:172): per-op host dispatches dominate eager
+    # stepping, and capture removes all but one.
     _AUTOJIT_THRESHOLD = 3
 
     def _autojit_try(self, inputs, kwargs):

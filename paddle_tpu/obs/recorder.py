@@ -1,8 +1,8 @@
 """Black-box flight recorder — forensic ring buffers dumped on failure.
 
-Every MULTICHIP_r0*.json run died rc=124 with ZERO forensic output: no
-phase, no last step, no collective sequence. The flight recorder is the
-fix — an always-on (flag-gated, overhead-guarded) black box holding
+A multichip dryrun that hit its time limit once left ZERO forensic
+output: no phase, no last step, no collective sequence. The flight
+recorder is the fix — an always-on (flag-gated, overhead-guarded) black box holding
 
   - the last N step-timeline records (shared ring with `obs/timeline.py`),
   - the last M per-step monitor-counter deltas,

@@ -27,7 +27,6 @@ from jax import lax
 
 from .. import monitor as _monitor
 from .. import obs as _obs
-from ..core.jaxcompat import axis_size as _axis_size
 from ..core.tensor import Tensor
 from ..ops._dispatch import ensure_tensor, run_op
 
@@ -105,7 +104,7 @@ def _in_spmd(axis_name) -> bool:
     if axis_name is None:
         return False
     try:
-        _axis_size(axis_name)
+        lax.axis_size(axis_name)
         return True
     except Exception:
         return False
@@ -142,7 +141,7 @@ def all_gather(tensor_list, tensor, group=None, sync_op=True, axis=0):
         with _obs.phase("collective"):
             out = run_op(lambda a: lax.all_gather(a, ax, tiled=False), [t],
                          "c_allgather")
-        n = _axis_size(ax)
+        n = lax.axis_size(ax)
         parts = [Tensor(out._value[i]) for i in range(n)]
         if tensor_list is not None:
             tensor_list.extend(parts)
@@ -255,7 +254,7 @@ def alltoall(in_tensor_list, out_tensor_list=None, group=None, sync_op=True):
         out = run_op(lambda a: lax.all_to_all(a, ax, split_axis=0, concat_axis=0,
                                               tiled=False), [src], "alltoall")
         if out_tensor_list is not None:
-            n = _axis_size(ax)
+            n = lax.axis_size(ax)
             out_tensor_list.extend(Tensor(out._value[i]) for i in range(n))
         return out
     if out_tensor_list is not None and isinstance(in_tensor_list, (list, tuple)):
@@ -283,7 +282,7 @@ def send(tensor, dst=0, group=None, sync_op=True):
     _record("send_v2", t)
     ax = _axis(group) or "pp"
     if _in_spmd(ax):
-        n = _axis_size(ax)
+        n = lax.axis_size(ax)
         perm = [(i, (i + 1) % n) for i in range(n)]
         return run_op(lambda a: lax.ppermute(a, ax, perm), [t], "send_v2")
     return t
@@ -302,7 +301,7 @@ def p2p_shift(x, group="pp", shift=1):
     t = ensure_tensor(x)
     _record("p2p_shift", t)
     ax = _axis(group) or "pp"
-    n = _axis_size(ax)
+    n = lax.axis_size(ax)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return run_op(lambda a: lax.ppermute(a, ax, perm), [t], "p2p_shift")
 
@@ -347,7 +346,7 @@ def _c_split(tensor, group=None):
     _record("c_split", t)
     ax = _axis(group) or "mp"
     if _in_spmd(ax):
-        n = _axis_size(ax)
+        n = lax.axis_size(ax)
         idx = lax.axis_index(ax)
 
         def f(a):

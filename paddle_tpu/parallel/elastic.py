@@ -268,6 +268,12 @@ def launch_elastic(training_script: str, script_args: Sequence[str] = (),
     Each (re)launch exports the CURRENT world size via
     PADDLE_TRAINERS_NUM/PADDLE_ELASTIC_NP, so AutoCheckpoint-driven
     scripts restore their snapshot and resume at the new membership.
+
+    One worker process per rank ON THIS HOST: what the CPU multi-process
+    tests need (`JAX_PLATFORMS=cpu` in `env`). A chip belongs to one
+    process at a time, so on a host with chips the ranks would all claim
+    the same chip(s); there the unit of restart is the one
+    single-controller SPMD process that drives every chip of the host.
     """
     base_env = dict(os.environ if env is None else env)
     watcher = ElasticManager(store, rank=-1, world_size=0) if store else None

@@ -80,13 +80,7 @@ def init_parallel_env(strategy=None):
     nproc = int(os.environ.get("PADDLE_TRAINERS_NUM", "1"))
     rank = int(os.environ.get("PADDLE_TRAINER_ID", "0"))
     if eps and nproc > 1:
-        already = False
-        try:
-            from jax._src import distributed as _jd
-            already = _jd.global_state.client is not None
-        except Exception:
-            pass
-        if not already:
+        if not jax.distributed.is_initialized():
             coordinator = eps.split(",")[0]
             jax.distributed.initialize(coordinator_address=coordinator,
                                        num_processes=nproc, process_id=rank)

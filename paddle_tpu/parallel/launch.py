@@ -6,8 +6,15 @@ launch_collective:380 → start_local_trainers with PADDLE_* env).
 TPU-native process model: ONE process per HOST (chips inside a host are
 addressed by the mesh, not by processes), so on a single host the launcher
 simply execs the script with rank env set; multi-host launch sets the
-coordinator address for jax.distributed. `--nproc_per_node` is accepted for
-CPU-mesh simulation (spawns N processes with a device-count override).
+coordinator address for jax.distributed.
+
+`--nproc_per_node N` (N > 1) is for the CPU multi-process tests only: it
+starts N worker processes on this host, which must run with
+`JAX_PLATFORMS=cpu`. A chip belongs to one process at a time, so on a host
+with chips every such worker would claim the same chip(s) and all but one
+would fail or hang. On chips the sharded path is the single-controller SPMD
+one — one process, a mesh over `jax.devices()` (`fleet.init`,
+`SPMDTrainStep`; `chip_smoke.py --chips 4` runs it).
 """
 from __future__ import annotations
 
@@ -107,7 +114,7 @@ def launch():
         runpy.run_path(args.training_script, run_name="__main__")
         return
 
-    # multi-process simulation (CPU mesh per process)
+    # multi-process simulation (CPU only: see the module docstring)
     procs = []
     for local in range(args.nproc_per_node):
         rank = args.node_rank * args.nproc_per_node + local

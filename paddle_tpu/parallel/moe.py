@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..core.jaxcompat import axis_size as _axis_size
 from ..core.tensor import Tensor
 from ..ops._dispatch import ensure_tensor, run_op
 from .collective import _in_spmd
@@ -120,7 +119,7 @@ def global_scatter(x, local_count=None, global_count=None, group=None):
             a = _mask_counts(a, lc)
         if not _in_spmd(ax):
             return a
-        ep = _axis_size(ax)
+        ep = lax.axis_size(ax)
         e_local = a.shape[0] // ep
         out = lax.all_to_all(a, ax, 0, 0, tiled=True)  # [ep*E_local, C, d]
         out = out.reshape(ep, e_local, a.shape[1], a.shape[2])
@@ -139,7 +138,7 @@ def global_gather(x, local_count=None, global_count=None, group=None):
     def f(a):
         if not _in_spmd(ax):
             return a if gc is None else _mask_counts(a, gc)
-        ep = _axis_size(ax)
+        ep = lax.axis_size(ax)
         e_local, epc, d = a.shape
         c = epc // ep
         b = a.reshape(e_local, ep, c, d)
@@ -207,7 +206,7 @@ class MoELayer:
         buckets = moe_dispatch(arr, dispatch)                # [E, C, d]
         ax = self.ep_axis
         if ax is not None and _in_spmd(ax):
-            ep = _axis_size(ax)
+            ep = lax.axis_size(ax)
             e_local = self.num_experts // ep
             rank = lax.axis_index(ax)
             # tokens' buckets -> owning ranks; each rank runs ITS experts

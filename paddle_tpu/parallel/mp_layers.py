@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..core.jaxcompat import axis_size as _axis_size
 from ..core.tensor import Tensor
 from ..nn import functional as F
 from ..nn import initializer as I
@@ -86,7 +85,7 @@ class RowParallelLinear(Layer):
         x = ensure_tensor(x)
         if _in_spmd("mp"):  # manual regime: partial matmul + psum
             if not self.input_is_parallel:
-                n = _axis_size("mp")
+                n = lax.axis_size("mp")
                 idx = lax.axis_index("mp")
 
                 def split_f(a):
@@ -121,7 +120,7 @@ class VocabParallelEmbedding(Layer):
     def forward(self, x):
         x = ensure_tensor(x)
         if _in_spmd("mp"):  # manual regime: mask out-of-shard ids, psum partial lookups
-            n = _axis_size("mp")
+            n = lax.axis_size("mp")
             idx = lax.axis_index("mp")
             per = self.num_embeddings // n
 
@@ -154,7 +153,7 @@ class ParallelCrossEntropy(Layer):
     def forward(self, input, label):
         input, label = ensure_tensor(input), ensure_tensor(label)
         if _in_spmd("mp"):
-            n = _axis_size("mp")
+            n = lax.axis_size("mp")
             idx = lax.axis_index("mp")
 
             def f(logits):

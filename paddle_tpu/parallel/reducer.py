@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core import flags as _flags
-from ..core.jaxcompat import axis_size as _axis_size
 from .collective import _record
 
 __all__ = ["Reducer"]
@@ -101,7 +100,7 @@ class Reducer:
         backward order. Must run inside a shard_map region binding the axis
         (SPMDTrainStep's bucketed mode); mean=True averages over the axis.
         Returns the reduced grads in ORIGINAL parameter order."""
-        n = _axis_size(self.axis)
+        n = lax.axis_size(self.axis)
         scale = 1.0 / n if self.mean else None
         out: List = [None] * len(grads)
         for bucket in self._buckets:

@@ -22,11 +22,9 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core.jaxcompat import axis_size as _axis_size
 from ..core.tensor import Tensor
 from ..ops._dispatch import ensure_tensor, run_op
 from .topology import get_mesh
@@ -53,7 +51,7 @@ def ring_attention_local(q, k, v, axis_name="sp", causal=False):
     q/k/v: local shards [B, S_local, H, D]. Rotates K/V n-1 times via
     ppermute, accumulating with the online-softmax (flash) recurrence.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, s_loc, h, d = q.shape
     scale = 1.0 / math.sqrt(d)
@@ -139,7 +137,7 @@ def sequence_parallel_attention(q, k, v, impl="ring", causal=False, mesh=None,
     fn = shard_map(
         functools.partial(local, axis_name=axis_name, causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
 
     def f(qa, ka, va):
         ns = NamedSharding(mesh, spec)
@@ -197,7 +195,7 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, block_q, block_k, interpret):
     result equals full-sequence attention to numerical precision.
     """
     from ..kernels.flash_attention import ring_block_fwd
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, s, h, d = q.shape
     qf, kf, vf = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)
@@ -240,7 +238,7 @@ def _ring_flash_bwd_rule(axis_name, causal, block_q, block_k, interpret, res,
     hops)."""
     from ..kernels.flash_attention import ring_block_dq, ring_block_dkv
     q, k, v, out, lse = res
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     b, s, h, d = q.shape
     qf, kf, vf = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)

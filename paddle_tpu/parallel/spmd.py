@@ -209,7 +209,7 @@ class SPMDTrainStep:
                                    bucket_bytes=self._bucket_bytes)
 
             def pure(params, slots, buffers, rng_key, lr, t, batch):
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
 
                 def body(params, slots, buffers, rng_key, lr, t, *batch):
                     inputs, labels = batch[:n_mi], batch[n_mi:]
@@ -227,7 +227,7 @@ class SPMDTrainStep:
                              P(), P(),
                              P() if nan_check else None)
                 return shard_map(body, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)(
+                                 out_specs=out_specs, check_vma=False)(
                     params, slots, buffers, rng_key, lr, t, *batch)
         else:
             def pure(params, slots, buffers, rng_key, lr, t, batch):
@@ -252,8 +252,8 @@ class SPMDTrainStep:
         donate = (0, 1, 5) if self._donate else ()
         self._donate_argnums = donate
         self._pure = pure   # unjitted typed-key body: collective_signature
-        # persistent-cache mode: raw-key-data program boundary (jax.export
-        # cannot serialize typed PRNG key avals — TrainStep._build regime)
+        # persistent-cache mode: raw-key-data program boundary (see
+        # TrainStep._build)
         self._raw_key = _cc.enabled()
         jit_pure = pure
         if self._raw_key:
@@ -476,10 +476,10 @@ class SPMDTrainStep:
             raise_nonfinite(bad, self._pnames, "jitted SPMD train step")
             return Tensor(loss)
 
-    def cost_analysis(self, *batch):
-        """Compiler-attributed {flops, bytes_accessed} for the sharded step
-        executable (see jit.TrainStep.cost_analysis). Per-device numbers:
-        XLA reports the cost of one shard of the SPMD program."""
+    def compiled(self, *batch):
+        """The `jax.stages.Compiled` sharded step executable at `batch`'s
+        signature (see jit.TrainStep.compiled). `.as_text()` shows the
+        collectives GSPMD put in."""
         arrs = [b._value if isinstance(b, Tensor) else jnp.asarray(b)
                 for b in batch]
         if self._jitted is None:
@@ -488,26 +488,20 @@ class SPMDTrainStep:
         params = [trainable[n]._value for n in self._pnames]
         buffers = [frozen[n]._value for n in self._bnames]
         key = rnd.default_generator().next_key()
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        t = jnp.asarray(self.optimizer._step_count + 1, jnp.float32)
-        lowered = self._jitted.lower(params, self._slots, buffers, key, lr,
-                                     t, arrs)
-        return _obs.executable_cost(lowered.compile())
+        if self._raw_key:
+            key = jax.random.key_data(key)
+        return self._jitted.lower(params, self._slots, buffers, key,
+                                  self._lr_scalar(), self._t_scalar(),
+                                  arrs).compile()
+
+    def cost_analysis(self, *batch):
+        """Compiler-attributed {flops, bytes_accessed} for the sharded step
+        executable (see jit.TrainStep.cost_analysis). Per-device numbers:
+        XLA reports the cost of one shard of the SPMD program."""
+        return _obs.executable_cost(self.compiled(*batch))
 
     def memory_report(self, *batch):
         """Compiler-reported memory breakdown for the sharded step
         executable (see jit.TrainStep.memory_report). Per-device numbers:
         XLA reports one shard of the SPMD program."""
-        arrs = [b._value if isinstance(b, Tensor) else jnp.asarray(b)
-                for b in batch]
-        if self._jitted is None:
-            self._build(arrs)
-        trainable, frozen = split_state(self.model)
-        params = [trainable[n]._value for n in self._pnames]
-        buffers = [frozen[n]._value for n in self._bnames]
-        key = rnd.default_generator().next_key()
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        t = jnp.asarray(self.optimizer._step_count + 1, jnp.float32)
-        lowered = self._jitted.lower(params, self._slots, buffers, key, lr,
-                                     t, arrs)
-        return _obs.executable_memory(lowered.compile())
+        return _obs.executable_memory(self.compiled(*batch))

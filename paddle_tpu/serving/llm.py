@@ -25,11 +25,15 @@ This module serves GPT/ERNIE decoders the way LLM traffic actually wants:
   the pool as int8 with a dequantization scale per slot.
 
 Decode blocks are `decode_block` (=2) tokens wide with only row 0 real:
-XLA lowers a rank-1 matmul through a differently-accumulated path, so a
-1-wide decode drifts ~1e-6 from the full-sequence forward, while any
-block >= 2 is bit-identical to it (tests/test_llm_serving.py proves
-logits-exact decode). The junk row's cache write lands one past the live
-prefix and is overwritten by the next real token before it can be read.
+on the XLA-CPU this was written against, a rank-1 matmul lowered through a
+differently-accumulated path and a block >= 2 made decode bitwise equal to
+the full-sequence forward. The installed XLA (jax 0.9) gives no such
+equality at any width — cached and full logits agree to ~2e-6, and
+tests/test_llm_serving.py holds them to 1e-4 with the same arg-max — so
+nothing may rely on it; the block width stays until ROADMAP D6 frees the
+decode step to be one row wide. The junk row's cache write lands one past
+the live prefix and is overwritten by the next real token before it can
+be read.
 
 Reference parity: this is the Paddle-Serving deployment role (PAPER.md
 §1 row 8) taken to continuous batching over a paged KV cache — the
@@ -101,8 +105,7 @@ class LLMConfig:
     warmup_on_start: bool = True
     quant: str = "off"          # "off" | "int8" weight-only decoder matmuls
     kv_int8: bool = False
-    # block width of one decode step; >= 2 keeps decode bit-identical to
-    # the full-sequence forward (see module docstring)
+    # block width of one decode step (see module docstring; ROADMAP D6)
     decode_block: int = 2
     idle_park_s: float = 0.02   # scheduler nap when no work is queued
 

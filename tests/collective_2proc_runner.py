@@ -62,7 +62,7 @@ assert jax.process_count() == 2, jax.process_count()
 import numpy as np  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax import lax  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
 devs = jax.devices()
@@ -77,7 +77,7 @@ def allred(x):
 
 
 out = jax.jit(shard_map(allred, mesh=mesh, in_specs=P("dp"), out_specs=P(),
-                        check_rep=False))(garr)
+                        check_vma=False))(garr)
 local_out = np.asarray(out.addressable_data(0))
 
 # store-side barrier + cross-check (TCPStore ADD used as the barrier count)

@@ -333,7 +333,7 @@ class TestGraphAnalysis:
         import jax
         import jax.numpy as jnp
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64():
             def f(x):
                 return x.astype(jnp.float64) * 2.0
 
@@ -384,7 +384,7 @@ def _mesh(axis="pp"):
 
 
 def _shmap(fn, mesh, **kw):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     return shard_map(fn, mesh=mesh, in_specs=P("pp"), out_specs=P("pp"),
                      **kw)
@@ -408,7 +408,7 @@ class TestCollectiveOrder:
         assert seq[0].axis == "pp" and seq[0].dtype == "float32"
 
     def test_check_rep_does_not_change_signature(self):
-        # psum is rewritten to psum2+pbroadcast under check_rep=True; the
+        # psum is rewritten to psum2+pbroadcast under check_vma=True; the
         # signature must be invariant to that bookkeeping
         import jax
         import jax.numpy as jnp
@@ -420,7 +420,7 @@ class TestCollectiveOrder:
         m = _mesh()
         x = jnp.ones((8, 4))
         a = agraph.collective_sequence(_shmap(stage, m), x)
-        b = agraph.collective_sequence(_shmap(stage, m, check_rep=False), x)
+        b = agraph.collective_sequence(_shmap(stage, m, check_vma=False), x)
         assert a == b
 
     def test_mismatch_names_first_divergence(self):

@@ -1,0 +1,133 @@
+"""chip_smoke.py's phases at a tiny size on the CPU.
+
+The script itself only passes on a TPU (`main()` has no way round that);
+these tests keep its control flow honest between chip runs: every phase
+function runs end to end on 2-layer hidden-64 models, with the Pallas
+kernels interpreted, and the four-chip phases on four of the eight
+virtual CPU devices. They check nothing about speed.
+"""
+import json
+import os
+import sys
+import types
+
+import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+import paddle_tpu as paddle  # noqa: E402
+from paddle_tpu import models  # noqa: E402
+from paddle_tpu.parallel.topology import set_mesh  # noqa: E402
+
+_TINY_ERNIE = dict(vocab_size=512, hidden_size=64, num_hidden_layers=2,
+                   num_attention_heads=4, intermediate_size=128,
+                   max_position_embeddings=64)
+
+
+@pytest.fixture(autouse=True)
+def _restore_process_state():
+    """The phases switch the monitor on, pick a device and set a mesh, as
+    a user's script would; put the process back for the next test file."""
+    yield
+    paddle.set_flags({"FLAGS_monitor": False})
+    set_mesh(None)
+    jax.config.update("jax_default_device", None)
+
+
+@pytest.fixture(scope="module")
+def lowerings():
+    return chip_smoke.Lowerings()
+
+
+def test_main_on_a_cpu_exits_nonzero_and_prints_no_ok(capsys):
+    assert chip_smoke.main([]) != 0
+    assert chip_smoke.main(["--chips", "4"]) != 0
+    out, err = capsys.readouterr()
+    assert '"ok"' not in out
+    assert "needs a TPU" in err
+
+
+def test_train_phase(lowerings, capsys):
+    cfg = chip_smoke.TrainCfg(build=lambda: models.ErnieModel(**_TINY_ERNIE),
+                              batch=4, seq=16, steps=3)
+    line = chip_smoke.phase_train(cfg, "cpu", lowerings)
+    assert line["check"]["loss_last"] < line["check"]["loss_first"]
+    assert line["check"]["model"] == {"layers": 2, "hidden": 64, "heads": 4,
+                                      "vocab": 512}
+    assert line["timing"] == chip_smoke.NOTE
+    assert json.loads(capsys.readouterr().out.splitlines()[-1]) == line
+
+
+def test_train_phase_fails_when_a_check_does_not_hold(lowerings,
+                                                      monkeypatch):
+    """No phase carries a failed check past: a ledger that books a compile
+    on the warm signature must end the phase."""
+    ticks = iter(range(100))
+    monkeypatch.setattr(chip_smoke, "_trace_compiles", lambda: next(ticks))
+    cfg = chip_smoke.TrainCfg(build=lambda: models.ErnieModel(**_TINY_ERNIE),
+                              batch=4, seq=16, steps=2)
+    with pytest.raises(chip_smoke.SmokeFailure, match="compiled"):
+        chip_smoke.phase_train(cfg, "cpu", lowerings)
+
+
+def test_serve_phase(lowerings):
+    from paddle_tpu.models.gpt import GPTModel
+    cfg = chip_smoke.ServeCfg(
+        build=lambda: GPTModel(vocab_size=128, hidden_size=64, num_layers=2,
+                               num_heads=4, max_seq_len=64),
+        num_slots=4, max_len=32, prefill_buckets=(8, 32), max_new_tokens=4,
+        prompt_lens=(3, 9, 17))
+    line = chip_smoke.phase_serve(cfg, "cpu", lowerings)
+    assert line["check"]["first_token_top1_agrees"] == 3
+    assert line["check"]["evictions_error"] == 0
+
+
+def test_kernel_phase_interprets_the_kernel_on_a_cpu(monkeypatch):
+    """`nn.functional` attention picks the kernel only on a TPU; the test
+    answers "tpu" to that one question, and the kernel entry, which asks
+    JAX itself, then interprets."""
+    from paddle_tpu.nn.functional import attention
+    monkeypatch.setattr(attention, "jax", types.SimpleNamespace(
+        default_backend=lambda: "tpu", nn=jax.nn, random=jax.random))
+    line = chip_smoke.phase_kernel(
+        geometries=((1, 1024, 2, 64, True),),
+        scan=dict(hidden=128, heads=2, ffn=256, layers=2, batch=1, seq=256),
+        min_kernels=0)
+    attn, scan = line["check"]["paths"]
+    assert (attn["forward"], attn["backward"]) == ("pallas", "fused")
+    assert attn["tpu_custom_calls"] == scan["tpu_custom_calls"] == 0
+    assert max(attn["rel_err"].values()) <= chip_smoke.BF16_TOL
+
+
+def test_kernel_phase_refuses_a_program_without_the_kernel():
+    """What the chip run relies on: with the default `min_kernels` a
+    program whose compiled text holds no tpu_custom_call cannot pass."""
+    with pytest.raises(chip_smoke.SmokeFailure, match="tpu_custom_call"):
+        chip_smoke.phase_kernel(geometries=((1, 256, 2, 64, True),))
+
+
+def test_spmd_phase_on_four_virtual_devices():
+    cfg = chip_smoke.SpmdCfg(
+        model=dict(_TINY_ERNIE, hidden_dropout_prob=0.0, use_mp=True),
+        batch=8, seq=16, steps=3)
+    line = chip_smoke.phase_spmd(cfg, jax.devices()[:4], "cpu")
+    check = line["check"]
+    assert check["mesh"] == {"dp": 2, "pp": 1, "sharding": 1, "mp": 2}
+    assert len(check["devices_covered"]) == 4
+    assert check["collectives"]["all-reduce"] > 0
+    assert check["worst_rel_diff"] <= cfg.loss_rtol
+
+
+def test_ring_phase_on_four_virtual_devices():
+    line = chip_smoke.phase_ring(jax.devices()[:4], geometry=(1, 512, 2, 64),
+                                 min_kernels=0)
+    assert line["check"]["collective_permutes"] > 0
+    assert max(line["check"]["rel_err"].values()) <= chip_smoke.BF16_TOL
+
+
+def test_ring_phase_refuses_a_ring_without_the_kernel():
+    with pytest.raises(chip_smoke.SmokeFailure, match="tpu_custom_call"):
+        chip_smoke.phase_ring(jax.devices()[:4], geometry=(1, 512, 2, 64))

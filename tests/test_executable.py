@@ -199,10 +199,13 @@ class TestAcquire:
         _flags.set_flags({"compile_cache_dir": str(tmp_path / "cc")})
         cc.reset_stats()
         try:
-            # typed PRNG key avals cannot ride jax.export: acquire must
+            # a host callback cannot ride jax.export (typed PRNG keys,
+            # this test's old subject, have since jax 0.9): acquire must
             # skip persistence and hand back the working fresh callable
-            f = jax.jit(lambda k: jax.random.uniform(k, (3,)))
-            args = (jax.random.key(0),)
+            import numpy as np
+            f = jax.jit(lambda a: jax.pure_callback(
+                np.sin, jax.ShapeDtypeStruct(a.shape, a.dtype), a))
+            args = (jnp.ones((3,)),)
             call, source = exe.acquire("unit", f, args)
             assert source == "fresh"
             assert call(*args).shape == (3,)
@@ -210,3 +213,63 @@ class TestAcquire:
         finally:
             _flags.set_flags({"compile_cache_dir": ""})
             cc.reset_stats()
+
+
+# ---- where JAX's own persistent cache goes ---------------------------------
+
+class TestJaxCachePlacement:
+    """`FLAGS_compile_cache_dir` places the export-blob store; JAX's own
+    compilation cache follows ONE rule (compile_cache.place_jax_cache):
+    the directory `JAX_COMPILATION_CACHE_DIR` names, else one fixed path
+    in the checkout — and nothing ever unsets it."""
+
+    @pytest.fixture(autouse=True)
+    def _restore_jax_config(self):
+        import jax
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_env_dir_survives_setting_and_clearing_the_flag(
+            self, tmp_path, monkeypatch):
+        import jax
+        given = str(tmp_path / "given")
+        monkeypatch.setenv(cc.JAX_CACHE_ENV, given)
+        jax.config.update("jax_compilation_cache_dir", given)  # as at import
+        _flags.set_flags({"compile_cache_dir": str(tmp_path / "blobs")})
+        try:
+            assert jax.config.jax_compilation_cache_dir == given
+        finally:
+            _flags.set_flags({"compile_cache_dir": ""})
+        assert jax.config.jax_compilation_cache_dir == given
+        assert not (tmp_path / "blobs" / "xla").exists()
+
+    def test_env_set_after_import_is_still_honoured(self, tmp_path,
+                                                    monkeypatch):
+        import jax
+        given = str(tmp_path / "late")
+        monkeypatch.setenv(cc.JAX_CACHE_ENV, given)
+        assert cc.place_jax_cache() == given
+        assert jax.config.jax_compilation_cache_dir == given
+
+    def test_without_env_the_fixed_checkout_path(self, tmp_path,
+                                                 monkeypatch):
+        import jax
+        monkeypatch.delenv(cc.JAX_CACHE_ENV, raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cc.JAX_CACHE_DEFAULT == os.path.join(repo, ".jax_cache")
+        _flags.set_flags({"compile_cache_dir": str(tmp_path / "blobs")})
+        try:
+            assert jax.config.jax_compilation_cache_dir == \
+                cc.JAX_CACHE_DEFAULT
+        finally:
+            _flags.set_flags({"compile_cache_dir": ""})
+        # clearing the flag turns the blob store off, not JAX's cache
+        assert not cc.enabled()
+        assert jax.config.jax_compilation_cache_dir == cc.JAX_CACHE_DEFAULT
+
+    def test_no_cache_path_is_made_from_a_pid_a_time_or_a_temp_name(self):
+        import inspect
+        src = inspect.getsource(cc.place_jax_cache)
+        for word in ("mkdtemp", "getpid", "time.", "tempfile"):
+            assert word not in src

@@ -161,3 +161,44 @@ class TestFusedBackwardParity:
                 np.testing.assert_allclose(
                     np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5,
                     err_msg=f"{nm} mismatch (causal={causal})")
+
+
+# ---- the dispatcher's own predicate (what chip_smoke.py prints) -------------
+
+@pytest.mark.parametrize("s,d,dtype,want", [
+    # bf16: the fused backward's resident set fits up to s8192 at d<=128
+    (1024, 64, "bfloat16", (512, 512, "pallas", "fused")),
+    (8192, 64, "bfloat16", (512, 512, "pallas", "fused")),
+    (8192, 128, "bfloat16", (512, 512, "pallas", "fused")),
+    (4096, 256, "bfloat16", (512, 512, "pallas", "fused")),
+    # d=64 occupies the 128 lanes d=128 does: s16384 streams (the guard
+    # that counted d sent it to a fused kernel the compiler refused)
+    (16384, 64, "bfloat16", (512, 512, "pallas", "two_pass")),
+    (8192, 256, "bfloat16", (512, 512, "pallas", "two_pass")),
+    # fp32 doubles every stream
+    (4096, 64, "float32", (512, 512, "pallas", "fused")),
+    (8192, 64, "float32", (512, 512, "pallas", "two_pass")),
+    # short sequences shrink the blocks to the sequence
+    (256, 64, "bfloat16", (256, 256, "pallas", "fused")),
+    # a length no block divides takes the XLA reference, backward included
+    (1000, 64, "bfloat16", (512, 512, "reference", "reference")),
+    (640, 64, "bfloat16", (512, 512, "reference", "reference")),
+])
+def test_dispatch_plan(s, d, dtype, want):
+    import importlib
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    assert fa.dispatch_plan(s, d, dtype) == want
+
+
+def test_vmem_request_counts_padded_lanes_and_both_buffers():
+    """A [S, 64] bf16 block occupies what [S, 128] does; every pipelined
+    block is double-buffered; the request never drops under Mosaic's
+    default nor passes the cap."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    assert fa._padded_bytes((8192, 64), jnp.bfloat16) \
+        == fa._padded_bytes((8192, 128), jnp.bfloat16) == 8192 * 128 * 2
+    # second-minor pads to the sublane tile: 8 rows of f32, 16 of bf16
+    assert fa._padded_bytes((1, 1, 512), jnp.float32) == 8 * 512 * 4
+    assert fa._padded_bytes((3, 512), jnp.bfloat16) == 16 * 512 * 2
+    assert fa._VMEM_DEFAULT < fa._FUSED_BWD_VMEM_CAP * 2 <= fa._VMEM_MAX

@@ -107,7 +107,7 @@ class TestMarginCrossEntropy:
                 return_softmax=True, reduction=None)
             return out[0]._value, out[1]._value
 
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         f = shard_map(body, mesh=mesh, in_specs=(P(None, "mp"), P()),
                           out_specs=(P(), P(None, "mp")))
         loss, sm = f(jnp.asarray(logits), jnp.asarray(label))

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import paddle_tpu as paddle
@@ -115,7 +115,7 @@ class TestExpertParallel:
             return dist(xs, capacity=xs.shape[0])
 
         f = shard_map(body, mesh=mesh, in_specs=P("ep"), out_specs=P("ep"),
-                      check_rep=False)
+                      check_vma=False)
         y_dist = np.asarray(f(x))
         np.testing.assert_allclose(y_dist, y_local, rtol=1e-4, atol=1e-4)
 
@@ -131,7 +131,7 @@ class TestExpertParallel:
             return global_gather(s, group="ep")._value
 
         f = shard_map(lambda b: body(b), mesh=mesh, in_specs=P("ep"),
-                      out_specs=P("ep"), check_rep=False)
+                      out_specs=P("ep"), check_vma=False)
         out = np.asarray(f(jnp.tile(x, (4, 1, 1))))  # each rank same buckets
         ref = np.asarray(x).copy()
         ref[1, 2:] = 0  # count=2 masks rows 2..3

@@ -200,7 +200,7 @@ class TestCollectivePlane:
         submesh of the 8-device virtual CPU mesh)."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         import paddle_tpu.distributed as dist
         mesh = Mesh(np.array(jax.devices()[:2]), ("dp",))
@@ -210,7 +210,7 @@ class TestCollectivePlane:
             return dist.all_reduce(t)._value
 
         f = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-                      check_rep=False)
+                      check_vma=False)
         out = f(jnp.ones((4, 8), jnp.float32))
         np.testing.assert_allclose(np.asarray(out), 2.0)
         snap = monitor.snapshot()

@@ -114,7 +114,7 @@ class TestSPMDTrainStep:
 
 class TestCollectivesInShardMap:
     def test_allreduce_psum(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         mesh = create_mesh({"dp": 8})
 
@@ -124,13 +124,13 @@ class TestCollectivesInShardMap:
             return out._value
 
         f = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-                      check_rep=False)
+                      check_vma=False)
         x = np.arange(8, dtype="float32")
         out = f(jnp.asarray(x))
         np.testing.assert_allclose(np.asarray(out), np.full(8, x.sum()), rtol=1e-6)
 
     def test_reduce_scatter_and_allgather(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         mesh = create_mesh({"dp": 4})
 
@@ -141,7 +141,7 @@ class TestCollectivesInShardMap:
             return gathered._value.reshape(1, -1)
 
         f = shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
-                      check_rep=False)
+                      check_vma=False)
         x = np.tile(np.arange(8, dtype="float32"), (4, 1)).reshape(-1)  # 4 shards of 8
         out = np.asarray(f(jnp.asarray(x)))
         # each shard contributes arange(8); rs gives 4*arange chunk per device
@@ -241,7 +241,7 @@ class TestPipelineParallel:
 
 class TestVocabParallelAndCE:
     def test_vocab_embedding_matches_dense(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         mesh = create_mesh({"mp": 4})
         vocab, dim = 16, 8
@@ -255,12 +255,12 @@ class TestVocabParallelAndCE:
             return out._value
 
         f = shard_map(body, mesh=mesh, in_specs=P("mp", None), out_specs=P(),
-                      check_rep=False)
+                      check_vma=False)
         out = np.asarray(f(jnp.asarray(w_full)))
         np.testing.assert_allclose(out, w_full[ids], rtol=1e-6)
 
     def test_parallel_ce_matches_dense(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         mesh = create_mesh({"mp": 4})
         logits = _r(6, 16)
@@ -272,7 +272,7 @@ class TestVocabParallelAndCE:
             return out._value
 
         f = shard_map(body, mesh=mesh, in_specs=P(None, "mp"), out_specs=P(),
-                      check_rep=False)
+                      check_vma=False)
         got = np.asarray(f(jnp.asarray(logits)))
         e = np.exp(logits - logits.max(-1, keepdims=True))
         p = e / e.sum(-1, keepdims=True)

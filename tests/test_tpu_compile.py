@@ -1,0 +1,154 @@
+"""Compile the main path's kernels for a DESCRIBED v5e, at real widths.
+
+No chip is involved: `jax.experimental.topologies` describes a `v5e:2x2`
+and the installed TPU compiler compiles for it, raising what the chip's
+compiler would raise (a slice off the tiling, more VMEM than a kernel may
+hold). Interpret-mode tests cannot see either. A compile that passes is
+not a chip run and says nothing about results or times.
+
+The topology is described inside a fixture, never at import: only one
+process may hold libtpu, and every xdist worker imports every test file.
+Keep all such compiles in THIS file (a second file may land on another
+worker, whose fixture would then skip). JAX's persistent compilation
+cache is off around them: an entry written for a described device cannot
+be read back without a chip.
+"""
+import functools
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# the package re-exports the `flash_attention` function under the module's name
+fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+# (b*h, s, d, causal): the geometries chip_smoke.py's kernel phase runs
+GEOMETRIES = [(96, 1024, 64, False), (12, 8192, 64, True),
+              (12, 8192, 128, True)]
+_IDS = [f"bh{bh}-s{s}-d{d}" for bh, s, d, _ in GEOMETRIES]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return make
+
+
+def _kernels_in(fn, *args):
+    """Compile `fn` for the described chip; count its Pallas kernels."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    n = text.count('custom_call_target="tpu_custom_call"')
+    assert n, "no tpu_custom_call in the compiled program"
+    return n
+
+
+def _sum_loss(out):
+    return out.astype(jnp.float32).sum()
+
+
+@pytest.mark.parametrize("bh,s,d,causal", GEOMETRIES, ids=_IDS)
+def test_flash_forward_compiles(sds, bh, s, d, causal):
+    bq, bk, fwd, _ = fa.dispatch_plan(s, d, jnp.bfloat16)
+    assert fwd == "pallas"
+    x = sds((bh, s, d))
+    assert _kernels_in(
+        lambda q, k, v: fa._flash_core(q, k, v, causal, bq, bk, False),
+        x, x, x) == 1
+
+
+@pytest.mark.parametrize("bh,s,d,causal", GEOMETRIES, ids=_IDS)
+def test_flash_grad_compiles(sds, bh, s, d, causal):
+    """The WHOLE differentiated program: at bh12/s8192/d64 each backward
+    kernel compiled alone while the program jax.grad composes around the
+    custom call was refused (scoped VMEM, PR 22)."""
+    bq, bk, _, bwd = fa.dispatch_plan(s, d, jnp.bfloat16)
+    assert bwd == "fused"
+    x = sds((bh, s, d))
+
+    def loss(q, k, v):
+        return _sum_loss(fa._flash_core(q, k, v, causal, bq, bk, False))
+
+    # forward + the fused single-pass backward
+    assert _kernels_in(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == 2
+
+
+@pytest.mark.parametrize("bwd,n_kernels", [("_flash_bwd_fused_bhsd", 1),
+                                           ("_flash_bwd_bhsd", 2)])
+def test_each_backward_forced(sds, bwd, n_kernels):
+    bh, s, d = 12, 8192, 64
+    x, row = sds((bh, s, d)), sds((bh, 1, s), jnp.float32)
+    fn = functools.partial(getattr(fa, bwd), causal=True, block_q=512,
+                           block_k=512, interpret=False)
+    assert _kernels_in(fn, x, x, x, x, row, x) == n_kernels
+
+
+def test_two_pass_grad_compiles_past_the_fused_cap(sds):
+    """s16384/d64 is where the old residency guard (counting d, not the
+    128 padded lanes) still chose the fused kernel and the compiler
+    refused it; the dispatcher now streams it through the two-pass."""
+    bh, s, d = 12, 16384, 64
+    bq, bk, _, bwd = fa.dispatch_plan(s, d, jnp.bfloat16)
+    assert bwd == "two_pass"
+    x = sds((bh, s, d))
+
+    def loss(q, k, v):
+        return _sum_loss(fa._flash_core(q, k, v, True, bq, bk, False))
+
+    assert _kernels_in(jax.grad(loss, argnums=(0, 1, 2)), x, x, x) == 3
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_ring_block_kernels_compile(sds, kernel):
+    """One hop of the sp=4 ring at s8192: each chip's 2048-row shard."""
+    bh, s_loc, d = 12, 2048, 64
+    x, row = sds((bh, s_loc, d)), sds((bh, 1, s_loc), jnp.float32)
+    offs = sds((2,), jnp.int32)
+    kw = dict(causal=True, block_q=512, block_k=512, interpret=False)
+    if kernel == "fwd":
+        _kernels_in(functools.partial(fa.ring_block_fwd, **kw), x, x, x, offs)
+    else:
+        fn = fa.ring_block_dq if kernel == "dq" else fa.ring_block_dkv
+        _kernels_in(functools.partial(fn, **kw), x, x, x, x, row, row, offs)
+
+
+def test_ernie_scan_layer_step_compiles(sds, monkeypatch):
+    """One ErnieScanStack layer, forward and backward, hidden 768 / seq
+    1024 / bf16. The layer asks `jax.default_backend()` whether to
+    interpret its kernel and would see this process's CPU, so the test
+    answers for the described chip."""
+    from paddle_tpu.models.ernie import ErnieScanStack
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    net = ErnieScanStack(768, 12, 3072, n_layers=1)
+    ws = [net.qkv_w, net.qkv_b, net.proj_w, net.proj_b, net.fc1_w,
+          net.fc1_b, net.fc2_w, net.fc2_b, net.ln1_g, net.ln1_b,
+          net.ln2_g, net.ln2_b]
+    wl = tuple(sds(tuple(w.shape[1:])) for w in ws)
+    x = sds((8, 1024, 768))
+
+    def loss(x, wl):
+        return _sum_loss(net._layer_fn(x, wl))
+
+    assert _kernels_in(jax.grad(loss, argnums=(0, 1)), x, wl) == 2
