@@ -25,13 +25,18 @@ from ..core import compile_cache as _cc
 from ..core import executable as _exe
 from ..core import random as rnd
 from ..core.tensor import Tensor
+from ..ops import _dispatch as _dsp
 from ..ops._dispatch import run_op
 from .functional import functional_call, split_state
 from .input_spec import InputSpec  # noqa: F401  (re-export)
 
 
 class StaticFunction:
-    def __init__(self, function, layer=None, input_spec=None):
+    def __init__(self, function, layer=None, input_spec=None, name=None):
+        # the compiled program's name (`jit_<name>` in an HLO dump and in a
+        # profiler trace's `XLA Modules`): a reader picks a program by it
+        self._name = name or (type(layer).__name__ if layer is not None
+                              else getattr(function, "__name__", "to_static"))
         try:
             from .dy2static import ast_transform
             self._function = ast_transform(function)
@@ -128,6 +133,7 @@ class StaticFunction:
                                 jax.random.wrap_key_data(key_data),
                                 input_arrays)
 
+            pure.__name__ = pure.__qualname__ = self._name
             jitted = jax.jit(pure)
             self._jit_cache[key] = jitted
         return jitted
@@ -159,72 +165,81 @@ class StaticFunction:
 
     def __call__(self, *args, **kwargs):
         from ..core import autograd as _ag
-        layer = self._layer
-        input_tensors = [a if isinstance(a, Tensor) else Tensor(a) for a in args]
-        if any(isinstance(v, Tensor) for v in kwargs.values()):
-            raise ValueError("to_static: pass Tensor arguments positionally")
-        try:
-            hash(tuple(sorted(kwargs.items())))
-            static_kwargs = kwargs
-        except TypeError:
-            raise ValueError("to_static kwargs must be hashable (static) values")
+        mon, hook = _monitor._ENABLED, _dsp._PROFILE_HOOK
+        # the `static_program` profiler event and `jit.to_static.dur` are
+        # the call span's interval, so it is timed for either reader
+        call_span = (_monitor.Span("jit.to_static.call")
+                     if mon or hook is not None else _monitor._NULL_SPAN)
+        with _monitor.span("jit.to_static.prepare"):
+            layer = self._layer
+            input_tensors = [a if isinstance(a, Tensor) else Tensor(a)
+                             for a in args]
+            if any(isinstance(v, Tensor) for v in kwargs.values()):
+                raise ValueError(
+                    "to_static: pass Tensor arguments positionally")
+            try:
+                hash(tuple(sorted(kwargs.items())))
+                static_kwargs = kwargs
+            except TypeError:
+                raise ValueError(
+                    "to_static kwargs must be hashable (static) values")
 
-        if layer is not None:
-            trainable, frozen = split_state(layer)
-            pnames, bnames = list(trainable), list(frozen)
-            ptensors = [trainable[n] for n in pnames]
-            barrs = [frozen[n]._value for n in bnames]
-            # composite mode flag: sublayer train/eval toggles re-key the
-            # trace caches (a capture traced with dropout active must not
-            # replay after model.dropout.eval())
-            training = tuple(l.training for l in
-                             layer.sublayers(include_self=True))
-        else:
-            pnames, bnames, ptensors, barrs = [], [], [], []
-            training = True
+            if layer is not None:
+                trainable, frozen = split_state(layer)
+                pnames, bnames = list(trainable), list(frozen)
+                ptensors = [trainable[n] for n in pnames]
+                barrs = [frozen[n]._value for n in bnames]
+                # composite mode flag: sublayer train/eval toggles re-key the
+                # trace caches (a capture traced with dropout active must not
+                # replay after model.dropout.eval())
+                training = tuple(l.training for l in
+                                 layer.sublayers(include_self=True))
+            else:
+                pnames, bnames, ptensors, barrs = [], [], [], []
+                training = True
 
-        key = rnd.default_generator().next_key()
-        n_p = len(ptensors)
-        diff_inputs = ptensors + input_tensors
-        arrays = [t._value for t in diff_inputs]
-        # persistent-cache mode rides the raw-key-data program variant
-        raw = _cc.enabled()
-        karg = jax.random.key_data(key) if raw else key
+            key = rnd.default_generator().next_key()
+            n_p = len(ptensors)
+            diff_inputs = ptensors + input_tensors
+            arrays = [t._value for t in diff_inputs]
+            # persistent-cache mode rides the raw-key-data program variant
+            raw = _cc.enabled()
+            karg = jax.random.key_data(key) if raw else key
 
-        # publish this capture as the default program (ProgramDesc role):
-        # introspection/pruning lower lazily from the same traced callable.
-        # Rebuilt only when the input signature changes (zero steady-state
-        # cost on the hot path).
-        sig = tuple((t._value.shape, str(t._value.dtype)) for t in diff_inputs)
-        # a NOVEL signature on a to_static capture = retrace: the whole
-        # program recompiles for the new shapes/dtypes. A previously-seen
-        # signature hits jax.jit's executable cache and is free — only
-        # the Program rebuild below runs.
-        novel = self._ledger.note(sig, detail=[f"{s}:{d}" for s, d in sig])
-        if self._ledger.current_sig != sig:
-            if _analysis._ENABLED:
-                # trace-time tpu-lint: novel-signature block only, so the
-                # steady-state call path never reaches this check
-                _analysis.lint_traced(self._function, "to_static")
-            jitted = self._get_jitted(training, pnames, bnames,
-                                      static_kwargs, raw)
+            # publish this capture as the default program (ProgramDesc role):
+            # introspection/pruning lower lazily from the same traced callable.
+            # Rebuilt only when the input signature changes (zero steady-state
+            # cost on the hot path).
+            sig = tuple((t._value.shape, str(t._value.dtype))
+                        for t in diff_inputs)
+            # a NOVEL signature on a to_static capture = retrace: the whole
+            # program recompiles for the new shapes/dtypes. A previously-seen
+            # signature hits jax.jit's executable cache and is free — only
+            # the Program rebuild below runs.
+            novel = self._ledger.note(sig, detail=[f"{s}:{d}" for s, d in sig])
+            if self._ledger.current_sig != sig:
+                if _analysis._ENABLED:
+                    # trace-time tpu-lint: novel-signature block only, so the
+                    # steady-state call path never reaches this check
+                    _analysis.lint_traced(self._function, "to_static")
+                jitted = self._get_jitted(training, pnames, bnames,
+                                          static_kwargs, raw)
 
-            def fn(*arrs, _jit=jitted, _b=list(barrs), _k=karg, _np=n_p):
-                return _jit(list(arrs[:_np]), _b, _k, list(arrs[_np:]))
+                def fn(*arrs, _jit=jitted, _b=list(barrs), _k=karg, _np=n_p):
+                    return _jit(list(arrs[:_np]), _b, _k, list(arrs[_np:]))
 
-            from ..static.program import Program, _set_default_program
-            specs = [jax.ShapeDtypeStruct(t._value.shape, t._value.dtype)
-                     for t in diff_inputs]
-            self._last_program = Program(fn, specs, name=getattr(
-                self._function, "__name__", "main"))
-            self._ledger.current_sig = sig
-            _set_default_program(self._last_program)
+                from ..static.program import Program, _set_default_program
+                specs = [jax.ShapeDtypeStruct(t._value.shape, t._value.dtype)
+                         for t in diff_inputs]
+                self._last_program = Program(fn, specs, name=getattr(
+                    self._function, "__name__", "main"))
+                self._ledger.current_sig = sig
+                _set_default_program(self._last_program)
 
-        import time as _time
-        _t0 = _time.time()
-        record = (_ag.is_grad_enabled()
-                  and any(not t.stop_gradient for t in diff_inputs)
-                  and not any(isinstance(a, jax.core.Tracer) for a in arrays))
+            record = (_ag.is_grad_enabled()
+                      and any(not t.stop_gradient for t in diff_inputs)
+                      and not any(isinstance(a, jax.core.Tracer)
+                                  for a in arrays))
         if not record:
             jitted = self._get_jitted(training, pnames, bnames,
                                       static_kwargs, raw)
@@ -246,14 +261,16 @@ class StaticFunction:
                         bk.compiled()
                 elif novel:
                     bk.compiled()
-                out = call(arrays[:n_p], barrs, karg, arrays[n_p:])
+                with call_span:
+                    out = call(arrays[:n_p], barrs, karg, arrays[n_p:])
         else:
             with _exe.booking("to_static") as bk:
                 if novel:
                     bk.compiled()
                 fwd_vjp = self._get_fwd_vjp(training, pnames, bnames,
                                             static_kwargs, n_p)
-                out, raw_vjp = fwd_vjp(arrays, barrs, key)
+                with call_span:
+                    out, raw_vjp = fwd_vjp(arrays, barrs, key)
         # arbitrary output pytrees (e.g. RNN layers return (out, (h, c))):
         # the tape stores flat leaf tensors; the vjp wrapper unflattens the
         # flat cotangents back to the traced structure
@@ -265,18 +282,16 @@ class StaticFunction:
                        or (len(leaves) > 1 and treedef ==
                            jax.tree_util.tree_structure(tuple(leaves))))
         outs_list = [Tensor(o) for o in leaves]
-        from ..ops import _dispatch as _dsp
         from ..core import flags as _flags
         if _flags.flag("check_nan_inf") and not any(
                 isinstance(o, jax.core.Tracer) for o in leaves):
             _dsp._check_nan_inf("static_program", tuple(leaves))
-        if _dsp._PROFILE_HOOK is not None:
-            import time as _time
-            _dsp._PROFILE_HOOK("static_program", _t0, _time.time())
-        if _monitor._ENABLED:
-            import time as _time
+        if hook is not None:
+            hook("static_program", call_span.wall,
+                 call_span.wall + call_span.dur)
+        if mon:
             _monitor.count("jit.to_static.calls")
-            _monitor.observe("jit.to_static.dur", _time.time() - _t0)
+            _monitor.observe("jit.to_static.dur", call_span.dur)
         if record:
             _ag.record_node(
                 _ag._JitVJP(raw_vjp,
@@ -298,20 +313,24 @@ class StaticFunction:
 
 
 def to_static(function=None, input_spec=None, build_strategy=None, backend=None,
-              **kwargs):
+              name=None, **kwargs):
     """Decorator/wrapper. Accepts a Layer, a Layer's bound forward, or a pure
-    function of Tensors."""
+    function of Tensors. `name` names the compiled program (`jit_<name>`);
+    the default is the Layer's class or the function's own name."""
 
     def decorate(obj):
         from ..nn.layer.layers import Layer
         if isinstance(obj, Layer):
-            static = StaticFunction(obj.forward, layer=obj, input_spec=input_spec)
+            static = StaticFunction(obj.forward, layer=obj,
+                                    input_spec=input_spec, name=name)
             obj.forward = static
             return obj
         if hasattr(obj, "__self__") and isinstance(obj.__self__, Layer):
             return StaticFunction(obj.__func__.__get__(obj.__self__),
-                                  layer=obj.__self__, input_spec=input_spec)
-        return StaticFunction(obj, layer=None, input_spec=input_spec)
+                                  layer=obj.__self__, input_spec=input_spec,
+                                  name=name)
+        return StaticFunction(obj, layer=None, input_spec=input_spec,
+                              name=name)
 
     if function is not None:
         return decorate(function)

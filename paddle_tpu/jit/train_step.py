@@ -123,7 +123,10 @@ class TrainStep:
             finally:
                 rnd.pop_trace_key()
 
-        def pure(params, slots, buffers, rng_key, lr, t, inputs, labels):
+        # the defs' names are the programs' (`jit_train_step` in a trace's
+        # `XLA Modules`), in the raw-key variant too
+        def train_step(params, slots, buffers, rng_key, lr, t, inputs,
+                       labels):
             # rng advance + step counter live IN the program: zero per-step
             # host->device scalar traffic
             step_key, carry_key = jax.random.split(rng_key)
@@ -131,7 +134,8 @@ class TrainStep:
                 params, slots, buffers, step_key, lr, t, inputs, labels)
             return new_params, new_slots, loss, carry_key, t + 1.0, bad
 
-        def pure_scan(params, slots, buffers, rng_key, lr, t, inputs, labels):
+        def train_step_scan(params, slots, buffers, rng_key, lr, t, inputs,
+                            labels):
             # Device-side training loop: N steps inside ONE executable via
             # lax.scan — the TPU answer to the reference's C++ trainer hot
             # loop (framework/trainer.h:59, hogwild_worker.cc TrainFiles),
@@ -157,17 +161,18 @@ class TrainStep:
         # can, so this adapter is a candidate for removal: PERF.md §7.)
         self._raw_key = _cc.enabled()
         if self._raw_key:
-            base_pure, base_scan = pure, pure_scan
+            base_step, base_scan = train_step, train_step_scan
 
-            def pure(params, slots, buffers, key_data, lr, t, inputs, labels):
-                new_params, new_slots, loss, carry, t1, bad = base_pure(
+            def train_step(params, slots, buffers, key_data, lr, t, inputs,
+                           labels):
+                new_params, new_slots, loss, carry, t1, bad = base_step(
                     params, slots, buffers,
                     jax.random.wrap_key_data(key_data), lr, t, inputs, labels)
                 return (new_params, new_slots, loss,
                         jax.random.key_data(carry), t1, bad)
 
-            def pure_scan(params, slots, buffers, key_data, lr, t,
-                          inputs, labels):
+            def train_step_scan(params, slots, buffers, key_data, lr, t,
+                                inputs, labels):
                 new_params, new_slots, losses, carry, t1, bads = base_scan(
                     params, slots, buffers,
                     jax.random.wrap_key_data(key_data), lr, t, inputs, labels)
@@ -176,8 +181,8 @@ class TrainStep:
 
         donate = (0, 1, 3, 5) if self._donate else ()
         self._donate_argnums = donate
-        self._jitted = jax.jit(pure, donate_argnums=donate)
-        self._jitted_scan = jax.jit(pure_scan, donate_argnums=donate)
+        self._jitted = jax.jit(train_step, donate_argnums=donate)
+        self._jitted_scan = jax.jit(train_step_scan, donate_argnums=donate)
         self._key = rnd.default_generator().next_key()
         if self._raw_key:
             self._key = jax.random.key_data(self._key)

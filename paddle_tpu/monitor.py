@@ -45,10 +45,11 @@ keeps `_ENABLED` in sync with `paddle.set_flags({"FLAGS_monitor": ...})`.
 
 Outputs: `snapshot()` (nested dict), `report()` (rendered table, the
 `Profiler.summary()` sibling), `export_json(path)`, `prometheus_text()` /
-`export_prometheus(path)`, and `span(name)` trace ranges that ALSO feed any
-active `paddle_tpu.profiler.Profiler`'s host-event stream so one chrome
-trace carries both planes (`Profiler.export` embeds `snapshot()` as trace
-metadata).
+`export_prometheus(path)`, and `span(name, **attrs)` trace ranges that ALSO
+enter a `jax.profiler.TraceAnnotation` (so a profiler trace carries them on
+the device plane's clock) and feed any active `paddle_tpu.profiler.Profiler`'s
+host-event stream, so one chrome trace carries both planes
+(`Profiler.export` embeds `snapshot()` as trace metadata).
 """
 from __future__ import annotations
 
@@ -59,6 +60,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from .core import flags as _flags
 
 __all__ = [
@@ -67,7 +70,7 @@ __all__ = [
     "counter", "gauge", "histogram",
     "count", "gauge_set", "observe", "log_event", "record_op",
     "record_collective", "record_retrace", "record_span",
-    "span", "snapshot", "report", "reset",
+    "span", "Span", "snapshot", "report", "reset",
     "mergeable_snapshot", "merge_snapshots",
     "export_json", "prometheus_text", "prometheus_text_multi",
     "export_prometheus",
@@ -519,41 +522,61 @@ _NULL_SPAN = _NullSpan()
 
 
 def record_span(name: str, t0: float, t1: float, kind: str = "span") -> None:
-    """Book one completed range: `span.<name>.count`/`.dur` metrics plus
-    every active Profiler's host-event stream (and thereby the chrome
-    trace). `monitor.span()` and the request-trace spans (obs/trace.py)
-    both land here, so one dispatcher feeds both export planes."""
-    _REGISTRY.counter(f"span.{name}.count").add(1)
-    _REGISTRY.histogram(f"span.{name}.dur").observe(t1 - t0)
+    """Book one completed range (wall-clock `t0`, `t1`): the
+    `span.<name>.count`/`.dur` metrics, when the monitor is on (a
+    `profiler.RecordEvent` is a Span that ignores the flag), plus every
+    active Profiler's host-event stream (and thereby the chrome trace).
+    `Span` and the request-trace spans (obs/trace.py) both land here, so
+    one dispatcher feeds both export planes."""
+    if _ENABLED:
+        _REGISTRY.counter(f"span.{name}.count").add(1)
+        _REGISTRY.histogram(f"span.{name}.dur").observe(t1 - t0)
     from . import profiler as _profiler
     for p in tuple(_profiler._ACTIVE_STACK):
         p._record_op(name, t0, t1, kind)
 
 
-class _Span:
-    __slots__ = ("name", "kind", "_t0")
+class Span:
+    """One host range, open for the `with` block's lifetime. It is a
+    `jax.profiler.TraceAnnotation(name, **attrs)`, so in a profiler trace
+    it lies on its thread's `/host:CPU` line, on the device plane's clock,
+    with `attrs` as the event's stats; nesting on one thread is the parent
+    link. On exit it books itself through `record_span`. `dur` comes from
+    `perf_counter`; `wall` is the `time.time()` start, which
+    `Profiler.export` writes as the chrome `ts`. Both stay readable after
+    the block for a caller that reports the same interval elsewhere."""
 
-    def __init__(self, name: str, kind: str):
+    __slots__ = ("name", "kind", "attrs", "wall", "dur", "_t0",
+                 "_annotation")
+
+    def __init__(self, name: str, kind: str = "span", **attrs):
         self.name = name
         self.kind = kind
-        self._t0 = 0.0
+        self.attrs = attrs
+        self.wall = self.dur = self._t0 = 0.0
+        self._annotation = None
 
     def __enter__(self):
-        self._t0 = time.time()
+        # a TraceAnnotation's start is its construction, not its __enter__
+        self._annotation = _TraceAnnotation(self.name, **self.attrs)
+        self._annotation.__enter__()
+        self.wall = time.time()
+        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        record_span(self.name, self._t0, time.time(), self.kind)
+        self.dur = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        record_span(self.name, self.wall, self.wall + self.dur, self.kind)
         return False
 
 
-def span(name: str, kind: str = "span"):
-    """Instrumentation range: `with monitor.span("stage"): ...`. Duration
-    lands in `span.<name>.dur`; when a Profiler is active the range also
-    appears on its host timeline. Disabled -> shared no-op context."""
+def span(name: str, kind: str = "span", **attrs):
+    """Instrumentation range: `with monitor.span("stage", step=3): ...`.
+    See `Span`. Disabled -> the shared no-op context."""
     if not _ENABLED:
         return _NULL_SPAN
-    return _Span(name, kind)
+    return Span(name, kind, **attrs)
 
 
 # ---- snapshots / reports / exporters ---------------------------------------

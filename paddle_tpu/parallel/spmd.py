@@ -260,6 +260,8 @@ class SPMDTrainStep:
             def jit_pure(params, slots, buffers, key_data, lr, t, batch):
                 return pure(params, slots, buffers,
                             jax.random.wrap_key_data(key_data), lr, t, batch)
+        # the program's name in a trace's `XLA Modules`: jit_spmd_train_step
+        jit_pure.__name__ = jit_pure.__qualname__ = "spmd_train_step"
         self._jitted = jax.jit(jit_pure, in_shardings=in_sh,
                                out_shardings=out_sh, donate_argnums=donate)
         self._pspecs = pspecs

@@ -14,7 +14,6 @@ Two planes, as in the reference:
 """
 from __future__ import annotations
 
-import contextlib
 import enum
 import json
 import os
@@ -22,6 +21,8 @@ import threading
 import time
 
 import jax
+
+from ..monitor import Span
 
 
 class ProfilerTarget(enum.Enum):
@@ -246,19 +247,12 @@ def _dispatch_hook(name, start, end, kind="op"):
         _EXTERNAL_HOOK(name, start, end)
 
 
-@contextlib.contextmanager
 def RecordEvent(name, event_type=None):
-    """Host-side instrumentation range (`platform/profiler/event_tracing.h`).
-    Recorded into every active Profiler's host events AND forwarded to the
-    XLA TraceMe so it shows up on the device timeline."""
-    t0 = time.time()
-    with jax.profiler.TraceAnnotation(name):
-        try:
-            yield
-        finally:
-            t1 = time.time()
-            for p in tuple(_ACTIVE_STACK):
-                p._record_op(name, t0, t1, kind="user")
+    """Host-side instrumentation range (`platform/profiler/event_tracing.h`):
+    a `monitor.Span` of kind "user" that does not wait for `FLAGS_monitor`.
+    It lands in every active Profiler's host events and, as a
+    `TraceAnnotation`, on the XLA profiler's timeline."""
+    return Span(name, "user")
 
 
 def load_profiler_result(filename):

@@ -54,7 +54,6 @@ import numpy as np
 from .. import faults as _faults
 from .. import monitor as _monitor
 from .. import nn
-from .. import obs as _obs
 from ..core import executable as _exe
 from ..core import flags as _flags
 from ..core.autograd import no_grad
@@ -320,8 +319,9 @@ class LLMEngine:
         self._decode = _DecodeNet(self.lm, self._n_layers,
                                   cfg.decode_block, cfg.kv_int8)
         from ..jit import to_static
-        to_static(self._prefill)
-        to_static(self._decode)
+        # the programs' names in a trace: jit_llm_prefill, jit_llm_decode
+        to_static(self._prefill, name="llm_prefill")
+        to_static(self._decode, name="llm_decode")
 
         import jax.numpy as jnp
         s = cfg.num_slots
@@ -482,7 +482,8 @@ class LLMEngine:
                     if self._stopped:
                         return
                     if not self._pending and not self._active:
-                        self._work.wait(timeout=self.config.idle_park_s)
+                        with _monitor.span("llm.park"):
+                            self._work.wait(timeout=self.config.idle_park_s)
                         if self._stopped:
                             return
                     pending_now = bool(self._pending)
@@ -490,16 +491,21 @@ class LLMEngine:
                     self._admit()
                 if self._active:
                     try:
-                        self._step()
+                        with _monitor.span("llm.step",
+                                           slots=len(self._active)):
+                            self._step()
                     except Exception as e:  # scheduler must survive
                         self._evict_all("error",
                                         f"{type(e).__name__}: {e}")
 
-    def _admit(self) -> None:
+    def _next_admission(self) -> Optional[_Seq]:
+        """The next pending sequence, moved into a free slot, or None when
+        the queue or the pool has nothing to give. A sequence whose
+        deadline passed in the queue is finished here, never admitted."""
         while True:
             with self._lock:
                 if not self._free or not self._pending:
-                    return
+                    return None
                 seq = self._pending.popleft()
                 slot = self._free.pop()
             now = time.monotonic()
@@ -509,7 +515,17 @@ class LLMEngine:
                 self._finish(seq, "deadline", "expired before admission")
                 continue
             seq.slot, seq.admit_t = slot, now
-            self._prefill_into(seq)
+            return seq
+
+    def _admit(self) -> None:
+        seq = self._next_admission()
+        if seq is None:
+            return
+        # one span per call that admits: the stall every stream sees
+        with _monitor.span("llm.admit"):
+            while seq is not None:
+                self._prefill_into(seq)
+                seq = self._next_admission()
 
     def _prefill_into(self, seq: _Seq) -> None:
         import jax
@@ -518,14 +534,16 @@ class LLMEngine:
         from ..ops._dispatch import run_op
 
         cfg = self.config
+        rid = seq.stream.request_id
         plen = int(seq.prompt.size)
         lb = next(b for b in self.buckets if b >= plen)
-        padded = np.zeros((1, lb), np.int32)
-        padded[0, :plen] = seq.prompt
-        outs = self._prefill(Tensor(jnp.asarray(padded)),
-                             Tensor(jnp.full((1,), plen, jnp.int32)))
-        first = int(np.asarray(outs[0].numpy())[0])
-        slot_t = Tensor(jnp.asarray(seq.slot, jnp.int32))
+        with _monitor.span("llm.prefill", request_id=rid, bucket=lb,
+                           prompt_len=plen):
+            padded = np.zeros((1, lb), np.int32)
+            padded[0, :plen] = seq.prompt
+            outs = self._prefill(Tensor(jnp.asarray(padded)),
+                                 Tensor(jnp.full((1,), plen, jnp.int32)))
+            first = int(np.asarray(outs[0].numpy())[0])
 
         def _row(pool, row, s):
             return jax.lax.dynamic_update_slice(pool, row, (s, 0, 0, 0))
@@ -534,34 +552,38 @@ class LLMEngine:
             return jax.lax.dynamic_update_slice(vec, val, (s,))
 
         pages = outs[2:2 + 2 * self._n_layers]
-        for i, page in enumerate(pages):
-            self._pool[i] = run_op(_row, [self._pool[i], page, slot_t],
-                                   "llm_slot_write")
-        if cfg.kv_int8:
-            svals = outs[2 + 2 * self._n_layers:]
+        svals = outs[2 + 2 * self._n_layers:] if cfg.kv_int8 else ()
+        with _monitor.span("llm.slot_write", request_id=rid,
+                           writes=len(pages) + len(svals)):
+            slot_t = Tensor(jnp.asarray(seq.slot, jnp.int32))
+            for i, page in enumerate(pages):
+                self._pool[i] = run_op(_row, [self._pool[i], page, slot_t],
+                                       "llm_slot_write")
             for i, sv in enumerate(svals):
                 self._scales[i] = run_op(_cell, [self._scales[i], sv, slot_t],
                                          "llm_scale_write")
-        now = time.monotonic()
-        seq.pos = plen
-        seq.last_token = first
-        seq.last_emit_t = now
-        seq.stream.status = "running"
-        seq.stream._emit(first)
-        with self._lock:
-            self._active[seq.slot] = seq
-        if _monitor._ENABLED:
-            _monitor.count("llm.prefill.requests")
-            _monitor.count("llm.tokens_generated")
-            _monitor.observe("llm.queue_wait", seq.admit_t - seq.submit_t)
-            _monitor.observe("llm.ttft_ms", (now - seq.submit_t) * 1000.0)
-            _monitor.gauge_set("llm.slots_active", len(self._active))
-        self._retag_pool()
-        # a one-token budget (or instant EOS) finishes without decoding
-        if first == cfg.eos_token_id:
-            self._evict(seq, "eos")
-        elif len(seq.stream.tokens) >= seq.max_new:
-            self._evict(seq, "length")
+        with _monitor.span("llm.emit"):
+            now = time.monotonic()
+            seq.pos = plen
+            seq.last_token = first
+            seq.last_emit_t = now
+            seq.stream.status = "running"
+            seq.stream._emit(first)
+            with self._lock:
+                self._active[seq.slot] = seq
+            if _monitor._ENABLED:
+                _monitor.count("llm.prefill.requests")
+                _monitor.count("llm.tokens_generated")
+                _monitor.observe("llm.queue_wait", seq.admit_t - seq.submit_t)
+                _monitor.observe("llm.ttft_ms",
+                                 (now - seq.submit_t) * 1000.0)
+                _monitor.gauge_set("llm.slots_active", len(self._active))
+            self._retag_pool()
+            # a one-token budget (or instant EOS) finishes without decoding
+            if first == cfg.eos_token_id:
+                self._evict(seq, "eos")
+            elif len(seq.stream.tokens) >= seq.max_new:
+                self._evict(seq, "length")
 
     def _step(self) -> None:
         """One decode step for every active slot: fault drill, dispatch,
@@ -588,50 +610,45 @@ class LLMEngine:
             live = sorted(self._active.items())
         if not live:
             return
-        s = cfg.num_slots
-        toks = np.zeros((s,), np.int32)
-        pos = np.zeros((s,), np.int32)
-        for slot, seq in live:
-            toks[slot] = seq.last_token
-            pos[slot] = seq.pos
-
-        def _dispatch():
-            report = lambda: {"kv_pool_bytes": self.kv_pool_bytes()}
-            with _exe.dispatch_guard("llm_decode", report=report), \
-                    _monitor.span("llm.decode_step"):
-                return self._decode(Tensor(jnp.asarray(toks)),
-                                    Tensor(jnp.asarray(pos)),
-                                    *self._pool, *self._scales)
-
-        if _obs._TL_ENABLED and not _obs.in_phase():
-            with _obs.timeline().phase("decode_step"):
-                outs = _dispatch()
-        else:
-            outs = _dispatch()
-        nxt = np.asarray(outs[0].numpy())
-        self._pool = list(outs[2:2 + 2 * self._n_layers])
-        now = time.monotonic()
-        for slot, seq in live:
-            tok = int(nxt[slot])
-            seq.pos += 1
-            seq.last_token = tok
-            seq.stream._emit(tok)
+        report = lambda: {"kv_pool_bytes": self.kv_pool_bytes()}
+        with _monitor.span("llm.decode.dispatch"), \
+                _exe.dispatch_guard("llm_decode", report=report):
+            s = cfg.num_slots
+            toks = np.zeros((s,), np.int32)
+            pos = np.zeros((s,), np.int32)
+            for slot, seq in live:
+                toks[slot] = seq.last_token
+                pos[slot] = seq.pos
+            outs = self._decode(Tensor(jnp.asarray(toks)),
+                                Tensor(jnp.asarray(pos)),
+                                *self._pool, *self._scales)
+        # the wait for the device and the d2h copy of the tokens
+        with _monitor.span("llm.decode.read"):
+            nxt = np.asarray(outs[0].numpy())
+        with _monitor.span("llm.emit"):
+            self._pool = list(outs[2:2 + 2 * self._n_layers])
+            now = time.monotonic()
+            for slot, seq in live:
+                tok = int(nxt[slot])
+                seq.pos += 1
+                seq.last_token = tok
+                seq.stream._emit(tok)
+                if _monitor._ENABLED:
+                    _monitor.count("llm.tokens_generated")
+                    _monitor.observe("llm.inter_token_ms",
+                                     (now - seq.last_emit_t) * 1000.0)
+                seq.last_emit_t = now
+                if tok == cfg.eos_token_id:
+                    self._evict(seq, "eos")
+                elif len(seq.stream.tokens) >= seq.max_new \
+                        or seq.pos >= cfg.max_len:
+                    self._evict(seq, "length")
+                elif seq.deadline is not None and now > seq.deadline:
+                    self._evict(seq, "deadline")
             if _monitor._ENABLED:
-                _monitor.count("llm.tokens_generated")
-                _monitor.observe("llm.inter_token_ms",
-                                 (now - seq.last_emit_t) * 1000.0)
-            seq.last_emit_t = now
-            if tok == cfg.eos_token_id:
-                self._evict(seq, "eos")
-            elif len(seq.stream.tokens) >= seq.max_new \
-                    or seq.pos >= cfg.max_len:
-                self._evict(seq, "length")
-            elif seq.deadline is not None and now > seq.deadline:
-                self._evict(seq, "deadline")
-        if _monitor._ENABLED:
-            _monitor.count("llm.decode.steps")
-            _monitor.gauge_set("llm.slots_active", len(self._active))
-        self._retag_pool()
+                _monitor.count("llm.decode.steps")
+                _monitor.gauge_set("llm.slots_active", len(self._active))
+            self._retag_pool()
 
     # ---- eviction / bookkeeping --------------------------------------------
 

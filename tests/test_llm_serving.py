@@ -11,7 +11,7 @@ import pytest
 
 import paddle_tpu as paddle
 import paddle_tpu.monitor as monitor
-from paddle_tpu import faults, obs
+from paddle_tpu import faults
 from paddle_tpu.models.gpt import GPTForCausalLM, GPTModel
 from paddle_tpu.serving import (EngineStoppedError, LLMConfig, LLMEngine,
                                 ServerOverloadedError, ServingError)
@@ -204,23 +204,6 @@ class TestContinuousBatching:
         out = capsys.readouterr().out
         assert "llm.tokens_generated" in out
         assert "llm.ttft_ms" in out
-
-    def test_decode_step_phase_in_timeline(self, monitored):
-        paddle.set_flags({"FLAGS_obs_timeline": True})
-        lm = _build_lm()
-        eng = LLMEngine(lm, LLMConfig(num_slots=2, max_len=16,
-                                      max_new_tokens=6)).start()
-        try:
-            assert eng.submit([9, 2]).result(timeout=60.0)[0] == "done"
-            # decode steps run between training steps: close one empty
-            # step record so the pending between-steps bucket is visible
-            with obs.timeline().step_record():
-                pass
-            rec = obs.timeline().records()[-1]
-            assert rec["between"].get("decode_step", 0.0) > 0.0
-        finally:
-            eng.stop()
-            paddle.set_flags({"FLAGS_obs_timeline": False})
 
     def test_interleaving_later_short_request_finishes_first(self):
         lm = _build_lm()
