@@ -32,7 +32,8 @@ from .input_spec import InputSpec  # noqa: F401  (re-export)
 
 
 class StaticFunction:
-    def __init__(self, function, layer=None, input_spec=None, name=None):
+    def __init__(self, function, layer=None, input_spec=None, name=None,
+                 donate_inputs=None):
         # the compiled program's name (`jit_<name>` in an HLO dump and in a
         # profiler trace's `XLA Modules`): a reader picks a program by it
         self._name = name or (type(layer).__name__ if layer is not None
@@ -44,6 +45,10 @@ class StaticFunction:
             self._function = function
         self._layer = layer
         self._input_spec = input_spec
+        # which positional inputs the caller gives away to the program
+        # (indices or a slice of them): only the owner of a buffer may
+        # say so, hence an argument of the one call that wraps, not a flag
+        self._donate_inputs = donate_inputs
         self._jit_cache = {}
         # executable substrate: only a NOVEL signature is a recompile —
         # alternating between two known shapes (e.g. the serving engine
@@ -111,10 +116,31 @@ class StaticFunction:
             self._jit_cache[key] = pure
         return pure
 
+    def _donated(self, n_inputs):
+        """Indices of the positional inputs that are donated, ascending."""
+        d = self._donate_inputs
+        if d is None:
+            return ()
+        if isinstance(d, slice):
+            return tuple(range(n_inputs)[d])
+        return tuple(sorted({range(n_inputs)[int(i)] for i in d}))
+
+    @staticmethod
+    def _call_args(params, buffers, karg, inputs, donated=()):
+        """The jitted program's arguments. Donated inputs travel as an
+        argument of their own (the only unit `donate_argnums` knows);
+        with none, the four arguments every capture has always had."""
+        if not donated:
+            return params, buffers, karg, inputs
+        given = set(donated)
+        return (params, buffers, karg,
+                [a for i, a in enumerate(inputs) if i not in given],
+                [inputs[i] for i in donated])
+
     def _get_jitted(self, training, pnames, bnames, static_kwargs,
-                    raw_key=False):
+                    raw_key=False, donated=()):
         key = ("jit", training, tuple(pnames), tuple(bnames),
-               tuple(sorted(static_kwargs.items())), raw_key)
+               tuple(sorted(static_kwargs.items())), raw_key, donated)
         jitted = self._jit_cache.get(key)
         if jitted is None:
             if _monitor._ENABLED:
@@ -133,8 +159,18 @@ class StaticFunction:
                                 jax.random.wrap_key_data(key_data),
                                 input_arrays)
 
+            if donated:
+                whole = pure
+
+                def pure(param_arrays, buffer_arrays, rng_key, kept_arrays,
+                         donated_arrays):
+                    inputs = list(kept_arrays)
+                    for i, a in zip(donated, donated_arrays):  # ascending
+                        inputs.insert(i, a)
+                    return whole(param_arrays, buffer_arrays, rng_key, inputs)
+
             pure.__name__ = pure.__qualname__ = self._name
-            jitted = jax.jit(pure)
+            jitted = jax.jit(pure, donate_argnums=(4,) if donated else ())
             self._jit_cache[key] = jitted
         return jitted
 
@@ -200,6 +236,7 @@ class StaticFunction:
 
             key = rnd.default_generator().next_key()
             n_p = len(ptensors)
+            donated = self._donated(len(input_tensors))
             diff_inputs = ptensors + input_tensors
             arrays = [t._value for t in diff_inputs]
             # persistent-cache mode rides the raw-key-data program variant
@@ -223,10 +260,12 @@ class StaticFunction:
                     # steady-state call path never reaches this check
                     _analysis.lint_traced(self._function, "to_static")
                 jitted = self._get_jitted(training, pnames, bnames,
-                                          static_kwargs, raw)
+                                          static_kwargs, raw, donated)
 
-                def fn(*arrs, _jit=jitted, _b=list(barrs), _k=karg, _np=n_p):
-                    return _jit(list(arrs[:_np]), _b, _k, list(arrs[_np:]))
+                def fn(*arrs, _jit=jitted, _b=list(barrs), _k=karg, _np=n_p,
+                       _d=donated):
+                    return _jit(*StaticFunction._call_args(
+                        list(arrs[:_np]), _b, _k, list(arrs[_np:]), _d))
 
                 from ..static.program import Program, _set_default_program
                 specs = [jax.ShapeDtypeStruct(t._value.shape, t._value.dtype)
@@ -242,7 +281,9 @@ class StaticFunction:
                                   for a in arrays))
         if not record:
             jitted = self._get_jitted(training, pnames, bnames,
-                                      static_kwargs, raw)
+                                      static_kwargs, raw, donated)
+            call_args = self._call_args(arrays[:n_p], barrs, karg,
+                                        arrays[n_p:], donated)
             csig = (sig, training, tuple(sorted(static_kwargs.items())), raw)
             with _exe.booking("to_static") as bk:
                 call = self._ledger.get(csig)
@@ -250,8 +291,8 @@ class StaticFunction:
                     call = jitted
                     if raw:
                         call, source = _exe.acquire(
-                            "to_static", jitted,
-                            (arrays[:n_p], barrs, karg, arrays[n_p:]),
+                            "to_static", jitted, call_args,
+                            donate=(4,) if donated else (),
                             label=getattr(self._function, "__name__",
                                           "to_static"))
                         self._ledger.put(csig, call)
@@ -262,8 +303,13 @@ class StaticFunction:
                 elif novel:
                     bk.compiled()
                 with call_span:
-                    out = call(arrays[:n_p], barrs, karg, arrays[n_p:])
+                    out = call(*call_args)
         else:
+            if donated:
+                raise RuntimeError(
+                    "to_static: donate_inputs gives buffers away, so the "
+                    "call cannot be recorded for backward; call it under "
+                    "no_grad()")
             with _exe.booking("to_static") as bk:
                 if novel:
                     bk.compiled()
@@ -313,24 +359,31 @@ class StaticFunction:
 
 
 def to_static(function=None, input_spec=None, build_strategy=None, backend=None,
-              name=None, **kwargs):
+              name=None, donate_inputs=None, **kwargs):
     """Decorator/wrapper. Accepts a Layer, a Layer's bound forward, or a pure
     function of Tensors. `name` names the compiled program (`jit_<name>`);
-    the default is the Layer's class or the function's own name."""
+    the default is the Layer's class or the function's own name.
+
+    `donate_inputs` (indices or a slice of the positional tensor inputs)
+    hands those inputs' buffers to the program, which may then update them
+    in place and alias them to its outputs: after a call they are deleted
+    and the caller goes on with what the call returned. Only the owner of
+    the buffers may ask for it; such a function runs under `no_grad()`."""
 
     def decorate(obj):
         from ..nn.layer.layers import Layer
         if isinstance(obj, Layer):
             static = StaticFunction(obj.forward, layer=obj,
-                                    input_spec=input_spec, name=name)
+                                    input_spec=input_spec, name=name,
+                                    donate_inputs=donate_inputs)
             obj.forward = static
             return obj
         if hasattr(obj, "__self__") and isinstance(obj.__self__, Layer):
             return StaticFunction(obj.__func__.__get__(obj.__self__),
                                   layer=obj.__self__, input_spec=input_spec,
-                                  name=name)
+                                  name=name, donate_inputs=donate_inputs)
         return StaticFunction(obj, layer=None, input_spec=input_spec,
-                              name=name)
+                              name=name, donate_inputs=donate_inputs)
 
     if function is not None:
         return decorate(function)

@@ -57,14 +57,15 @@ class GPTModel(nn.Layer):
 
     def init_kv_cache(self, batch_size, max_len, dtype="float32"):
         """Fresh zero KV pages for forward_cached: one (k, v) pair per
-        layer, each [batch, max_len, num_heads, head_dim]. dtype "int8"
-        builds the quantized-KV pages (scales start as None and are
-        computed by the first forward_cached call)."""
+        layer, each [batch, max_len, num_heads * head_dim] (a position is
+        one contiguous row: `ErnieSelfAttention.forward_cached`). dtype
+        "int8" builds the quantized-KV pages (scales start as None and
+        are computed by the first forward_cached call)."""
         import jax.numpy as jnp
 
         from ..core.tensor import Tensor
         attn = self.layers[0].attention
-        shape = (batch_size, max_len, attn.num_heads, attn.head_dim)
+        shape = (batch_size, max_len, attn.num_heads * attn.head_dim)
         return [(Tensor(jnp.zeros(shape, dtype=dtype)),
                  Tensor(jnp.zeros(shape, dtype=dtype)))
                 for _ in self.layers]
@@ -73,7 +74,7 @@ class GPTModel(nn.Layer):
         """Prefill/decode step over explicit KV-cache carries.
 
         input_ids [B, T]; past_kv: list over layers of (k, v) fixed-shape
-        pages [B, L, nh, hd]; positions [B] int32 tokens-already-cached
+        pages [B, L, nh*hd]; positions [B] int32 tokens-already-cached
         per row (also the position-embedding offset). kv_scales: list of
         (k_scale, v_scale) [B] pairs for int8 pages, or None.
         Returns (hidden, new_past_kv, new_kv_scales)."""

@@ -5,16 +5,24 @@ ResNet/OCR, wrong for decoders, where per-request full-sequence recompute
 wastes nearly all decode FLOPs and fixed batches idle between stragglers.
 This module serves GPT/ERNIE decoders the way LLM traffic actually wants:
 
-- **KV cache as explicit carry** — `GPTForCausalLM.forward_cached` takes
-  fixed-shape cache pages in and returns updated pages, so a decode step
-  is one-token work instead of a full-sequence forward.
+- **KV cache as state the decode program updates in place** —
+  `GPTForCausalLM.forward_cached` takes fixed-shape cache pages in and
+  returns updated pages, so a decode step is one-token work instead of a
+  full-sequence forward. The engine owns the pool and donates it into
+  `jit_llm_decode` (`to_static(..., donate_inputs=...)`): the program
+  writes one row a slot into the buffers it was given and aliases them to
+  its outputs, so the pool is live once, not twice, and no step copies
+  it. `self._pool` is the program's output pages from the moment the
+  dispatch returns; the pages passed in are deleted
+  (`llm.decode.pool_donated` counts the steps where they were).
 - **Slot-paged fixed-shape pool** — per layer, one `[num_slots, page_len,
-  heads, head_dim]` array pair. Sequences borrow a slot for their
-  lifetime; shapes never depend on which slots are live, so steady state
-  runs exactly two kinds of cached executables — one prefill per length
-  bucket, one decode — with ZERO steady-state compiles (the `jit.*`
-  retrace counters stay flat; tests assert it). Pool bytes carry the
-  `mem.kv_pool.bytes` census tag.
+  heads * head_dim]` array pair (a cached position is one contiguous
+  row on the device, so writing it touches that row only). Sequences
+  borrow a slot for their lifetime; shapes never depend on which slots
+  are live, so steady state runs exactly two kinds of cached executables
+  — one prefill per length bucket, one decode — with ZERO steady-state
+  compiles (the `jit.*` retrace counters stay flat; tests assert it).
+  Pool bytes carry the `mem.kv_pool.bytes` census tag.
 - **Continuous scheduler** — every decode step admits queued sequences
   into free slots and evicts on EOS/length/deadline, streaming each token
   to the caller the moment it exists (and over the wire as `'PDST'`
@@ -92,7 +100,12 @@ class LLMConfig:
     (max_len + decode_block) * heads * head_dim * itemsize — fp32
     itemsize 4, kv_int8 itemsize 1 (+ two f32 scales per slot per
     layer). `LLMEngine.kv_pool_bytes()` reports the real figure and the
-    census publishes it as `mem.kv_pool.bytes`."""
+    census publishes it as `mem.kv_pool.bytes`. That is what the pool
+    costs on the device: the decode program takes it donated, so beside
+    the weights a deployment budgets the pool once (the TPU pads the
+    position axis to its tile of 8 rows in fp32: 1026 positions occupy
+    1032), plus one more pool array (one layer's K or V) while an
+    admission writes its slot out of place."""
 
     num_slots: int = 8
     max_len: int = 256
@@ -319,18 +332,17 @@ class LLMEngine:
         self._decode = _DecodeNet(self.lm, self._n_layers,
                                   cfg.decode_block, cfg.kv_int8)
         from ..jit import to_static
-        # the programs' names in a trace: jit_llm_prefill, jit_llm_decode
+        # the programs' names in a trace: jit_llm_prefill, jit_llm_decode.
+        # The engine owns the pool, so it alone may give it away: the
+        # decode program takes the pages (its inputs after tokens and
+        # positions) donated, writes them in place and aliases them out.
         to_static(self._prefill, name="llm_prefill")
-        to_static(self._decode, name="llm_decode")
+        to_static(self._decode, name="llm_decode",
+                  donate_inputs=slice(2, 2 + 2 * self._n_layers))
 
         import jax.numpy as jnp
         s = cfg.num_slots
-        shape = (s, self._page_len, self._heads, self._head_dim)
-        kdt = jnp.int8 if cfg.kv_int8 else jnp.float32
-        self._pool: List[Tensor] = []   # k0, v0, k1, v1, ...
-        for _ in range(self._n_layers):
-            self._pool += [Tensor(jnp.zeros(shape, kdt)),
-                           Tensor(jnp.zeros(shape, kdt))]
+        self._pool: List[Tensor] = self._zero_pool()   # k0, v0, k1, v1, ...
         self._scales: List[Tensor] = []  # ks0, vs0, ... ([S] f32 per slot)
         if cfg.kv_int8:
             for _ in range(self._n_layers):
@@ -349,6 +361,35 @@ class LLMEngine:
                           "evictions.eos": 0, "evictions.length": 0,
                           "evictions.deadline": 0, "evictions.error": 0}
         self._warm_ms = 0.0
+
+    def _zero_pool(self) -> List[Tensor]:
+        import jax.numpy as jnp
+        cfg = self.config
+        shape = (cfg.num_slots, self._page_len, self._heads * self._head_dim)
+        kdt = jnp.int8 if cfg.kv_int8 else jnp.float32
+        return [Tensor(jnp.zeros(shape, kdt))
+                for _ in range(2 * self._n_layers)]
+
+    def _decode_pool(self, tokens, positions):
+        """Run the decode program on (tokens, positions) and the pool,
+        which it consumes: `self._pool` is its output pages from the
+        moment the dispatch returns, so no other thread and no later line
+        ever holds a deleted page. Returns (outs, donated) — donated is
+        whether the old buffers were really given away (a host attribute
+        read). A dispatch that fails after taking the pool leaves a zero
+        pool behind; every sequence is lost with it either way."""
+        import jax.numpy as jnp
+        old = self._pool[0]._value
+        try:
+            outs = self._decode(Tensor(jnp.asarray(tokens)),
+                                Tensor(jnp.asarray(positions)),
+                                *self._pool, *self._scales)
+        except BaseException:
+            if any(t._value.is_deleted() for t in self._pool):
+                self._pool = self._zero_pool()
+            raise
+        self._pool = list(outs[2:2 + 2 * self._n_layers])
+        return outs, old.is_deleted()
 
     # ---- lifecycle ---------------------------------------------------------
 
@@ -372,9 +413,10 @@ class LLMEngine:
                 self._prefill(Tensor(jnp.zeros((1, lb), jnp.int32)),
                               Tensor(jnp.ones((1,), jnp.int32)))
             s = self.config.num_slots
-            self._decode(Tensor(jnp.zeros((s,), jnp.int32)),
-                         Tensor(jnp.zeros((s,), jnp.int32)),
-                         *self._pool, *self._scales)
+            # the pool is zeros and no slot is live: the junk rows this
+            # writes at position 0 are never read
+            self._decode_pool(np.zeros((s,), np.int32),
+                              np.zeros((s,), np.int32))
         self._warm_ms = (time.monotonic() - t0) * 1000.0
         if _monitor._ENABLED:
             _monitor.gauge_set("llm.warm_start_ms", self._warm_ms)
@@ -546,7 +588,7 @@ class LLMEngine:
             first = int(np.asarray(outs[0].numpy())[0])
 
         def _row(pool, row, s):
-            return jax.lax.dynamic_update_slice(pool, row, (s, 0, 0, 0))
+            return jax.lax.dynamic_update_slice(pool, row, (s, 0, 0))
 
         def _cell(vec, val, s):
             return jax.lax.dynamic_update_slice(vec, val, (s,))
@@ -588,8 +630,6 @@ class LLMEngine:
     def _step(self) -> None:
         """One decode step for every active slot: fault drill, dispatch,
         emit, evict. Fixed shapes — occupancy is data, not signature."""
-        import jax.numpy as jnp
-
         cfg = self.config
         now = time.monotonic()
         with self._lock:
@@ -619,14 +659,11 @@ class LLMEngine:
             for slot, seq in live:
                 toks[slot] = seq.last_token
                 pos[slot] = seq.pos
-            outs = self._decode(Tensor(jnp.asarray(toks)),
-                                Tensor(jnp.asarray(pos)),
-                                *self._pool, *self._scales)
+            outs, donated = self._decode_pool(toks, pos)
         # the wait for the device and the d2h copy of the tokens
         with _monitor.span("llm.decode.read"):
             nxt = np.asarray(outs[0].numpy())
         with _monitor.span("llm.emit"):
-            self._pool = list(outs[2:2 + 2 * self._n_layers])
             now = time.monotonic()
             for slot, seq in live:
                 tok = int(nxt[slot])
@@ -647,6 +684,8 @@ class LLMEngine:
                     self._evict(seq, "deadline")
             if _monitor._ENABLED:
                 _monitor.count("llm.decode.steps")
+                if donated:
+                    _monitor.count("llm.decode.pool_donated")
                 _monitor.gauge_set("llm.slots_active", len(self._active))
             self._retag_pool()
 

@@ -83,42 +83,55 @@ class TestCachedForwardBitIdentity:
     names from when XLA-CPU happened to make the two bitwise equal at
     decode_block=2; jax 0.9's does not, and nothing may lean on it.)"""
 
+    @pytest.mark.parametrize("lengths", [(4,), (2, 57)],
+                             ids=["one_row", "rows_near_0_and_near_the_end"])
     @pytest.mark.parametrize("lazy", [False, True],
                              ids=["eager", "lazy_eager"])
-    def test_decode_bit_identical_to_full_forward(self, lazy):
+    def test_decode_bit_identical_to_full_forward(self, lazy, lengths):
+        """`lengths`: the prompt length of each cache row. With two rows
+        one decode step writes at two different positions — one near 0,
+        one near the end of the page — and each row must still be its own
+        sequence's full forward."""
         lm = _build_lm()
         paddle.set_flags({"FLAGS_lazy_eager": lazy,
                           "FLAGS_eager_auto_jit": False})
         try:
-            prompt = [5, 17, 3, 8]
-            page_len = 16
-            kv = lm.gpt.init_kv_cache(1, page_len)
-            pos = paddle.to_tensor(np.zeros((1,), np.int32))
-            logits, kv, _ = lm.forward_cached(
-                paddle.to_tensor(np.asarray([prompt], np.int32)), kv, pos)
-            full = lm(paddle.to_tensor(np.asarray([prompt], np.int32)))
-            # prefill logits ARE the full forward's logits
-            np.testing.assert_allclose(np.asarray(logits.numpy()),
-                                       np.asarray(full.numpy()),
-                                       rtol=0, atol=_F32_ATOL)
-            seq = list(prompt)
-            nxt = int(np.asarray(logits.numpy())[0, -1].argmax())
+            rng = np.random.default_rng(5)
+            seqs = [rng.integers(0, 64, n).tolist() for n in lengths]
+            page_len = 64
+            pages, nxt = [], []
+            for prompt in seqs:         # prefill row by row
+                kv = lm.gpt.init_kv_cache(1, page_len)
+                pos = paddle.to_tensor(np.zeros((1,), np.int32))
+                logits, kv, _ = lm.forward_cached(
+                    paddle.to_tensor(np.asarray([prompt], np.int32)), kv, pos)
+                full = lm(paddle.to_tensor(np.asarray([prompt], np.int32)))
+                # prefill logits ARE the full forward's logits
+                np.testing.assert_allclose(np.asarray(logits.numpy()),
+                                           np.asarray(full.numpy()),
+                                           rtol=0, atol=_F32_ATOL)
+                pages.append(kv)
+                nxt.append(int(np.asarray(logits.numpy())[0, -1].argmax()))
+            kv = [tuple(paddle.to_tensor(np.concatenate(
+                [np.asarray(row[i][j].numpy()) for row in pages]))
+                for j in range(2)) for i in range(len(pages[0]))]
             for _ in range(4):
                 # decode block: row 0 = the real token, row 1 = junk that
                 # the next step overwrites before any mask admits it
-                blk = np.asarray([[nxt, 0]], np.int32)
+                blk = np.asarray([[t, 0] for t in nxt], np.int32)
                 positions = paddle.to_tensor(
-                    np.asarray([len(seq)], np.int32))
+                    np.asarray([len(s) for s in seqs], np.int32))
                 logits, kv, _ = lm.forward_cached(
                     paddle.to_tensor(blk), kv, positions)
-                seq.append(nxt)
-                full = lm(paddle.to_tensor(np.asarray([seq], np.int32)))
-                got = np.asarray(logits.numpy())[0, 0]
-                want = np.asarray(full.numpy())[0, -1]
-                np.testing.assert_allclose(got, want, rtol=0,
-                                           atol=_F32_ATOL)
-                assert got.argmax() == want.argmax()
-                nxt = int(got.argmax())
+                for r, seq in enumerate(seqs):
+                    seq.append(nxt[r])
+                    full = lm(paddle.to_tensor(np.asarray([seq], np.int32)))
+                    got = np.asarray(logits.numpy())[r, 0]
+                    want = np.asarray(full.numpy())[0, -1]
+                    np.testing.assert_allclose(got, want, rtol=0,
+                                               atol=_F32_ATOL)
+                    assert got.argmax() == want.argmax()
+                    nxt[r] = int(got.argmax())
         finally:
             paddle.set_flags({"FLAGS_lazy_eager": False,
                               "FLAGS_eager_auto_jit": False})
@@ -481,4 +494,103 @@ class TestFaultContainment:
             snap = monitor.snapshot()["counters"]
             assert snap["llm.evictions.deadline"] == 1
         finally:
+            eng.stop()
+
+
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["fp32", "kv_int8"])
+class TestDonatedPool:
+    """The decode program takes the KV pool donated (it updates the pages
+    in place and aliases them out): the engine must never be left holding
+    a page the program consumed."""
+
+    def _engine(self, kv_int8, **kw):
+        cfg = dict(num_slots=3, max_len=32, max_new_tokens=8,
+                   kv_int8=kv_int8)
+        cfg.update(kw)
+        return LLMEngine(_build_lm(), LLMConfig(**cfg))
+
+    @staticmethod
+    def _live(eng):
+        return not any(t._value.is_deleted()
+                       for t in (*eng._pool, *eng._scales))
+
+    def test_pool_is_live_after_warmup_admission_and_step(self, kv_int8,
+                                                          monitored):
+        eng = self._engine(kv_int8)
+        before = [t._value for t in eng._pool]
+        eng._warmup()
+        assert self._live(eng)
+        assert all(a.is_deleted() for a in before)  # consumed, not copied
+        eng.start()
+        try:
+            stream = eng.submit([3, 1, 4])
+            stream.result(timeout=60.0)
+            assert self._live(eng)                   # admission + steps
+            assert eng.submit([2, 7]).result(timeout=60.0)[0] == "done"
+            assert self._live(eng)
+        finally:
+            eng.stop()
+        snap = monitor.snapshot()["counters"]
+        assert snap["llm.decode.steps"] > 0
+        assert snap["llm.decode.pool_donated"] == snap["llm.decode.steps"]
+        assert snap["span.llm.decode.dispatch.count"] == \
+            snap["llm.decode.steps"]
+
+    def test_introspection_from_another_thread_never_sees_a_dead_page(
+            self, kv_int8):
+        eng = self._engine(kv_int8, max_new_tokens=24).start()
+        want = eng.kv_pool_bytes()
+        errors, stop = [], threading.Event()
+
+        def hammer():
+            while not stop.is_set():
+                try:
+                    assert eng.kv_pool_bytes() == want
+                    assert eng.stats()["kv_pool_bytes"] == want
+                except Exception as e:   # noqa: BLE001 - the test's catch
+                    errors.append(repr(e))
+                    return
+
+        t = threading.Thread(target=hammer, daemon=True)
+        t.start()
+        try:
+            streams = [eng.submit(p) for p in ([5, 6], [1], [9, 9, 9])]
+            assert all(s.result(timeout=120.0)[0] == "done"
+                       for s in streams)
+        finally:
+            stop.set()
+            t.join(timeout=10.0)
+            eng.stop()
+        assert not errors
+
+    def test_failure_inside_the_dispatch_leaves_a_serving_engine(
+            self, kv_int8):
+        """The program consumed the pool and the dispatch then raised:
+        the sequences in flight are lost, the next request is served."""
+        eng = self._engine(kv_int8)
+        ref = _ref_generate(eng.lm, [4, 2], 8) if not kv_int8 else None
+        real = eng._decode
+        armed = {"n": 0}
+
+        def failing(*a, **kw):
+            out = real(*a, **kw)
+            if armed["n"]:
+                armed["n"] -= 1
+                raise RuntimeError("device fell over after the dispatch")
+            return out
+
+        eng._decode = failing
+        eng.start()
+        armed["n"] = 1
+        try:
+            status, _ = eng.submit([4, 2]).result(timeout=60.0)
+            assert status == "error"
+            assert self._live(eng)
+            assert eng.stats()["free"] == 3
+            status, toks = eng.submit([4, 2]).result(timeout=60.0)
+            assert status == "done" and len(toks) == 8
+            if ref is not None:
+                assert toks == ref
+        finally:
+            eng._decode = real
             eng.stop()

@@ -229,15 +229,17 @@ def test_engine_programs_lower_under_their_names(which):
     else:
         inputs = [Tensor(jnp.zeros((1, 8), jnp.int32)),
                   Tensor(jnp.ones((1,), jnp.int32))]
+    # shapes, taken before the call: the decode program consumes the pool
+    specs = [jax.ShapeDtypeStruct(t.shape, t._value.dtype) for t in inputs]
     with paddle.no_grad():
         net(*inputs)
     static = net.forward
     (jitted,) = [v for k, v in static._jit_cache.items() if k[0] == "jit"]
     trainable, frozen = split_state(net)
-    text = jitted.lower([t._value for t in trainable.values()],
-                        [t._value for t in frozen.values()],
-                        jax.random.key(0),
-                        [t._value for t in inputs]).as_text()
+    text = jitted.lower(*static._call_args(
+        [t._value for t in trainable.values()],
+        [t._value for t in frozen.values()], jax.random.key(0), specs,
+        static._donated(len(specs)))).as_text()
     assert _lowered_name(text) == f"jit_{which}"
 
 

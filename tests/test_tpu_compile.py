@@ -152,3 +152,54 @@ def test_ernie_scan_layer_step_compiles(sds, monkeypatch):
         return _sum_loss(net._layer_fn(x, wl))
 
     assert _kernels_in(jax.grad(loss, argnums=(0, 1)), x, wl) == 2
+
+
+def test_llm_decode_program_updates_the_pool_in_place(sds):
+    """The serving engine's decode program at GPT-2-large widths (hidden
+    1280, 20 heads, 12 slots, page 1026) cut to 2 layers, from shapes: the
+    donated pool is aliased to the pool outputs, no synchronous copy of a
+    pool array is left, and the device layout of a pool array keeps one
+    cached position a contiguous row (heads * head_dim on the lanes; with
+    heads and head_dim as two axes the compiler lays the positions along
+    the lanes and a row's write touches the slot's whole page). The
+    per-slot write is still the scatter's loop, one per pool array: what
+    it costs is a chip's to say (PERF.md section 5)."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu.jit.functional import split_state
+    from paddle_tpu.models.gpt import GPTForCausalLM, GPTModel
+    from paddle_tpu.serving import LLMConfig, LLMEngine
+
+    layers, slots = 2, 12
+    paddle.seed(0)
+    lm = GPTForCausalLM(GPTModel(
+        vocab_size=512, hidden_size=1280, num_layers=layers, num_heads=20,
+        intermediate_size=5120, max_seq_len=1024, dropout=0.0))
+    eng = LLMEngine(lm, LLMConfig(num_slots=slots, max_len=1024,
+                                  prefill_buckets=(64,),
+                                  warmup_on_start=False))
+    net, static = eng._decode, eng._decode.forward
+    trainable, frozen = split_state(net)
+    inputs = [sds((slots,), jnp.int32), sds((slots,), jnp.int32)] + [
+        sds(tuple(t.shape), t._value.dtype) for t in eng._pool]
+    pool_shape = "f32[" + ",".join(str(d) for d in eng._pool[0].shape) + "]"
+    pool_bytes = sum(t._value.nbytes for t in eng._pool)
+    eng._pool = []                      # shapes are all the compile needs
+    donated = static._donated(len(inputs))
+    assert donated == tuple(range(2, 2 + 2 * layers))
+    jitted = static._get_jitted(
+        tuple(l.training for l in net.sublayers(include_self=True)),
+        list(trainable), list(frozen), {}, False, donated)
+    compiled = jitted.lower(*static._call_args(
+        [sds(tuple(t.shape), t._value.dtype) for t in trainable.values()],
+        [sds(tuple(t.shape), t._value.dtype) for t in frozen.values()],
+        sds((), jax.random.key(0).dtype), inputs, donated)).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_llm_decode")
+    assert not re.findall(r"= " + re.escape(pool_shape) + r"\S* copy\(", text)
+    entry = text.split("\n", 1)[0]
+    assert set(re.findall(re.escape(pool_shape) + r"\{([\d,]+):", entry)) \
+        == {"2,1,0"}
+    assert text.count(" while(") == 2 * layers
