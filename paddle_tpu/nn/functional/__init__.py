@@ -20,5 +20,5 @@ from .loss import (  # noqa: F401
     triplet_margin_loss, square_error_cost, sigmoid_focal_loss, ctc_loss,
     margin_cross_entropy, dice_loss, log_loss, npair_loss, hsigmoid_loss,
 )
-from .attention import scaled_dot_product_attention  # noqa: F401
+from .attention import rotary_embedding, scaled_dot_product_attention  # noqa: F401
 from .vision import grid_sample, affine_grid, temporal_shift  # noqa: F401

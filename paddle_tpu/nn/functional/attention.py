@@ -78,3 +78,31 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
         return jnp.swapaxes(out, 1, 2)
 
     return run_op(f, [q, k, v], "scaled_dot_product_attention")
+
+
+def rotary_embedding(x, positions, theta=10000.0, name=None):
+    """Rotary position embedding, half-split (the Qwen / GPT-NeoX
+    convention): with x = [x1, x2] the two halves of the last axis and
+    angle[i] = position * theta^(-2i/d), the result is
+    [x1 cos - x2 sin, x2 cos + x1 sin].
+
+    x: [batch, seq, heads, head_dim]; positions: [batch, seq] integers, a
+    position per row and token (a cached decode step passes each row's own
+    offset). The rotation is computed in float32 and returned in x's dtype.
+    """
+    x, positions = ensure_tensor(x), ensure_tensor(positions)
+    half = x.shape[-1] // 2
+    if 2 * half != x.shape[-1]:
+        raise ValueError(f"rotary_embedding: head_dim {x.shape[-1]} is odd")
+
+    def f(a, pos):
+        inv = jnp.asarray(theta, jnp.float32) ** (
+            -jnp.arange(half, dtype=jnp.float32) / half)
+        angle = pos.astype(jnp.float32)[..., None, None] * inv   # [B,T,1,d/2]
+        cos, sin = jnp.cos(angle), jnp.sin(angle)
+        a32 = a.astype(jnp.float32)
+        x1, x2 = a32[..., :half], a32[..., half:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1).astype(a.dtype)
+
+    return run_op(f, [x, positions], "rotary_embedding")

@@ -182,8 +182,11 @@ def rms_norm(x, weight=None, epsilon=1e-6, name=None):
     x = ensure_tensor(x)
 
     def f(a, *rest):
-        ms = jnp.mean(jnp.square(a), axis=-1, keepdims=True)
-        out = a * jax.lax.rsqrt(ms + epsilon)
+        # statistics in float32 whatever the input (a bfloat16 model's
+        # norm; a no-op for float32), the result in the input's dtype
+        a32 = a.astype(jnp.promote_types(a.dtype, jnp.float32))
+        ms = jnp.mean(jnp.square(a32), axis=-1, keepdims=True)
+        out = (a32 * jax.lax.rsqrt(ms + epsilon)).astype(a.dtype)
         return out * rest[0] if rest else out
 
     ins = [x] + ([ensure_tensor(weight)] if weight is not None else [])

@@ -36,8 +36,10 @@ _LAYER_CALL_DEPTH = _CallDepth()
 
 
 class Layer:
-    def __init__(self, name_scope=None, dtype="float32"):
+    def __init__(self, name_scope=None, dtype=None):
         self.training = True
+        # None: `paddle.get_default_dtype()` at construction, as the
+        # reference's layers take it from their helper
         self._dtype = convert_dtype(dtype) or get_default_dtype()
         self._parameters: Dict[str, Parameter] = collections.OrderedDict()
         self._sub_layers: Dict[str, "Layer"] = collections.OrderedDict()

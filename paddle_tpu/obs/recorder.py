@@ -129,7 +129,9 @@ class FlightRecorder:
                                incident_id=incident_id, source=source)
         os.makedirs(os.path.dirname(os.path.abspath(path)) or ".",
                     exist_ok=True)
-        tmp = path + ".tmp"
+        # a name of the thread's own: threads that dump one reason in one
+        # millisecond share `path`, and would move each other's file away
+        tmp = f"{path}.{threading.get_ident()}.tmp"
         with open(tmp, "w") as f:
             json.dump(payload, f, default=str)
         os.replace(tmp, path)

@@ -3,26 +3,55 @@
 The ServingEngine batches fixed-shape `run_batch` calls — right for
 ResNet/OCR, wrong for decoders, where per-request full-sequence recompute
 wastes nearly all decode FLOPs and fixed batches idle between stragglers.
-This module serves GPT/ERNIE decoders the way LLM traffic actually wants:
+This module serves decoders the way LLM traffic actually wants:
 
-- **KV cache as state the decode program updates in place** —
-  `GPTForCausalLM.forward_cached` takes fixed-shape cache pages in and
-  returns updated pages, so a decode step is one-token work instead of a
-  full-sequence forward. The engine owns the pool and donates it into
-  `jit_llm_decode` (`to_static(..., donate_inputs=...)`): the program
-  writes one row a slot into the buffers it was given and aliases them to
-  its outputs, so the pool is live once, not twice, and no step copies
-  it. `self._pool` is the program's output pages from the moment the
-  dispatch returns; the pages passed in are deleted
-  (`llm.decode.pool_donated` counts the steps where they were).
-- **Slot-paged fixed-shape pool** — per layer, one `[num_slots, page_len,
-  heads * head_dim]` array pair (a cached position is one contiguous
-  row on the device, so writing it touches that row only). Sequences
-  borrow a slot for their lifetime; shapes never depend on which slots
-  are live, so steady state runs exactly two kinds of cached executables
-  — one prefill per length bucket, one decode — with ZERO steady-state
-  compiles (the `jit.*` retrace counters stay flat; tests assert it).
-  Pool bytes carry the `mem.kv_pool.bytes` census tag.
+- **The model declares its cache; the engine owns a pool of it** — a
+  model answers `init_cache(batch, max_len, dtype)` with a flat list of
+  arrays that have the sequence on axis 0, and takes and returns that
+  list in `forward_cached`. The engine builds the list once with
+  `batch = num_slots` (the pool), hands ALL of it donated into
+  `jit_llm_decode` (`to_static(..., donate_inputs=...)`), and writes a
+  prefilled sequence into its slot with one `dynamic_update_slice` on
+  axis 0 per array: it never looks inside. The program updates the
+  buffers it was given and aliases them to its outputs, so the pool is
+  live once, not twice, and no step copies it. `self._pool` is the
+  program's output arrays from the moment the dispatch returns; the
+  arrays passed in are deleted (`llm.decode.pool_donated` counts the
+  steps where they were). `forward_cached(tokens, cache, positions,
+  lengths=None)` returns `(logits [B, vocab] of each row's last real
+  position, new cache)`: with `lengths` it reads prompts `[B, T]`
+  right-padded to T from an empty cache, without it one token a row
+  (`[B, 1]`) through `cache`. The model also declares the census tag of
+  its cache (`cache_tag`). Two kinds of cache answer the contract:
+  - **K/V pages** (`GPTForCausalLM`, through `_PagedKV` below, which puts
+    its older `forward_cached` — (k, v) pairs in, logits for every
+    position out — under the contract): per layer one `[num_slots,
+    page_len, heads * head_dim]` array pair (a cached position is one
+    contiguous row on the device, so writing it touches that row only).
+    A page is only read, and one row written, a step. `cache_tag`
+    `kv_pool` (census `mem.kv_pool.bytes`).
+  - **A recurrent state** (`BrumbyForCausalLM`'s power-retention layers):
+    per layer a fixed-size state a sequence, whatever its length
+    (`[num_slots, kv_heads, head_dim, rows]` and its normaliser), read
+    AND rewritten whole every step. Three things that are harmless for
+    pages would stay in a state for good, and the contract leaves no
+    room for them: a decode step is exactly one real token wide (the
+    `decode_block` junk row is `_PagedKV`'s own affair), prefill hands
+    the model `lengths`, so that it folds nothing of the bucket's
+    padding into the state, and the head runs on the last real position
+    only (logits for a whole 4096 bucket at a 150k vocabulary would be
+    2.5 GB). `cache_tag` `state_pool` (census `mem.state_pool.bytes`);
+    `llm.decode.state_bytes` counts the bytes of state a step rewrites.
+  Which of the two runs follows from the model handed to the engine:
+  no `LLMConfig` field chooses it, and the engine's two programs have one
+  body for both.
+- **Slot-paged fixed-shape pool** — sequences borrow a slot for their
+  lifetime; shapes never depend on which slots are live, so steady state
+  runs exactly two kinds of cached executables — one prefill per length
+  bucket, one decode — with ZERO steady-state compiles (the `jit.*`
+  retrace counters stay flat; tests assert it).
+  `llm.prefill.tokens_real` / `llm.prefill.tokens_bucket` count what the
+  buckets' padding costs.
 - **Continuous scheduler** — every decode step admits queued sequences
   into free slots and evicts on EOS/length/deadline, streaming each token
   to the caller the moment it exists (and over the wire as `'PDST'`
@@ -32,7 +61,8 @@ This module serves GPT/ERNIE decoders the way LLM traffic actually wants:
   matmuls through `quantization.quant_weight_only`; `kv_int8=True` stores
   the pool as int8 with a dequantization scale per slot.
 
-Decode blocks are `decode_block` (=2) tokens wide with only row 0 real:
+K/V pages take decode blocks `decode_block` (=2) tokens wide with only
+row 0 real (`_PagedKV`):
 on the XLA-CPU this was written against, a rank-1 matmul lowered through a
 differently-accumulated path and a block >= 2 made decode bitwise equal to
 the full-sequence forward. The installed XLA (jax 0.9) gives no such
@@ -96,16 +126,21 @@ def _prefill_ladder(max_len: int, declared: Sequence[int] = ()) -> List[int]:
 class LLMConfig:
     """Knobs for the continuous-batching engine (FLAGS_llm_* defaults).
 
-    Pool sizing recipe: bytes = 2 (K and V) * num_layers * num_slots *
-    (max_len + decode_block) * heads * head_dim * itemsize — fp32
-    itemsize 4, kv_int8 itemsize 1 (+ two f32 scales per slot per
-    layer). `LLMEngine.kv_pool_bytes()` reports the real figure and the
-    census publishes it as `mem.kv_pool.bytes`. That is what the pool
+    Pool sizing recipe, K/V pages: bytes = 2 (K and V) * num_layers *
+    num_slots * (max_len + decode_block) * heads * head_dim * itemsize —
+    fp32 itemsize 4, kv_int8 itemsize 1 (+ two f32 scales per slot per
+    layer). A recurrent state (`BrumbyForCausalLM`): bytes = num_layers *
+    num_slots * kv_heads * rows * (head_dim + 1) * 4 with rows =
+    head_dim (head_dim + 1) / 2 rounded up to 128 — 0.275 GB a slot at
+    Brumby-14B's widths and 8 layers, whatever `max_len` is (which only
+    bounds a sequence's positions there). `LLMEngine.kv_pool_bytes()`
+    reports the real figure of either and the census publishes it as
+    `mem.kv_pool.bytes` / `mem.state_pool.bytes`. That is what the pool
     costs on the device: the decode program takes it donated, so beside
-    the weights a deployment budgets the pool once (the TPU pads the
-    position axis to its tile of 8 rows in fp32: 1026 positions occupy
-    1032), plus one more pool array (one layer's K or V) while an
-    admission writes its slot out of place."""
+    the weights a deployment budgets the pool once (the TPU pads a
+    page's position axis to its tile of 8 rows in fp32: 1026 positions
+    occupy 1032), plus one more pool array (one layer's K, V or state)
+    while an admission writes its slot out of place."""
 
     num_slots: int = 8
     max_len: int = 256
@@ -211,90 +246,111 @@ class _Seq:
     admit_t: float = 0.0
 
 
-class _PrefillNet(nn.Layer):
-    """One prefill executable per length bucket: (tokens [B, Lb],
-    lengths [B]) -> (first greedy token [B], last-position logits [B, V],
-    fresh KV pages, [int8 scales]). Pages are created inside the trace so
-    the wire signature is just the token block."""
+class _PagedKV(nn.Layer):
+    """`GPTForCausalLM`'s K/V pages under the cache contract (module
+    docstring). Its own `forward_cached` takes (k, v) pairs and gives
+    logits for every position; here the flat list is paired up, the last
+    real position's logits are gathered (padding right of it writes rows
+    no query reads), and a decode step is widened to a block
+    `decode_block` wide with only row 0 real. With `kv_int8` the pages'
+    dequantization scales follow the pages in the list a prefill returns
+    and a decode step is handed; a decode step returns the pages only
+    (the scales of a live slot do not change)."""
 
-    def __init__(self, lm, page_len: int, kv_int8: bool):
+    cache_tag = "kv_pool"
+
+    def __init__(self, lm, block: int, kv_int8: bool):
         super().__init__()
         self.lm = lm
-        self._page_len = page_len
+        self._block = block
         self._kv_int8 = kv_int8
 
-    def forward(self, tokens, lengths):
+    def init_cache(self, batch_size, max_len, dtype="float32"):
+        """The `2 x layers` pages of `GPTModel.init_kv_cache` as one flat
+        list (k0, v0, k1, v1, ...), the sequence on axis 0."""
+        return [page for pair in self.lm.gpt.init_kv_cache(
+            batch_size, max_len, dtype=dtype) for page in pair]
+
+    def forward_cached(self, tokens, cache, positions, lengths=None):
         import jax.numpy as jnp
 
         from ..ops._dispatch import run_op
+
+        if lengths is not None:
+            pages = list(zip(cache[0::2], cache[1::2]))
+            logits, kv, scales = self.lm.forward_cached(tokens, pages,
+                                                        positions)
+
+            def _last(la, ln):
+                idx = (ln - 1).astype(jnp.int32)[:, None, None]
+                return jnp.take_along_axis(la, idx, axis=1)[:, 0]
+
+            last = run_op(_last, [logits, lengths], "llm_last_logits")
+            new = [page for pair in kv for page in pair]
+            if self._kv_int8:
+                new += [scale for pair in scales for scale in pair]
+            return last, new
+        n, block = len(cache), self._block
+        scales = None
+        if self._kv_int8:
+            n //= 2
+            scales = list(zip(cache[n::2], cache[n + 1::2]))
+        kv = list(zip(cache[0:n:2], cache[1:n:2]))
+        # [S, 1] -> [S, block]: row 0 real, the rest padding (bit-exactness
+        # trick — see module docstring)
+        blk = run_op(
+            lambda t: jnp.broadcast_to(t, (t.shape[0], block)),
+            [tokens], "llm_decode_block")
+        logits, kv, _ = self.lm.forward_cached(blk, kv, positions, scales)
+        return logits[:, 0], [page for pair in kv for page in pair]
+
+
+class _PrefillNet(nn.Layer):
+    """One prefill executable per length bucket: (tokens [B, Lb],
+    lengths [B]) -> (first greedy token [B], last-position logits [B, V],
+    the fresh cache). The cache is created inside the trace so the wire
+    signature is just the token block."""
+
+    def __init__(self, lm, page_len: int, dtype: str):
+        super().__init__()
+        self.lm = lm
+        self._page_len = page_len
+        self._dtype = dtype
+
+    def forward(self, tokens, lengths):
         from ..ops.creation import zeros
         from ..ops.manipulation import cast
         from ..ops.search import argmax
 
         b = tokens.shape[0]
-        dtype = "int8" if self._kv_int8 else "float32"
-        pages = self.lm.gpt.init_kv_cache(b, self._page_len, dtype=dtype)
-        positions = zeros([b], dtype="int32")
-        logits, kv, scales = self.lm.forward_cached(tokens, pages, positions)
-
-        def _last(la, ln):
-            idx = (ln - 1).astype(jnp.int32)[:, None, None]
-            return jnp.take_along_axis(la, idx, axis=1)[:, 0]
-
-        last = run_op(_last, [logits, lengths], "llm_last_logits")
-        first = cast(argmax(last, axis=-1), "int32")
-        outs = [first, last]
-        for k, v in kv:
-            outs += [k, v]
-        if self._kv_int8:
-            for ks, vs in scales:
-                outs += [ks, vs]
-        return tuple(outs)
+        cache = self.lm.init_cache(b, self._page_len, dtype=self._dtype)
+        last, cache = self.lm.forward_cached(
+            tokens, cache, zeros([b], dtype="int32"), lengths)
+        return (cast(argmax(last, axis=-1), "int32"), last, *cache)
 
 
 class _DecodeNet(nn.Layer):
     """THE decode executable: one fixed-shape step for the whole pool.
-    (tokens [S], positions [S], *pool state) -> (next greedy token [S],
-    logits [S, V], updated pool pages). Free slots ride along as masked
-    junk rows — occupancy never changes the signature."""
+    (tokens [S], positions [S], *pool) -> (next greedy token [S], logits
+    [S, V], the updated pool). Free slots ride along as junk rows —
+    occupancy never changes the signature."""
 
-    def __init__(self, lm, num_layers: int, block: int, kv_int8: bool):
+    def __init__(self, lm):
         super().__init__()
         self.lm = lm
-        self._n = num_layers
-        self._block = block
-        self._kv_int8 = kv_int8
 
-    def forward(self, tokens, positions, *state):
-        import jax.numpy as jnp
-
-        from ..ops._dispatch import run_op
-        from ..ops.manipulation import cast
+    def forward(self, tokens, positions, *cache):
+        from ..ops.manipulation import cast, unsqueeze
         from ..ops.search import argmax
 
-        n, block = self._n, self._block
-        kv = [(state[2 * i], state[2 * i + 1]) for i in range(n)]
-        scales = None
-        if self._kv_int8:
-            off = 2 * n
-            scales = [(state[off + 2 * i], state[off + 2 * i + 1])
-                      for i in range(n)]
-        # [S] -> [S, block]: row 0 real, the rest padding (bit-exactness
-        # trick — see module docstring)
-        blk = run_op(
-            lambda t: jnp.broadcast_to(t[:, None], (t.shape[0], block)),
-            [tokens], "llm_decode_block")
-        logits, kv, _ = self.lm.forward_cached(blk, kv, positions, scales)
-        last = logits[:, 0]
-        nxt = cast(argmax(last, axis=-1), "int32")
-        outs = [nxt, last]
-        for k, v in kv:
-            outs += [k, v]
-        return tuple(outs)
+        last, cache = self.lm.forward_cached(unsqueeze(tokens, 1),
+                                             list(cache), positions)
+        return (cast(argmax(last, axis=-1), "int32"), last, *cache)
 
 
 class LLMEngine:
-    """Continuous-batching scheduler over a slot-paged KV-cache pool.
+    """Continuous-batching scheduler over a slot-paged pool of whatever
+    the model keeps a sequence: K/V pages or a recurrent state.
 
     `submit()` is thread-safe and returns an `LLMStream` immediately; a
     single scheduler thread owns the pool and runs the admit -> decode ->
@@ -308,46 +364,55 @@ class LLMEngine:
         cfg = config or LLMConfig.from_flags()
         if isinstance(model, GPTModel):
             model = GPTForCausalLM(model)
-        if not hasattr(model, "forward_cached"):
-            raise ServingError(
-                "LLMEngine needs a model with a cached-attention path "
-                "(GPTForCausalLM / GPTModel)")
         self.config = cfg
         self.lm = model
+        # the model under the cache contract (module docstring): nothing
+        # in LLMConfig chooses which kind of cache runs
+        if isinstance(model, GPTForCausalLM):
+            model = _PagedKV(model, cfg.decode_block, cfg.kv_int8)
+        if not all(hasattr(model, a) for a in
+                   ("init_cache", "forward_cached", "cache_tag")):
+            raise ServingError(
+                "LLMEngine needs a model that declares its cache: "
+                "init_cache, forward_cached and cache_tag "
+                "(BrumbyForCausalLM; GPTForCausalLM / GPTModel are put "
+                "under the contract here)")
+        if cfg.kv_int8 and model.cache_tag != "kv_pool":
+            raise ServingError("kv_int8 quantises K/V pages; this model "
+                               f"keeps a {model.cache_tag}")
         self.lm.eval()  # serving path: dropout off, rng-stable
         if cfg.quant == "int8":
             from ..quantization import quant_weight_only
             quant_weight_only(self.lm)
         elif cfg.quant not in ("", "off"):
             raise ServingError(f"unknown llm quant arm {cfg.quant!r}")
+        self._cached = model
+        self._tag = model.cache_tag
 
-        gpt = self.lm.gpt
-        attn = gpt.layers[0].attention
-        self._n_layers = len(gpt.layers)
-        self._heads, self._head_dim = attn.num_heads, attn.head_dim
         self._page_len = cfg.max_len + cfg.decode_block
         self.buckets = _prefill_ladder(cfg.max_len, cfg.prefill_buckets)
 
-        self._prefill = _PrefillNet(self.lm, self._page_len, cfg.kv_int8)
-        self._decode = _DecodeNet(self.lm, self._n_layers,
-                                  cfg.decode_block, cfg.kv_int8)
+        import jax.numpy as jnp
+        s = cfg.num_slots
+        # what the model says a sequence keeps, slot on axis 0: K/V pages
+        # (k0, v0, k1, v1, ...) or recurrent states (S0, z0, S1, z1, ...)
+        self._pool: List[Tensor] = self._zero_pool()
+        # ks0, vs0, ...: one [S] f32 dequantization scale a page and slot
+        self._scales: List[Tensor] = [
+            Tensor(jnp.ones((s,), jnp.float32)) for _ in self._pool
+        ] if cfg.kv_int8 else []
+
+        self._prefill = _PrefillNet(self._cached, self._page_len,
+                                    "int8" if cfg.kv_int8 else "float32")
+        self._decode = _DecodeNet(self._cached)
         from ..jit import to_static
         # the programs' names in a trace: jit_llm_prefill, jit_llm_decode.
         # The engine owns the pool, so it alone may give it away: the
-        # decode program takes the pages (its inputs after tokens and
-        # positions) donated, writes them in place and aliases them out.
+        # decode program takes the pool (its inputs after tokens and
+        # positions) donated, writes it in place and aliases it out.
         to_static(self._prefill, name="llm_prefill")
         to_static(self._decode, name="llm_decode",
-                  donate_inputs=slice(2, 2 + 2 * self._n_layers))
-
-        import jax.numpy as jnp
-        s = cfg.num_slots
-        self._pool: List[Tensor] = self._zero_pool()   # k0, v0, k1, v1, ...
-        self._scales: List[Tensor] = []  # ks0, vs0, ... ([S] f32 per slot)
-        if cfg.kv_int8:
-            for _ in range(self._n_layers):
-                self._scales += [Tensor(jnp.ones((s,), jnp.float32)),
-                                 Tensor(jnp.ones((s,), jnp.float32))]
+                  donate_inputs=slice(2, 2 + len(self._pool)))
 
         self._free: List[int] = list(range(s))
         self._active: Dict[int, _Seq] = {}
@@ -363,12 +428,10 @@ class LLMEngine:
         self._warm_ms = 0.0
 
     def _zero_pool(self) -> List[Tensor]:
-        import jax.numpy as jnp
         cfg = self.config
-        shape = (cfg.num_slots, self._page_len, self._heads * self._head_dim)
-        kdt = jnp.int8 if cfg.kv_int8 else jnp.float32
-        return [Tensor(jnp.zeros(shape, kdt))
-                for _ in range(2 * self._n_layers)]
+        return list(self._cached.init_cache(
+            cfg.num_slots, self._page_len,
+            dtype="int8" if cfg.kv_int8 else "float32"))
 
     def _decode_pool(self, tokens, positions):
         """Run the decode program on (tokens, positions) and the pool,
@@ -388,7 +451,7 @@ class LLMEngine:
             if any(t._value.is_deleted() for t in self._pool):
                 self._pool = self._zero_pool()
             raise
-        self._pool = list(outs[2:2 + 2 * self._n_layers])
+        self._pool = list(outs[2:2 + len(self._pool)])
         return outs, old.is_deleted()
 
     # ---- lifecycle ---------------------------------------------------------
@@ -569,41 +632,52 @@ class LLMEngine:
                 self._prefill_into(seq)
                 seq = self._next_admission()
 
-    def _prefill_into(self, seq: _Seq) -> None:
+    def _prefill_slot(self, prompt: np.ndarray, slot: int, rid: int = 0):
+        """The device's part of an admission: the prompt, right-padded to
+        its bucket, through that bucket's program, and the cache it
+        returns written into `slot` of the pool, one
+        `dynamic_update_slice` on axis 0 an array. Returns (first greedy
+        token, bucket, last-position logits [1, V])."""
         import jax
         import jax.numpy as jnp
 
         from ..ops._dispatch import run_op
 
-        cfg = self.config
-        rid = seq.stream.request_id
-        plen = int(seq.prompt.size)
+        plen = int(prompt.size)
         lb = next(b for b in self.buckets if b >= plen)
         with _monitor.span("llm.prefill", request_id=rid, bucket=lb,
                            prompt_len=plen):
             padded = np.zeros((1, lb), np.int32)
-            padded[0, :plen] = seq.prompt
+            padded[0, :plen] = prompt
             outs = self._prefill(Tensor(jnp.asarray(padded)),
                                  Tensor(jnp.full((1,), plen, jnp.int32)))
             first = int(np.asarray(outs[0].numpy())[0])
 
         def _row(pool, row, s):
-            return jax.lax.dynamic_update_slice(pool, row, (s, 0, 0))
+            return jax.lax.dynamic_update_slice(
+                pool, row, (s,) + (0,) * (pool.ndim - 1))
 
         def _cell(vec, val, s):
             return jax.lax.dynamic_update_slice(vec, val, (s,))
 
-        pages = outs[2:2 + 2 * self._n_layers]
-        svals = outs[2 + 2 * self._n_layers:] if cfg.kv_int8 else ()
+        pages = outs[2:2 + len(self._pool)]
+        svals = outs[2 + len(self._pool):]
         with _monitor.span("llm.slot_write", request_id=rid,
                            writes=len(pages) + len(svals)):
-            slot_t = Tensor(jnp.asarray(seq.slot, jnp.int32))
+            slot_t = Tensor(jnp.asarray(slot, jnp.int32))
             for i, page in enumerate(pages):
                 self._pool[i] = run_op(_row, [self._pool[i], page, slot_t],
                                        "llm_slot_write")
             for i, sv in enumerate(svals):
                 self._scales[i] = run_op(_cell, [self._scales[i], sv, slot_t],
                                          "llm_scale_write")
+        return first, lb, outs[1]
+
+    def _prefill_into(self, seq: _Seq) -> None:
+        cfg = self.config
+        plen = int(seq.prompt.size)
+        first, lb, _ = self._prefill_slot(seq.prompt, seq.slot,
+                                          seq.stream.request_id)
         with _monitor.span("llm.emit"):
             now = time.monotonic()
             seq.pos = plen
@@ -615,6 +689,8 @@ class LLMEngine:
                 self._active[seq.slot] = seq
             if _monitor._ENABLED:
                 _monitor.count("llm.prefill.requests")
+                _monitor.count("llm.prefill.tokens_real", plen)
+                _monitor.count("llm.prefill.tokens_bucket", lb)
                 _monitor.count("llm.tokens_generated")
                 _monitor.observe("llm.queue_wait", seq.admit_t - seq.submit_t)
                 _monitor.observe("llm.ttft_ms",
@@ -686,6 +762,10 @@ class LLMEngine:
                 _monitor.count("llm.decode.steps")
                 if donated:
                     _monitor.count("llm.decode.pool_donated")
+                if self._tag == "state_pool":
+                    # a step reads and rewrites every state, live or free
+                    _monitor.count("llm.decode.state_bytes",
+                                   self.kv_pool_bytes())
                 _monitor.gauge_set("llm.slots_active", len(self._active))
             self._retag_pool()
 
@@ -733,7 +813,7 @@ class LLMEngine:
 
     def _retag_pool(self) -> None:
         if _mem._ENABLED:
-            _mem.tag("kv_pool",
+            _mem.tag(self._tag,
                      [t._value for t in (*self._pool, *self._scales)],
                      origin="LLMEngine")
 

@@ -203,3 +203,91 @@ def test_llm_decode_program_updates_the_pool_in_place(sds):
     assert set(re.findall(re.escape(pool_shape) + r"\{([\d,]+):", entry)) \
         == {"2,1,0"}
     assert text.count(" while(") == 2 * layers
+
+
+def test_power_retention_step_kernel_compiles_in_place(sds, monkeypatch):
+    """The decode state update at Brumby-14B's widths (12 slots, 8 kv heads
+    of 5 query heads, head_dim 128: a state [12, 8, 128, 8320] float32,
+    409 MB a layer): one Pallas kernel under its own name, the donated
+    state aliased to the state it returns, nothing of its size left as a
+    temporary."""
+    pr = importlib.import_module("paddle_tpu.kernels.power_retention")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, heads, groups, d = 12, 40, 8, 128
+    rows = pr.state_rows(d)
+    state = sds((slots, groups, d, rows), jnp.float32)
+    compiled = jax.jit(
+        lambda q, k, v, g, s, z: pr.power_retention_step(q, k, v, g, (s, z)),
+        donate_argnums=(4, 5)).lower(
+            sds((slots, heads, d)), sds((slots, groups, d)),
+            sds((slots, groups, d)), sds((slots, groups), jnp.float32),
+            state, sds((slots, groups, rows), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"%{pr.STEP_KERNEL}" in text     # the name a trace reader finds
+    mem = compiled.memory_analysis()
+    nbytes = slots * groups * d * rows * 4
+    assert mem.alias_size_in_bytes >= nbytes
+    assert mem.temp_size_in_bytes < nbytes // 8
+
+
+@pytest.mark.parametrize("bucket", [512, 4096])
+def test_power_retention_prompt_form_compiles(sds, bucket):
+    """One layer's prompt form at the published widths, one chunk a
+    bucket: the scores stay in blocks of rows (well under a gigabyte of
+    temporaries at the 4096 bucket, where [40, 4096, 4096] float32 scores
+    alone would be 2.7 GB)."""
+    pr = importlib.import_module("paddle_tpu.kernels.power_retention")
+    compiled = jax.jit(pr.power_retention_chunked).lower(
+        sds((1, bucket, 40, 128)), sds((1, bucket, 8, 128)),
+        sds((1, bucket, 8, 128)), sds((1, bucket, 8), jnp.float32),
+        sds((1,), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_brumby_decode_program_rewrites_the_state_pool_in_place(sds,
+                                                                monkeypatch):
+    """The engine's decode program over a Brumby model at the published
+    widths (vocabulary cut to 1024 rows: the head is not the subject), 2
+    layers, 12 slots, from shapes: the whole state pool is donated and
+    aliased out, each layer's update is the named kernel, and the program
+    is one token wide (no `decode_block` broadcast)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.jit.functional import split_state
+    from paddle_tpu.models.brumby import BrumbyForCausalLM, BrumbyModel
+    from paddle_tpu.serving import LLMConfig, LLMEngine
+
+    pr = importlib.import_module("paddle_tpu.kernels.power_retention")
+    layers, slots = 2, 12
+    paddle.seed(0)
+    lm = BrumbyForCausalLM(BrumbyModel(vocab_size=1024, num_layers=layers,
+                                       dtype="bfloat16"))
+    eng = LLMEngine(lm, LLMConfig(num_slots=slots, max_len=8192,
+                                  prefill_buckets=(512,),
+                                  warmup_on_start=False))
+    net, static = eng._decode, eng._decode.forward
+    trainable, frozen = split_state(net)
+    inputs = [sds((slots,), jnp.int32), sds((slots,), jnp.int32)] + [
+        sds(tuple(t.shape), t._value.dtype) for t in eng._pool]
+    pool_bytes = sum(t._value.nbytes for t in eng._pool)
+    assert pool_bytes == layers * slots * 8 * 8320 * 129 * 4
+    eng._pool = []                      # shapes are all the compile needs
+    donated = static._donated(len(inputs))
+    assert donated == tuple(range(2, 2 + 2 * layers))
+    jitted = static._get_jitted(
+        tuple(l.training for l in net.sublayers(include_self=True)),
+        list(trainable), list(frozen), {}, False, donated)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with paddle.no_grad():
+        compiled = jitted.lower(*static._call_args(
+            [sds(tuple(t.shape), t._value.dtype)
+             for t in trainable.values()],
+            [sds(tuple(t.shape), t._value.dtype) for t in frozen.values()],
+            sds((), jax.random.key(0).dtype), inputs, donated)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 16
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_llm_decode")
+    assert text.count(f"%{pr.STEP_KERNEL}") >= layers
+    assert text.count('custom_call_target="tpu_custom_call"') == layers
