@@ -52,11 +52,29 @@ This module serves decoders the way LLM traffic actually wants:
   retrace counters stay flat; tests assert it).
   `llm.prefill.tokens_real` / `llm.prefill.tokens_bucket` count what the
   buckets' padding costs.
-- **Continuous scheduler** — every decode step admits queued sequences
-  into free slots and evicts on EOS/length/deadline, streaming each token
-  to the caller the moment it exists (and over the wire as `'PDST'`
-  frames via `inference/server.py`). Admission sheds on SLO burn
-  (`obs/slo.py`) and queue depth, like the batch engine.
+- **Continuous scheduler, one decode step ahead** — every turn admits
+  queued sequences into free slots and evicts on EOS/length/deadline,
+  streaming each token to the caller the moment the host holds it (and
+  over the wire as `'PDST'` frames via `inference/server.py`). Admission
+  sheds on SLO burn (`obs/slo.py`) and queue depth, like the batch
+  engine. All that step n+1 needs of step n is its `[S]` greedy tokens,
+  which `jit_llm_decode` computes itself (`outs[0]`); positions the host
+  knows without reading anything. So a turn (`_step`) DISPATCHES step
+  n+1 on step n's `outs[0]`, still on the device and never donated, and
+  only THEN reads step n, emits and evicts: dispatch, read and emit run
+  while the chip runs, not between two programs. The depth is exactly
+  one step and not a setting. What the host knows ahead it uses: a
+  sequence whose budget or page ends with the step in flight is left out
+  of the next one (its slot rides along as a junk row, as free slots
+  do). EOS and a deadline are learnt at the read: that sequence has one
+  more row in the step already in flight, whose token is discarded,
+  never emitted (`llm.decode.discarded`), and its slot is free at once
+  (the next prefill's slot write depends on the pool the step in flight
+  returns, so it lands after it). An admission first reads and emits the
+  step in flight (`llm.decode.drains`), so no finished token waits for a
+  prefill, and every live slot's next token is then on the host: the
+  first step after an empty pipeline takes host tokens, every other one
+  the device's (`llm.decode.ahead`), and no step mixes the two.
 - **Quantized decode arm** — `LLMConfig(quant="int8")` runs the decoder
   matmuls through `quantization.quant_weight_only`; `kv_int8=True` stores
   the pool as int8 with a dequantization scale per slot.
@@ -71,7 +89,9 @@ tests/test_llm_serving.py holds them to 1e-4 with the same arg-max — so
 nothing may rely on it; the block width stays until ROADMAP D6 frees the
 decode step to be one row wide. The junk row's cache write lands one past
 the live prefix and is overwritten by the next real token before it can
-be read.
+be read; under a discarded row (above) it lands two past, inside the page
+(`max_len + decode_block`) because a sequence at `max_len` is never
+dispatched again.
 
 Reference parity: this is the Paddle-Serving deployment role (PAPER.md
 §1 row 8) taken to continuous batching over a paged KV cache — the
@@ -240,10 +260,24 @@ class _Seq:
     deadline: Optional[float]          # absolute monotonic, or None
     submit_t: float
     slot: int = -1
-    pos: int = 0                       # tokens cached so far
-    last_token: int = 0
+    # tokens cached once the step in flight has landed: the position of
+    # the next row to DISPATCH (what has been emitted is `stream.tokens`)
+    pos: int = 0
+    last_token: int = 0                # the last token emitted
     last_emit_t: float = 0.0
     admit_t: float = 0.0
+
+    @property
+    def sent(self) -> int:
+        """The tokens its stream will hold once the step in flight is
+        read: the prefill's one and a token a row dispatched."""
+        return self.pos - int(self.prompt.size) + 1
+
+    def ends_at(self, n_tokens: int, max_len: int) -> bool:
+        """Whether its `n_tokens`-th token is its last: the budget is
+        spent, or the page's last position is cached."""
+        return (n_tokens >= self.max_new
+                or int(self.prompt.size) + n_tokens - 1 >= max_len)
 
 
 class _PagedKV(nn.Layer):
@@ -416,6 +450,10 @@ class LLMEngine:
 
         self._free: List[int] = list(range(s))
         self._active: Dict[int, _Seq] = {}
+        # the decode step dispatched and not yet read (the scheduler
+        # thread's own): its tokens, still on the device, and the (slot,
+        # sequence) rows it computes
+        self._flight: Optional[Tuple[Tensor, List[Tuple[int, _Seq]]]] = None
         self._pending: "collections.deque[_Seq]" = collections.deque()
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
@@ -437,15 +475,21 @@ class LLMEngine:
         """Run the decode program on (tokens, positions) and the pool,
         which it consumes: `self._pool` is its output pages from the
         moment the dispatch returns, so no other thread and no later line
-        ever holds a deleted page. Returns (outs, donated) — donated is
-        whether the old buffers were really given away (a host attribute
-        read). A dispatch that fails after taking the pool leaves a zero
-        pool behind; every sequence is lost with it either way."""
+        ever holds a deleted page. `tokens` is a host array, or a
+        `Tensor` that is handed on as it is: an earlier step's `outs[0]`,
+        still on the device and possibly not computed yet (it is never
+        donated, so the caller may read it afterwards). Returns (outs,
+        donated): outs[0] the greedy tokens, outs[1] the logits, then the
+        pool; donated is whether the old buffers were really given away
+        (a host attribute read). Nothing here waits for the device. A
+        dispatch that fails after taking the pool leaves a zero pool
+        behind; every sequence is lost with it either way."""
         import jax.numpy as jnp
         old = self._pool[0]._value
+        if not isinstance(tokens, Tensor):
+            tokens = Tensor(jnp.asarray(tokens))
         try:
-            outs = self._decode(Tensor(jnp.asarray(tokens)),
-                                Tensor(jnp.asarray(positions)),
+            outs = self._decode(tokens, Tensor(jnp.asarray(positions)),
                                 *self._pool, *self._scales)
         except BaseException:
             if any(t._value.is_deleted() for t in self._pool):
@@ -477,20 +521,23 @@ class LLMEngine:
                               Tensor(jnp.ones((1,), jnp.int32)))
             s = self.config.num_slots
             # the pool is zeros and no slot is live: the junk rows this
-            # writes at position 0 are never read
-            self._decode_pool(np.zeros((s,), np.int32),
-                              np.zeros((s,), np.int32))
+            # writes at position 0 are never read. Once on host tokens and
+            # once on the step's own, the two ways the scheduler feeds it
+            outs, _ = self._decode_pool(np.zeros((s,), np.int32),
+                                        np.zeros((s,), np.int32))
+            self._decode_pool(outs[0], np.zeros((s,), np.int32))
         self._warm_ms = (time.monotonic() - t0) * 1000.0
         if _monitor._ENABLED:
             _monitor.gauge_set("llm.warm_start_ms", self._warm_ms)
-            _monitor.count("llm.warmup_runs", len(self.buckets) + 1)
+            _monitor.count("llm.warmup_runs", len(self.buckets) + 2)
 
     def stop(self, drain: bool = True, timeout: float = 30.0) -> None:
         if drain and self._thread is not None:
             deadline = time.monotonic() + timeout
             while time.monotonic() < deadline:
                 with self._lock:
-                    if not self._pending and not self._active:
+                    if not self._pending and not self._active \
+                            and self._flight is None:
                         break
                 time.sleep(0.01)
         with self._work:
@@ -585,23 +632,41 @@ class LLMEngine:
             while True:
                 with self._work:
                     if self._stopped:
-                        return
-                    if not self._pending and not self._active:
+                        break
+                    if not self._pending and not self._active \
+                            and self._flight is None:
                         with _monitor.span("llm.park"):
                             self._work.wait(timeout=self.config.idle_park_s)
                         if self._stopped:
-                            return
+                            break
                     pending_now = bool(self._pending)
                 if pending_now:
                     self._admit()
-                if self._active:
-                    try:
-                        with _monitor.span("llm.step",
-                                           slots=len(self._active)):
-                            self._step()
-                    except Exception as e:  # scheduler must survive
-                        self._evict_all("error",
-                                        f"{type(e).__name__}: {e}")
+                if self._active or self._flight is not None:
+                    self._turn()
+            # a stop that did not wait: the step in flight is read and
+            # emitted before the streams left are told "stopped"
+            self._drain()
+
+    def _turn(self, ahead: bool = True) -> None:
+        """`_step` under its span; the scheduler survives what it raises,
+        at the dispatch or at the (later) read: every sequence is lost,
+        the engine and its pool serve on."""
+        try:
+            with _monitor.span("llm.step", slots=len(self._active)):
+                self._step(ahead)
+        except Exception as e:
+            self._evict_all("error", f"{type(e).__name__}: {e}")
+
+    def _drain(self) -> None:
+        """Empty the pipeline: read and emit the step in flight and
+        dispatch nothing. Every live sequence's next token is then on the
+        host (`last_token`)."""
+        if self._flight is None:
+            return
+        self._turn(ahead=False)
+        if _monitor._ENABLED:
+            _monitor.count("llm.decode.drains")
 
     def _next_admission(self) -> Optional[_Seq]:
         """The next pending sequence, moved into a free slot, or None when
@@ -623,6 +688,12 @@ class LLMEngine:
             return seq
 
     def _admit(self) -> None:
+        with self._lock:
+            if not self._free or not self._pending:
+                return
+        # finished tokens are not held for the length of an admission
+        # (before a slot is taken: a read that fails frees them all)
+        self._drain()
         seq = self._next_admission()
         if seq is None:
             return
@@ -700,50 +771,97 @@ class LLMEngine:
             # a one-token budget (or instant EOS) finishes without decoding
             if first == cfg.eos_token_id:
                 self._evict(seq, "eos")
-            elif len(seq.stream.tokens) >= seq.max_new:
+            elif seq.ends_at(1, cfg.max_len):
                 self._evict(seq, "length")
 
-    def _step(self) -> None:
-        """One decode step for every active slot: fault drill, dispatch,
-        emit, evict. Fixed shapes — occupancy is data, not signature."""
+    def _step(self, ahead: bool = True) -> None:
+        """One turn of the scheduler, which runs ONE decode step ahead of
+        what it has read: dispatch step n+1 for the sequences that go on
+        (`_dispatch`; not with `ahead` false, which is a drain), THEN read
+        step n's tokens, emit them and evict (`_collect`), while the chip
+        runs n+1. Fixed shapes — occupancy is data, not signature. If the
+        dispatch raises, step n stays in `_flight` for `_evict_all`."""
+        prev = self._flight
+        self._flight = self._dispatch(prev) if ahead else None
+        if prev is not None:
+            self._collect(*prev)
+
+    def _dispatch(self, prev):
+        """Dispatch the next decode step and return it as a flight
+        (tokens on the device, rows), or None when no sequence goes on.
+        Its tokens are `prev`'s own, step n's `outs[0]` handed on unread,
+        or, with nothing in flight, the host's `last_token`s: the two
+        never mix, since an admission drains the pipeline first and so
+        every sequence that is live under a flight has a row in it. A
+        sequence whose last token is in flight is left out (a junk row)."""
         cfg = self.config
         now = time.monotonic()
         with self._lock:
             live = sorted(self._active.items())
-        # the llm.decode fault site is checked once per in-flight
-        # sequence so an injected error takes down exactly one of them
+        rows = []
         for slot, seq in live:
+            if seq.ends_at(seq.sent, cfg.max_len):
+                continue
             if seq.deadline is not None and now > seq.deadline:
                 self._evict(seq, "deadline")
                 continue
+            # the llm.decode fault site is checked once per in-flight
+            # sequence so an injected error takes down exactly one of them
             if _faults._ENABLED:
                 try:
                     _faults.check(self._FAULT_SITE)
                 except Exception as e:
                     self._evict(seq, "error",
                                 f"{type(e).__name__}: {e}")
-        with self._lock:
-            live = sorted(self._active.items())
-        if not live:
-            return
+                    continue
+            rows.append((slot, seq))
+        if not rows:
+            return None
         report = lambda: {"kv_pool_bytes": self.kv_pool_bytes()}
         with _monitor.span("llm.decode.dispatch"), \
                 _exe.dispatch_guard("llm_decode", report=report):
             s = cfg.num_slots
-            toks = np.zeros((s,), np.int32)
             pos = np.zeros((s,), np.int32)
-            for slot, seq in live:
-                toks[slot] = seq.last_token
+            for slot, seq in rows:
                 pos[slot] = seq.pos
+            if prev is None:
+                toks = np.zeros((s,), np.int32)
+                for slot, seq in rows:
+                    toks[slot] = seq.last_token
+            else:
+                toks = prev[0]
             outs, donated = self._decode_pool(toks, pos)
-        # the wait for the device and the d2h copy of the tokens
+        for _, seq in rows:
+            seq.pos += 1
+        if _monitor._ENABLED:
+            _monitor.count("llm.decode.steps")
+            if prev is not None:
+                _monitor.count("llm.decode.ahead")
+            if donated:
+                _monitor.count("llm.decode.pool_donated")
+            if self._tag == "state_pool":
+                # a step reads and rewrites every state, live or free
+                _monitor.count("llm.decode.state_bytes",
+                               self.kv_pool_bytes())
+        return outs[0], rows
+
+    def _collect(self, tokens: Tensor, rows) -> None:
+        """Read a dispatched step's tokens, emit them, evict. A row whose
+        sequence ended after the dispatch (EOS, a deadline or a fault,
+        learnt since) is discarded: its token reaches no stream."""
+        cfg = self.config
+        # the wait for the device and the d2h copy of the tokens: the one
+        # host sync of the decode loop
         with _monitor.span("llm.decode.read"):
-            nxt = np.asarray(outs[0].numpy())
+            nxt = np.asarray(tokens.numpy())  # tpu-lint: disable=host-sync
         with _monitor.span("llm.emit"):
             now = time.monotonic()
-            for slot, seq in live:
+            discarded = 0
+            for slot, seq in rows:
+                if self._active.get(slot) is not seq:
+                    discarded += 1
+                    continue
                 tok = int(nxt[slot])
-                seq.pos += 1
                 seq.last_token = tok
                 seq.stream._emit(tok)
                 if _monitor._ENABLED:
@@ -753,19 +871,12 @@ class LLMEngine:
                 seq.last_emit_t = now
                 if tok == cfg.eos_token_id:
                     self._evict(seq, "eos")
-                elif len(seq.stream.tokens) >= seq.max_new \
-                        or seq.pos >= cfg.max_len:
+                elif seq.ends_at(len(seq.stream.tokens), cfg.max_len):
                     self._evict(seq, "length")
                 elif seq.deadline is not None and now > seq.deadline:
                     self._evict(seq, "deadline")
             if _monitor._ENABLED:
-                _monitor.count("llm.decode.steps")
-                if donated:
-                    _monitor.count("llm.decode.pool_donated")
-                if self._tag == "state_pool":
-                    # a step reads and rewrites every state, live or free
-                    _monitor.count("llm.decode.state_bytes",
-                                   self.kv_pool_bytes())
+                _monitor.count("llm.decode.discarded", discarded)
                 _monitor.gauge_set("llm.slots_active", len(self._active))
             self._retag_pool()
 
@@ -787,6 +898,7 @@ class LLMEngine:
         self._finish(seq, status, error)
 
     def _evict_all(self, status: str, error: str) -> None:
+        self._flight = None     # its rows' sequences end here, unread
         with self._lock:
             live = list(self._active.values())
             self._active.clear()

@@ -45,6 +45,53 @@ def _ref_generate(lm, prompt, max_new, with_margins=False):
     return (out, margins) if with_margins else out
 
 
+def _serial_generate(eng, prompt, max_new, slot=0, eos=None):
+    """The strictly serial loop the scheduler ran before it ran ahead,
+    over the engine's own two programs (the engine is not started yet, or
+    stopped): the prompt prefilled into `slot`, then one `_decode_pool` a
+    token, each step's tokens read before the next is dispatched. A row
+    depends on nothing but its own slot, so one sequence at a time is the
+    reference for any mix of them."""
+    cfg = eng.config
+    max_new = min(max_new, max(cfg.max_len - len(prompt), 1))
+    with paddle.no_grad():
+        toks = [eng._prefill_slot(np.asarray(prompt, np.int32), slot)[0]]
+        pos = len(prompt)
+        while len(toks) < max_new and toks[-1] != eos and pos < cfg.max_len:
+            t = np.zeros(cfg.num_slots, np.int32)
+            at = np.zeros(cfg.num_slots, np.int32)
+            t[slot], at[slot] = toks[-1], pos
+            outs, _ = eng._decode_pool(t, at)
+            toks.append(int(np.asarray(outs[0].numpy())[slot]))
+            pos += 1
+    return toks
+
+
+def _engine_of(kind, **cfg):
+    """An engine over K/V pages ("fp32", "kv_int8") or over a small
+    Brumby model's recurrent states ("brumby"), not started."""
+    if kind == "brumby":
+        from paddle_tpu.models.brumby import BrumbyForCausalLM, BrumbyModel
+        paddle.seed(3)
+        lm = BrumbyForCausalLM(BrumbyModel(
+            dtype="float32", vocab_size=64, hidden_size=32, num_layers=2,
+            num_heads=4, num_kv_heads=2, head_dim=8, intermediate_size=64,
+            gate_bias=3.0))
+        lm.eval()
+    else:
+        lm = _build_lm()
+    return LLMEngine(lm, LLMConfig(kv_int8=kind == "kv_int8", **cfg))
+
+
+def _hold_admissions(eng):
+    """Nothing is admitted until the returned event is set, so that
+    requests submitted meanwhile are admitted by ONE `_admit` call and no
+    later admission drains the pipeline under them."""
+    gate, admit = threading.Event(), eng._admit
+    eng._admit = lambda: gate.wait(30.0) and admit()
+    return gate
+
+
 @pytest.fixture()
 def monitored():
     monitor.reset()
@@ -185,7 +232,14 @@ class TestContinuousBatching:
             snap = monitor.snapshot()
             assert snap["counters"]["llm.requests"] == 8
             assert snap["counters"]["llm.tokens_generated"] == 8 * max_new
-            assert snap["counters"]["llm.decode.steps"] > 0
+            # one step deep: a request's 15 decode rows ride 15 steps,
+            # shared with whoever else is live, and since every stream
+            # ends on its budget, which the host knows ahead, no row is
+            # computed in vain
+            steps = snap["counters"]["llm.decode.steps"]
+            assert max_new - 1 <= steps <= 8 * (max_new - 1)
+            assert 0 < snap["counters"]["llm.decode.ahead"] < steps
+            assert snap["counters"]["llm.decode.discarded"] == 0
             assert snap["counters"]["llm.evictions.length"] == 8
             assert "llm.slots_active" in snap["gauges"]
             assert snap["histograms"]["llm.ttft_ms"]["count"] == 8
@@ -278,6 +332,162 @@ class TestContinuousBatching:
         del lm, eng
         gc.collect()
         assert ref() is None, "model survived engine teardown"
+
+
+class TestRunAhead:
+    """The scheduler dispatches step n+1 on step n's tokens, still on the
+    device, before it reads step n: the same tokens as the serial loop,
+    none after an EOS, none lost to an admission, no row past a budget."""
+
+    @pytest.mark.parametrize("kind", ["fp32", "kv_int8", "brumby"])
+    def test_streams_are_the_serial_loops_token_for_token(self, kind):
+        """More requests than slots, prompts and budgets of mixed lengths,
+        submitted while the engine decodes: slots are reused, admissions
+        drain the pipeline, sequences end on different steps."""
+        eng = _engine_of(kind, num_slots=3, max_len=32,
+                         prefill_buckets=(16,), max_new_tokens=8)
+        rng = np.random.default_rng(11)
+        asks = [(rng.integers(0, 64, int(n)).tolist(), int(m))
+                for n, m in zip((2, 14, 5, 9, 3, 11, 7), (12, 3, 9, 1, 7, 5, 10))]
+        refs = [_serial_generate(eng, p, m, slot=i % 3)
+                for i, (p, m) in enumerate(asks)]
+        assert [len(r) for r in refs] == [m for _, m in asks]
+        eng.start()
+        try:
+            streams = []
+            for p, m in asks:
+                streams.append(eng.submit(p, max_new_tokens=m))
+                time.sleep(0.01)        # arrive between steps, not at once
+            for s, ref in zip(streams, refs):
+                assert s.result(timeout=120.0) == ("done", ref)
+        finally:
+            eng.stop()
+
+    def test_nothing_after_eos_reaches_a_stream(self, monitored):
+        """An EOS is learnt one dispatch late: the row already in flight
+        for that sequence is discarded and counted, its slot is free, and
+        the next request in that slot is served correctly."""
+        cfg = dict(num_slots=4, max_len=48, prefill_buckets=(16,),
+                   max_new_tokens=16)
+        eng = _engine_of("fp32", **cfg)
+        prompts = [[3, 1, 4], [9], [7, 7, 2, 50], [11, 2]]
+        free_run = [_serial_generate(eng, p, 16) for p in prompts]
+        # the token that ends most streams after their first token and
+        # before their budget: the model does emit it
+        eos = max(range(64), key=lambda t: sum(
+            t in r[1:-1] and t != r[0] for r in free_run))
+        refs = [_serial_generate(eng, p, 16, eos=eos) for p in prompts]
+        ran_on = sum(1 < len(r) < 16 for r in refs)   # ended by EOS, early
+        assert ran_on >= 1 and all(eos not in r[:-1] for r in refs)
+        eng = _engine_of("fp32", eos_token_id=eos, **cfg)
+        # all four are admitted by one call, so that no admission's drain
+        # reads an EOS (a drained step has no successor to discard)
+        gate = _hold_admissions(eng)
+        eng.start()
+        try:
+            streams = [eng.submit(p) for p in prompts]
+            gate.set()
+            for s, ref in zip(streams, refs):
+                assert s.result(timeout=120.0) == ("done", ref)
+            snap = monitor.snapshot()["counters"]
+            assert snap["llm.decode.discarded"] == ran_on
+            assert snap["llm.evictions.eos"] == \
+                sum(r[-1] == eos for r in refs)
+            assert eng.stats()["free"] == 4
+            # a slot that took a discarded row serves the next sequence
+            again = [eng.submit(p) for p in prompts]
+            for s, ref in zip(again, refs):
+                assert s.result(timeout=120.0) == ("done", ref)
+        finally:
+            eng.stop()
+
+    def test_admission_under_a_step_in_flight_and_the_share_ahead(
+            self, monitored):
+        """50 decode steps with one admission in the middle. The admitted
+        request starts from its prefill's first token, not from the junk
+        row its slot rode along as; the admission drains the pipeline
+        once; every dispatch but the two that follow an empty pipeline is
+        made ahead of the read."""
+        eng = _engine_of("fp32", num_slots=2, max_len=64,
+                         prefill_buckets=(16,), max_new_tokens=51)
+        long_ref = _serial_generate(eng, [1, 2, 3], 51)
+        short_ref = _serial_generate(eng, [4, 5], 5, slot=1)
+        eng.start()
+        try:
+            long_s = eng.submit([1, 2, 3])
+            deadline = time.monotonic() + 60.0
+            while len(long_s.tokens) < 10:       # steps are in flight
+                assert time.monotonic() < deadline
+                time.sleep(0.001)
+            short_s = eng.submit([4, 5], max_new_tokens=5)
+            assert short_s.result(timeout=120.0) == ("done", short_ref)
+            assert long_s.result(timeout=120.0) == ("done", long_ref)
+        finally:
+            eng.stop()
+        snap = monitor.snapshot()["counters"]
+        assert snap["llm.decode.steps"] == 50
+        assert snap["llm.decode.drains"] == 1
+        assert snap["llm.decode.ahead"] == 48
+        assert snap["llm.decode.ahead"] / snap["llm.decode.steps"] >= 0.8
+        assert snap["llm.decode.discarded"] == 0
+
+    def test_a_stop_that_does_not_wait_reads_the_step_in_flight(
+            self, monitored):
+        """`stop(drain=False)` under a decoding stream: the step in flight
+        is read and emitted (a drain), then the stream is told "stopped";
+        what it got is a prefix of the serial loop's tokens."""
+        eng = _engine_of("fp32", num_slots=2, max_len=64,
+                         prefill_buckets=(16,), max_new_tokens=60)
+        ref = _serial_generate(eng, [1, 2, 3], 60)
+        eng.start()
+        stream = eng.submit([1, 2, 3])
+        deadline = time.monotonic() + 60.0
+        while len(stream.tokens) < 5:
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        eng.stop(drain=False)
+        status, toks = stream.result(timeout=10.0)
+        assert status == "stopped" and eng._flight is None
+        assert 5 <= len(toks) < 60 and toks == ref[:len(toks)]
+        snap = monitor.snapshot()["counters"]
+        assert snap["llm.decode.drains"] == 1
+        # every dispatched step was read: the tokens are all accounted for
+        assert snap["llm.tokens_generated"] == len(toks) \
+            == snap["llm.decode.steps"] + 1
+
+    def test_a_sequence_at_max_len_is_not_dispatched_past_it(self,
+                                                            monitored):
+        """A prompt of 12 in a page of 16 gets 4 tokens whatever it asks
+        for: rows at positions 12, 13, 14, then its slot rides along at
+        position 0 while its neighbour decodes on."""
+        eng = _engine_of("fp32", num_slots=2, max_len=16,
+                         prefill_buckets=(16,), max_new_tokens=32)
+        seen, real = [], eng._decode_pool
+
+        def watched(tokens, positions):
+            seen.append(np.array(positions))
+            return real(tokens, positions)
+
+        eng._decode_pool = watched
+        gate = _hold_admissions(eng)
+        eng.start()
+        del seen[:]                           # the warm-up's two steps
+        try:
+            a = eng.submit(list(range(12)))
+            b = eng.submit([5], max_new_tokens=10)
+            gate.set()
+            assert a.result(timeout=60.0)[0] == "done"
+            assert b.result(timeout=60.0)[0] == "done"
+        finally:
+            eng.stop()
+        assert len(a.tokens) == 4 and len(b.tokens) == 10
+        assert len(seen) == 9                 # b's rows; a rode three
+        assert max(int(p.max()) for p in seen) == 14 < eng.config.max_len
+        slot = int(np.argmax(seen[0]))
+        assert [int(p[slot]) for p in seen] == [12, 13, 14] + [0] * 6
+        snap = monitor.snapshot()["counters"]
+        assert snap["llm.decode.steps"] == 9
+        assert snap["llm.decode.discarded"] == 0
 
 
 class TestQuantizedDecode:
@@ -562,6 +772,41 @@ class TestDonatedPool:
             t.join(timeout=10.0)
             eng.stop()
         assert not errors
+
+    def test_failure_at_the_read_leaves_a_serving_engine(self, kv_int8):
+        """The error surfaces where the host reads a step's tokens, one
+        dispatch later than it used to: the step after it is in flight
+        and unread, the sequences are lost, the next request is served."""
+        from paddle_tpu.core.tensor import Tensor
+
+        class Unreadable(Tensor):
+            def numpy(self):
+                raise RuntimeError("device fell over under the read")
+
+        eng = self._engine(kv_int8)
+        ref = _serial_generate(eng, [4, 2], 8)
+        real = eng._decode
+        armed = {"n": 0}
+
+        def poisoned(*a, **kw):
+            out = list(real(*a, **kw))
+            if armed["n"]:
+                armed["n"] -= 1
+                out[0] = Unreadable(out[0]._value)
+            return out
+
+        eng._decode = poisoned
+        eng.start()
+        armed["n"] = 1
+        try:
+            status, toks = eng.submit([4, 2]).result(timeout=60.0)
+            assert status == "error" and toks == ref[:1]
+            assert self._live(eng) and eng._flight is None
+            assert eng.stats()["free"] == 3
+            assert eng.submit([4, 2]).result(timeout=60.0) == ("done", ref)
+        finally:
+            eng._decode = real
+            eng.stop()
 
     def test_failure_inside_the_dispatch_leaves_a_serving_engine(
             self, kv_int8):

@@ -19,7 +19,12 @@ from paddle_tpu.models.gpt import GPTForCausalLM, GPTModel
 from paddle_tpu.profiler import Profiler, RecordEvent
 from paddle_tpu.serving import LLMConfig, LLMEngine
 
+# a turn dispatches the next step, then reads and emits the one that was
+# in flight: the first turn after an empty pipeline has nothing to read, a
+# drain (before an admission) and a turn with no sequence going on have
+# nothing to dispatch
 STEP_CHILDREN = ("llm.decode.dispatch", "llm.decode.read", "llm.emit")
+STEP_SHAPES = (STEP_CHILDREN, STEP_CHILDREN[:1], STEP_CHILDREN[1:])
 ADMIT_CHILDREN = ("llm.prefill", "llm.slot_write", "llm.emit")
 # every site that goes through `monitor.span` (jit.to_static.call takes the
 # null span itself, for the `static_program` profiler hook's sake)
@@ -109,8 +114,11 @@ def test_span_tree_is_on_one_line_with_the_documented_nesting(served):
     steps = [e for e in events if e[0] == "llm.step"]
     admits = [e for e in events if e[0] == "llm.admit"]
     assert steps and admits
-    for s in steps:
-        assert tuple(c[0] for c in _children(events, s)) == STEP_CHILDREN
+    shapes = [tuple(c[0] for c in _children(events, s)) for s in steps]
+    assert set(shapes) <= set(STEP_SHAPES)
+    assert STEP_CHILDREN in shapes            # it did run ahead
+    # what is dispatched alone is read alone: every step is read once
+    assert shapes.count(STEP_CHILDREN[:1]) == shapes.count(STEP_CHILDREN[1:])
     for a in admits:
         kids = tuple(c[0] for c in _children(events, a))
         assert kids and kids == ADMIT_CHILDREN * (len(kids) // 3)
@@ -127,8 +135,10 @@ def test_span_tree_is_on_one_line_with_the_documented_nesting(served):
 
 def test_read_starts_when_dispatch_has_returned(served):
     events = served["events"]
-    for s in (e for e in events if e[0] == "llm.step"):
-        dispatch, read, emit = _children(events, s)
+    whole = [kids for kids in (_children(events, s) for s in events
+                               if s[0] == "llm.step") if len(kids) == 3]
+    assert whole
+    for dispatch, read, emit in whole:
         assert dispatch[2] <= read[1] and read[2] <= emit[1]
         assert read[2] > read[1]
 
