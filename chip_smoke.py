@@ -183,7 +183,7 @@ class ServeCfg:
     build: Callable          # () -> GPTModel
     num_slots: int = 8
     max_len: int = 256
-    # one prefill executable each (FLAGS_llm_prefill_buckets): three, not
+    # one prefill executable each (LLMConfig.prefill_buckets): three, not
     # the default ladder's six, keeps warm-up inside the smoke's time
     prefill_buckets: Tuple[int, ...] = (16, 64, 256)
     max_new_tokens: int = 8

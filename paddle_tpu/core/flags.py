@@ -74,7 +74,6 @@ define_flag("use_standalone_executor", True, "new-executor opt-in (executor.py:1
 define_flag("eager_delete_tensor_gb", 0.0, "GC threshold (unused on TPU; XLA owns buffers)")
 define_flag("allocator_strategy", "auto_growth", "host allocator strategy name")
 define_flag("tpu_matmul_precision", "default", "default|high|highest - lax precision for matmul/conv")
-define_flag("tpu_eager_jit", True, "jit-cache eager primitive ops instead of op-by-op dispatch")
 define_flag("lazy_eager", False,
             "lazy batching eager executor (ops/lazy.py): run_op defers ops "
             "into a per-thread segment and flushes them as ONE jitted "
@@ -486,43 +485,6 @@ define_flag("compile_cache_mb", 1024,
             "compile cache: on-disk size cap in MB; least-recently-used "
             "entries beyond it are evicted at store/gc time "
             "(compile_cache.evictions counter)")
-# ---- LLM continuous-batching serving (serving/llm.py) ---------------------
-define_flag("llm_num_slots", 8,
-            "LLM engine: KV-cache pool slots = max sequences decoding "
-            "concurrently; one fixed-shape decode executable covers all "
-            "slots, so this is also the decode batch width")
-define_flag("llm_max_len", 256,
-            "LLM engine: per-slot KV page length (prompt + generated "
-            "ceiling); pool bytes scale linearly with it "
-            "(see README 'LLM serving' sizing recipe)")
-define_flag("llm_prefill_buckets", "",
-            "LLM engine: comma-separated prefill length buckets (prompts "
-            "pad up to the next bucket; one cached prefill executable per "
-            "bucket). Empty = powers of two from 8 up to llm_max_len")
-define_flag("llm_max_new_tokens", 64,
-            "LLM engine: default generation budget per request when the "
-            "submit call doesn't set one")
-define_flag("llm_queue_depth", 256,
-            "LLM engine: max queued (not yet admitted) requests before "
-            "submit sheds with ServerOverloadedError")
-define_flag("llm_default_deadline_ms", 0.0,
-            "LLM engine: deadline applied to requests that don't carry "
-            "one; sequences past it are evicted at the next decode step "
-            "(llm.evictions.deadline). 0 = no default")
-define_flag("llm_warmup", True,
-            "LLM engine: trace+compile every prefill bucket and the "
-            "decode step at start() so steady-state serving performs "
-            "zero compiles (the jit.* retrace counters stay flat)")
-define_flag("llm_quant", "off",
-            "LLM engine decode quantization arm: 'int8' applies "
-            "quant_weight_only to the decoder matmuls (Linear + "
-            "ColumnParallelLinear/RowParallelLinear) at engine init; "
-            "'off' serves fp32 weights")
-define_flag("llm_kv_int8", False,
-            "LLM engine: store KV-cache pages as int8 with one "
-            "dequantization scale per slot (computed at prefill, "
-            "clipped into at decode) — 4x pool bytes reduction")
-
 define_flag("lazy_cache_entries", 256,
             "lazy eager: max cached segment replay executables "
             "(the ops/lazy.py executable ledger); least-recently-used entries are "

@@ -65,6 +65,21 @@ class ErnieMLP(nn.Layer):
         return self.dropout(self.fc2(self.act(self.fc1(x))))
 
 
+# Width of one decode step through `ErnieSelfAttention.forward_cached` for
+# a model that keeps K/V pages (`GPTForCausalLM`): the real token in row 0,
+# junk behind it, and as many rows added to every page. On the XLA-CPU this
+# was written against a block of 2 made decode bitwise equal to the full
+# forward (a rank-1 matmul accumulated differently); jax 0.9's XLA gives no
+# such equality at any width (cached and full logits agree to ~2e-6, tests
+# hold them to 1e-4 with the same arg-max), so nothing relies on it. It is
+# still 2 because a one-row step is another program, a `perf_opt` PR's to
+# measure: ROADMAP S1(d) / D6 removes it. The junk row's write lands one
+# past the live prefix and the next real token overwrites it before any
+# mask admits it; under a row the engine discards it lands two past, still
+# inside the page, because a sequence at `max_len` is never dispatched again.
+DECODE_BLOCK = 2
+
+
 class ErnieSelfAttention(nn.Layer):
     def __init__(self, hidden_size, num_heads, dropout=0.1, use_mp=False,
                  use_sp=False, causal=False):

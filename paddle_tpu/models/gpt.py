@@ -10,7 +10,7 @@ from __future__ import annotations
 from .. import nn
 from ..ops.creation import arange
 from ..ops.manipulation import reshape, unsqueeze
-from .ernie import ErnieLayer
+from .ernie import DECODE_BLOCK, ErnieLayer
 
 
 class GPTEmbeddings(nn.Layer):
@@ -55,41 +55,67 @@ class GPTModel(nn.Layer):
             x = layer(x)
         return self.final_norm(x)
 
-    def init_kv_cache(self, batch_size, max_len, dtype="float32"):
-        """Fresh zero KV pages for forward_cached: one (k, v) pair per
-        layer, each [batch, max_len, num_heads * head_dim] (a position is
-        one contiguous row: `ErnieSelfAttention.forward_cached`). dtype
-        "int8" builds the quantized-KV pages (scales start as None and
-        are computed by the first forward_cached call)."""
+    def init_cache(self, batch_size, max_len, dtype="float32"):
+        """Zero K/V pages for `max_len` positions a row, as one flat list
+        k0, v0, k1, v1, ...: each [batch, max_len + DECODE_BLOCK, num_heads
+        * head_dim] (a position is one contiguous row:
+        `ErnieSelfAttention.forward_cached`; the extra rows take the
+        decode block's junk). dtype "int8" builds quantised pages, followed
+        by their [batch] float32 dequantisation scales ks0, vs0, ... (ones:
+        a prompt computes them)."""
         import jax.numpy as jnp
 
         from ..core.tensor import Tensor
         attn = self.layers[0].attention
-        shape = (batch_size, max_len, attn.num_heads * attn.head_dim)
-        return [(Tensor(jnp.zeros(shape, dtype=dtype)),
-                 Tensor(jnp.zeros(shape, dtype=dtype)))
-                for _ in self.layers]
+        n = 2 * len(self.layers)
+        shape = (batch_size, max_len + DECODE_BLOCK,
+                 attn.num_heads * attn.head_dim)
+        cache = [Tensor(jnp.zeros(shape, dtype=dtype)) for _ in range(n)]
+        if dtype == "int8":
+            cache += [Tensor(jnp.ones((batch_size,), jnp.float32))
+                      for _ in range(n)]
+        return cache
 
-    def forward_cached(self, input_ids, past_kv, positions, kv_scales=None):
-        """Prefill/decode step over explicit KV-cache carries.
-
-        input_ids [B, T]; past_kv: list over layers of (k, v) fixed-shape
-        pages [B, L, nh*hd]; positions [B] int32 tokens-already-cached
-        per row (also the position-embedding offset). kv_scales: list of
-        (k_scale, v_scale) [B] pairs for int8 pages, or None.
-        Returns (hidden, new_past_kv, new_kv_scales)."""
+    def forward_cached(self, input_ids, cache, positions, lengths=None):
+        """`GPTForCausalLM.forward_cached` up to the head: input_ids
+        [B, T] through the pages of `init_cache`, written at positions[b]
+        .. positions[b] + T - 1 (positions [B] int32: tokens already cached
+        a row, also the position-embedding offset). Pages are int8 when
+        scales follow them; a prompt (`lengths` given) computes the scales
+        from its own K/V, a decode step clips into the ones it is handed.
+        Returns (hidden [B, T, H], new cache)."""
+        n = 2 * len(self.layers)
+        handed = lengths is None and len(cache) > n
+        scales = cache[n:] if handed else [None] * n
         x = self.embeddings(input_ids, position_offset=positions)
-        new_kv, new_scales = [], []
+        pages, new_scales = [], []
         for i, layer in enumerate(self.layers):
-            ks, vs = (None, None) if kv_scales is None else kv_scales[i]
-            k, v = past_kv[i]
-            x, k, v, ks, vs = layer.forward_cached(x, k, v, positions, ks, vs)
-            new_kv.append((k, v))
-            new_scales.append((ks, vs))
-        return self.final_norm(x), new_kv, new_scales
+            k, v = 2 * i, 2 * i + 1
+            x, *kept = layer.forward_cached(x, cache[k], cache[v], positions,
+                                            scales[k], scales[v])
+            pages += kept[:2]
+            new_scales += kept[2:]      # None, None without int8 pages
+        return self.final_norm(x), pages + [s for s in new_scales
+                                            if s is not None]
 
 
 class GPTForCausalLM(nn.Layer):
+    """The LM head over `GPTModel`, and the cache contract
+    `serving.LLMEngine` asks of a model: what a sequence keeps is K/V
+    pages, per layer one `[batch, page, heads * head_dim]` pair that a
+    step only reads, with one row written (census tag `kv_pool`).
+
+    - `init_cache(batch, max_len, dtype)` -> `GPTModel.init_cache`'s flat
+      list, the sequence (the engine's slot) on axis 0 of every array.
+    - `forward_cached(tokens, cache, positions, lengths=None)` ->
+      `(logits [B, vocab] of each row's last real position, new cache)`.
+      With `lengths` it reads prompts `[B, T]` right-padded to T (padding
+      right of the last real position writes rows no query reads);
+      without, one token a row (`[B, 1]`) through `cache`, widened here
+      to a block `DECODE_BLOCK` wide with only row 0 real."""
+
+    cache_tag = "kv_pool"
+
     def __init__(self, gpt: GPTModel):
         super().__init__()
         self.gpt = gpt
@@ -100,15 +126,30 @@ class GPTForCausalLM(nn.Layer):
         w = self.gpt.embeddings.word_embeddings.weight
         return matmul(h, w, transpose_y=True)
 
-    def forward_cached(self, input_ids, past_kv, positions, kv_scales=None):
-        """Cached-attention LM step: (logits, new_past_kv, new_kv_scales).
-        Weight-tied head over GPTModel.forward_cached — a decode step
-        ([B, 1] input) is one-token work against the cache pages."""
+    def init_cache(self, batch_size, max_len, dtype="float32"):
+        return self.gpt.init_cache(batch_size, max_len, dtype)
+
+    def forward_cached(self, tokens, cache, positions, lengths=None):
+        import jax.numpy as jnp
+
+        from ..ops._dispatch import run_op
         from ..ops.math import matmul
-        h, new_kv, new_scales = self.gpt.forward_cached(
-            input_ids, past_kv, positions, kv_scales)
+
+        if lengths is None:
+            tokens = run_op(
+                lambda t: jnp.broadcast_to(t, (t.shape[0], DECODE_BLOCK)),
+                [tokens], "llm_decode_block")
+        h, cache = self.gpt.forward_cached(tokens, cache, positions, lengths)
         w = self.gpt.embeddings.word_embeddings.weight
-        return matmul(h, w, transpose_y=True), new_kv, new_scales
+        logits = matmul(h, w, transpose_y=True)
+        if lengths is None:
+            return logits[:, 0], cache
+
+        def _last(la, ln):
+            idx = (ln - 1).astype(jnp.int32)[:, None, None]
+            return jnp.take_along_axis(la, idx, axis=1)[:, 0]
+
+        return run_op(_last, [logits, lengths], "llm_last_logits"), cache
 
 
 class GPTPretrainingCriterion(nn.Layer):

@@ -17,6 +17,7 @@ from __future__ import annotations
 import enum
 import json
 import os
+import tempfile
 import threading
 import time
 
@@ -120,7 +121,10 @@ class Profiler:
             _ACTIVE_STACK.append(self)
         _dispatch._PROFILE_HOOK = _dispatch_hook
         if not self._timer_only:
-            self._export_dir = self._export_dir or "./profiler_log"
+            if self._export_dir is None:
+                # no directory given: a fresh one under the temp directory,
+                # never the working tree (read it back off `_export_dir`)
+                self._export_dir = tempfile.mkdtemp(prefix="profiler_log_")
             os.makedirs(self._export_dir, exist_ok=True)
             try:
                 jax.profiler.start_trace(self._export_dir)

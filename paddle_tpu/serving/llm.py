@@ -1,58 +1,34 @@
 """Continuous-batching autoregressive serving (the LLM decode plane).
 
-The ServingEngine batches fixed-shape `run_batch` calls — right for
+The ServingEngine batches fixed-shape `run_batch` calls: right for
 ResNet/OCR, wrong for decoders, where per-request full-sequence recompute
 wastes nearly all decode FLOPs and fixed batches idle between stragglers.
-This module serves decoders the way LLM traffic actually wants:
 
-- **The model declares its cache; the engine owns a pool of it** — a
-  model answers `init_cache(batch, max_len, dtype)` with a flat list of
-  arrays that have the sequence on axis 0, and takes and returns that
-  list in `forward_cached`. The engine builds the list once with
-  `batch = num_slots` (the pool), hands ALL of it donated into
-  `jit_llm_decode` (`to_static(..., donate_inputs=...)`), and writes a
-  prefilled sequence into its slot with one `dynamic_update_slice` on
-  axis 0 per array: it never looks inside. The program updates the
-  buffers it was given and aliases them to its outputs, so the pool is
-  live once, not twice, and no step copies it. `self._pool` is the
-  program's output arrays from the moment the dispatch returns; the
-  arrays passed in are deleted (`llm.decode.pool_donated` counts the
-  steps where they were). `forward_cached(tokens, cache, positions,
-  lengths=None)` returns `(logits [B, vocab] of each row's last real
-  position, new cache)`: with `lengths` it reads prompts `[B, T]`
-  right-padded to T from an empty cache, without it one token a row
-  (`[B, 1]`) through `cache`. The model also declares the census tag of
-  its cache (`cache_tag`). Two kinds of cache answer the contract:
-  - **K/V pages** (`GPTForCausalLM`, through `_PagedKV` below, which puts
-    its older `forward_cached` — (k, v) pairs in, logits for every
-    position out — under the contract): per layer one `[num_slots,
-    page_len, heads * head_dim]` array pair (a cached position is one
-    contiguous row on the device, so writing it touches that row only).
-    A page is only read, and one row written, a step. `cache_tag`
-    `kv_pool` (census `mem.kv_pool.bytes`).
-  - **A recurrent state** (`BrumbyForCausalLM`'s power-retention layers):
-    per layer a fixed-size state a sequence, whatever its length
-    (`[num_slots, kv_heads, head_dim, rows]` and its normaliser), read
-    AND rewritten whole every step. Three things that are harmless for
-    pages would stay in a state for good, and the contract leaves no
-    room for them: a decode step is exactly one real token wide (the
-    `decode_block` junk row is `_PagedKV`'s own affair), prefill hands
-    the model `lengths`, so that it folds nothing of the bucket's
-    padding into the state, and the head runs on the last real position
-    only (logits for a whole 4096 bucket at a 150k vocabulary would be
-    2.5 GB). `cache_tag` `state_pool` (census `mem.state_pool.bytes`);
-    `llm.decode.state_bytes` counts the bytes of state a step rewrites.
-  Which of the two runs follows from the model handed to the engine:
-  no `LLMConfig` field chooses it, and the engine's two programs have one
-  body for both.
-- **Slot-paged fixed-shape pool** — sequences borrow a slot for their
-  lifetime; shapes never depend on which slots are live, so steady state
-  runs exactly two kinds of cached executables — one prefill per length
-  bucket, one decode — with ZERO steady-state compiles (the `jit.*`
-  retrace counters stay flat; tests assert it).
-  `llm.prefill.tokens_real` / `llm.prefill.tokens_bucket` count what the
-  buckets' padding costs.
-- **Continuous scheduler, one decode step ahead** — every turn admits
+- **The model declares its cache; the engine owns a pool of it.** A model
+  answers three names and the engine asks nothing else of it:
+  `init_cache(batch, max_len, dtype)` -> a flat list of arrays with the
+  sequence on axis 0; `forward_cached(tokens, cache, positions,
+  lengths=None)` -> `(logits [B, vocab] of each row's last real position,
+  new cache)`, with `lengths` reading prompts `[B, T]` right-padded to T
+  from an empty cache, without it one token a row (`[B, 1]`) through
+  `cache`; `cache_tag`, the pool's tag in the memory census (`kv_pool`:
+  `GPTForCausalLM`'s K/V pages, `models/gpt.py`; `state_pool`:
+  `BrumbyForCausalLM`'s recurrent state, `models/brumby.py`, where
+  `llm.decode.state_bytes` counts what a step rewrites). The engine builds
+  the list once with `batch = num_slots` (the pool), hands ALL of it
+  donated into `jit_llm_decode` (`to_static(..., donate_inputs=...)`),
+  which updates the buffers it was given and aliases them to its outputs
+  (`self._pool` is the program's outputs from the moment the dispatch
+  returns; `llm.decode.pool_donated` counts the steps that gave the old
+  ones away), and writes a prefilled sequence into its slot with one
+  `dynamic_update_slice` on axis 0 per array: it never looks inside.
+- **Two kinds of program, fixed shapes.** Sequences borrow a slot for
+  their lifetime; shapes never depend on which slots are live, so steady
+  state runs one prefill executable per length bucket and one decode
+  executable, with ZERO steady-state compiles (the `jit.*` retrace
+  counters stay flat; tests assert it). `llm.prefill.tokens_real` /
+  `llm.prefill.tokens_bucket` count what the buckets' padding costs.
+- **Continuous scheduler, one decode step ahead.** Every turn admits
   queued sequences into free slots and evicts on EOS/length/deadline,
   streaming each token to the caller the moment the host holds it (and
   over the wire as `'PDST'` frames via `inference/server.py`). Admission
@@ -75,26 +51,12 @@ This module serves decoders the way LLM traffic actually wants:
   prefill, and every live slot's next token is then on the host: the
   first step after an empty pipeline takes host tokens, every other one
   the device's (`llm.decode.ahead`), and no step mixes the two.
-- **Quantized decode arm** — `LLMConfig(quant="int8")` runs the decoder
-  matmuls through `quantization.quant_weight_only`; `kv_int8=True` stores
-  the pool as int8 with a dequantization scale per slot.
-
-K/V pages take decode blocks `decode_block` (=2) tokens wide with only
-row 0 real (`_PagedKV`):
-on the XLA-CPU this was written against, a rank-1 matmul lowered through a
-differently-accumulated path and a block >= 2 made decode bitwise equal to
-the full-sequence forward. The installed XLA (jax 0.9) gives no such
-equality at any width — cached and full logits agree to ~2e-6, and
-tests/test_llm_serving.py holds them to 1e-4 with the same arg-max — so
-nothing may rely on it; the block width stays until ROADMAP D6 frees the
-decode step to be one row wide. The junk row's cache write lands one past
-the live prefix and is overwritten by the next real token before it can
-be read; under a discarded row (above) it lands two past, inside the page
-(`max_len + decode_block`) because a sequence at `max_len` is never
-dispatched again.
+- **Quantized decode arm.** `LLMConfig(quant="int8")` runs the decoder
+  matmuls through `quantization.quant_weight_only`; `kv_int8=True` asks a
+  model that keeps K/V pages for an int8 cache.
 
 Reference parity: this is the Paddle-Serving deployment role (PAPER.md
-§1 row 8) taken to continuous batching over a paged KV cache — the
+§1 row 8) taken to continuous batching over a paged KV cache: the
 vLLM-style iteration-level scheduler, built TPU-first (fixed shapes, two
 executables, zero steady-state compiles) instead of kernel-first.
 """
@@ -113,7 +75,6 @@ from .. import faults as _faults
 from .. import monitor as _monitor
 from .. import nn
 from ..core import executable as _exe
-from ..core import flags as _flags
 from ..core.autograd import no_grad
 from ..core.tensor import Tensor
 from ..obs import memory as _mem
@@ -144,12 +105,13 @@ def _prefill_ladder(max_len: int, declared: Sequence[int] = ()) -> List[int]:
 
 @dataclass
 class LLMConfig:
-    """Knobs for the continuous-batching engine (FLAGS_llm_* defaults).
+    """Knobs for the continuous-batching engine.
 
-    Pool sizing recipe, K/V pages: bytes = 2 (K and V) * num_layers *
-    num_slots * (max_len + decode_block) * heads * head_dim * itemsize —
-    fp32 itemsize 4, kv_int8 itemsize 1 (+ two f32 scales per slot per
-    layer). A recurrent state (`BrumbyForCausalLM`): bytes = num_layers *
+    Pool sizing recipe, K/V pages (`GPTForCausalLM`): bytes = 2 (K and V)
+    * num_layers * num_slots * (max_len + 2, `models.ernie.DECODE_BLOCK`)
+    * heads * head_dim * itemsize: fp32 itemsize 4, kv_int8 itemsize 1 (+
+    two f32 scales per slot per layer). A recurrent state
+    (`BrumbyForCausalLM`): bytes = num_layers *
     num_slots * kv_heads * rows * (head_dim + 1) * 4 with rows =
     head_dim (head_dim + 1) / 2 rounded up to 128 — 0.275 GB a slot at
     Brumby-14B's widths and 8 layers, whatever `max_len` is (which only
@@ -172,28 +134,7 @@ class LLMConfig:
     warmup_on_start: bool = True
     quant: str = "off"          # "off" | "int8" weight-only decoder matmuls
     kv_int8: bool = False
-    # block width of one decode step (see module docstring; ROADMAP D6)
-    decode_block: int = 2
     idle_park_s: float = 0.02   # scheduler nap when no work is queued
-
-    @classmethod
-    def from_flags(cls) -> "LLMConfig":
-        buckets: Tuple[int, ...] = ()
-        raw = str(_flags.flag("llm_prefill_buckets") or "").strip()
-        if raw:
-            buckets = tuple(int(p) for p in raw.split(",") if p.strip())
-        ddl = float(_flags.flag("llm_default_deadline_ms"))
-        return cls(
-            num_slots=int(_flags.flag("llm_num_slots")),
-            max_len=int(_flags.flag("llm_max_len")),
-            prefill_buckets=buckets,
-            max_new_tokens=int(_flags.flag("llm_max_new_tokens")),
-            queue_depth=int(_flags.flag("llm_queue_depth")),
-            default_deadline_ms=ddl if ddl > 0 else None,
-            warmup_on_start=bool(_flags.flag("llm_warmup")),
-            quant=str(_flags.flag("llm_quant")),
-            kv_int8=bool(_flags.flag("llm_kv_int8")),
-        )
 
 
 class LLMStream:
@@ -280,75 +221,16 @@ class _Seq:
                 or int(self.prompt.size) + n_tokens - 1 >= max_len)
 
 
-class _PagedKV(nn.Layer):
-    """`GPTForCausalLM`'s K/V pages under the cache contract (module
-    docstring). Its own `forward_cached` takes (k, v) pairs and gives
-    logits for every position; here the flat list is paired up, the last
-    real position's logits are gathered (padding right of it writes rows
-    no query reads), and a decode step is widened to a block
-    `decode_block` wide with only row 0 real. With `kv_int8` the pages'
-    dequantization scales follow the pages in the list a prefill returns
-    and a decode step is handed; a decode step returns the pages only
-    (the scales of a live slot do not change)."""
-
-    cache_tag = "kv_pool"
-
-    def __init__(self, lm, block: int, kv_int8: bool):
-        super().__init__()
-        self.lm = lm
-        self._block = block
-        self._kv_int8 = kv_int8
-
-    def init_cache(self, batch_size, max_len, dtype="float32"):
-        """The `2 x layers` pages of `GPTModel.init_kv_cache` as one flat
-        list (k0, v0, k1, v1, ...), the sequence on axis 0."""
-        return [page for pair in self.lm.gpt.init_kv_cache(
-            batch_size, max_len, dtype=dtype) for page in pair]
-
-    def forward_cached(self, tokens, cache, positions, lengths=None):
-        import jax.numpy as jnp
-
-        from ..ops._dispatch import run_op
-
-        if lengths is not None:
-            pages = list(zip(cache[0::2], cache[1::2]))
-            logits, kv, scales = self.lm.forward_cached(tokens, pages,
-                                                        positions)
-
-            def _last(la, ln):
-                idx = (ln - 1).astype(jnp.int32)[:, None, None]
-                return jnp.take_along_axis(la, idx, axis=1)[:, 0]
-
-            last = run_op(_last, [logits, lengths], "llm_last_logits")
-            new = [page for pair in kv for page in pair]
-            if self._kv_int8:
-                new += [scale for pair in scales for scale in pair]
-            return last, new
-        n, block = len(cache), self._block
-        scales = None
-        if self._kv_int8:
-            n //= 2
-            scales = list(zip(cache[n::2], cache[n + 1::2]))
-        kv = list(zip(cache[0:n:2], cache[1:n:2]))
-        # [S, 1] -> [S, block]: row 0 real, the rest padding (bit-exactness
-        # trick — see module docstring)
-        blk = run_op(
-            lambda t: jnp.broadcast_to(t, (t.shape[0], block)),
-            [tokens], "llm_decode_block")
-        logits, kv, _ = self.lm.forward_cached(blk, kv, positions, scales)
-        return logits[:, 0], [page for pair in kv for page in pair]
-
-
 class _PrefillNet(nn.Layer):
     """One prefill executable per length bucket: (tokens [B, Lb],
     lengths [B]) -> (first greedy token [B], last-position logits [B, V],
     the fresh cache). The cache is created inside the trace so the wire
     signature is just the token block."""
 
-    def __init__(self, lm, page_len: int, dtype: str):
+    def __init__(self, lm, max_len: int, dtype: str):
         super().__init__()
         self.lm = lm
-        self._page_len = page_len
+        self._max_len = max_len
         self._dtype = dtype
 
     def forward(self, tokens, lengths):
@@ -357,7 +239,7 @@ class _PrefillNet(nn.Layer):
         from ..ops.search import argmax
 
         b = tokens.shape[0]
-        cache = self.lm.init_cache(b, self._page_len, dtype=self._dtype)
+        cache = self.lm.init_cache(b, self._max_len, dtype=self._dtype)
         last, cache = self.lm.forward_cached(
             tokens, cache, zeros([b], dtype="int32"), lengths)
         return (cast(argmax(last, axis=-1), "int32"), last, *cache)
@@ -394,23 +276,15 @@ class LLMEngine:
     _FAULT_SITE = "llm.decode"
 
     def __init__(self, model, config: Optional[LLMConfig] = None):
-        from ..models.gpt import GPTForCausalLM, GPTModel
-        cfg = config or LLMConfig.from_flags()
-        if isinstance(model, GPTModel):
-            model = GPTForCausalLM(model)
+        cfg = config or LLMConfig()
         self.config = cfg
         self.lm = model
-        # the model under the cache contract (module docstring): nothing
-        # in LLMConfig chooses which kind of cache runs
-        if isinstance(model, GPTForCausalLM):
-            model = _PagedKV(model, cfg.decode_block, cfg.kv_int8)
         if not all(hasattr(model, a) for a in
                    ("init_cache", "forward_cached", "cache_tag")):
             raise ServingError(
                 "LLMEngine needs a model that declares its cache: "
-                "init_cache, forward_cached and cache_tag "
-                "(BrumbyForCausalLM; GPTForCausalLM / GPTModel are put "
-                "under the contract here)")
+                "init_cache, forward_cached and cache_tag (module "
+                "docstring; GPTForCausalLM, BrumbyForCausalLM)")
         if cfg.kv_int8 and model.cache_tag != "kv_pool":
             raise ServingError("kv_int8 quantises K/V pages; this model "
                                f"keeps a {model.cache_tag}")
@@ -420,25 +294,14 @@ class LLMEngine:
             quant_weight_only(self.lm)
         elif cfg.quant not in ("", "off"):
             raise ServingError(f"unknown llm quant arm {cfg.quant!r}")
-        self._cached = model
         self._tag = model.cache_tag
-
-        self._page_len = cfg.max_len + cfg.decode_block
+        self._dtype = "int8" if cfg.kv_int8 else "float32"
         self.buckets = _prefill_ladder(cfg.max_len, cfg.prefill_buckets)
 
-        import jax.numpy as jnp
-        s = cfg.num_slots
-        # what the model says a sequence keeps, slot on axis 0: K/V pages
-        # (k0, v0, k1, v1, ...) or recurrent states (S0, z0, S1, z1, ...)
+        # what the model says a sequence keeps, slot on axis 0
         self._pool: List[Tensor] = self._zero_pool()
-        # ks0, vs0, ...: one [S] f32 dequantization scale a page and slot
-        self._scales: List[Tensor] = [
-            Tensor(jnp.ones((s,), jnp.float32)) for _ in self._pool
-        ] if cfg.kv_int8 else []
-
-        self._prefill = _PrefillNet(self._cached, self._page_len,
-                                    "int8" if cfg.kv_int8 else "float32")
-        self._decode = _DecodeNet(self._cached)
+        self._prefill = _PrefillNet(model, cfg.max_len, self._dtype)
+        self._decode = _DecodeNet(model)
         from ..jit import to_static
         # the programs' names in a trace: jit_llm_prefill, jit_llm_decode.
         # The engine owns the pool, so it alone may give it away: the
@@ -448,7 +311,7 @@ class LLMEngine:
         to_static(self._decode, name="llm_decode",
                   donate_inputs=slice(2, 2 + len(self._pool)))
 
-        self._free: List[int] = list(range(s))
+        self._free: List[int] = list(range(cfg.num_slots))
         self._active: Dict[int, _Seq] = {}
         # the decode step dispatched and not yet read (the scheduler
         # thread's own): its tokens, still on the device, and the (slot,
@@ -467,15 +330,14 @@ class LLMEngine:
 
     def _zero_pool(self) -> List[Tensor]:
         cfg = self.config
-        return list(self._cached.init_cache(
-            cfg.num_slots, self._page_len,
-            dtype="int8" if cfg.kv_int8 else "float32"))
+        return list(self.lm.init_cache(cfg.num_slots, cfg.max_len,
+                                       dtype=self._dtype))
 
     def _decode_pool(self, tokens, positions):
         """Run the decode program on (tokens, positions) and the pool,
-        which it consumes: `self._pool` is its output pages from the
+        which it consumes: `self._pool` is its output arrays from the
         moment the dispatch returns, so no other thread and no later line
-        ever holds a deleted page. `tokens` is a host array, or a
+        ever holds a deleted array. `tokens` is a host array, or a
         `Tensor` that is handed on as it is: an earlier step's `outs[0]`,
         still on the device and possibly not computed yet (it is never
         donated, so the caller may read it afterwards). Returns (outs,
@@ -490,12 +352,12 @@ class LLMEngine:
             tokens = Tensor(jnp.asarray(tokens))
         try:
             outs = self._decode(tokens, Tensor(jnp.asarray(positions)),
-                                *self._pool, *self._scales)
+                                *self._pool)
         except BaseException:
             if any(t._value.is_deleted() for t in self._pool):
                 self._pool = self._zero_pool()
             raise
-        self._pool = list(outs[2:2 + len(self._pool)])
+        self._pool = list(outs[2:])
         return outs, old.is_deleted()
 
     # ---- lifecycle ---------------------------------------------------------
@@ -577,7 +439,7 @@ class LLMEngine:
         if prompt.size > self.buckets[-1]:
             raise ServingError(
                 f"prompt length {prompt.size} exceeds the largest prefill "
-                f"bucket {self.buckets[-1]} (raise FLAGS_llm_max_len)")
+                f"bucket {self.buckets[-1]} (raise LLMConfig.max_len)")
         if self._stopped or self._thread is None:
             raise EngineStoppedError("LLM engine not running")
         if _slo._ENABLED and _slo.should_shed():
@@ -728,20 +590,13 @@ class LLMEngine:
             return jax.lax.dynamic_update_slice(
                 pool, row, (s,) + (0,) * (pool.ndim - 1))
 
-        def _cell(vec, val, s):
-            return jax.lax.dynamic_update_slice(vec, val, (s,))
-
-        pages = outs[2:2 + len(self._pool)]
-        svals = outs[2 + len(self._pool):]
+        rows = outs[2:]
         with _monitor.span("llm.slot_write", request_id=rid,
-                           writes=len(pages) + len(svals)):
+                           writes=len(rows)):
             slot_t = Tensor(jnp.asarray(slot, jnp.int32))
-            for i, page in enumerate(pages):
-                self._pool[i] = run_op(_row, [self._pool[i], page, slot_t],
+            for i, row in enumerate(rows):
+                self._pool[i] = run_op(_row, [self._pool[i], row, slot_t],
                                        "llm_slot_write")
-            for i, sv in enumerate(svals):
-                self._scales[i] = run_op(_cell, [self._scales[i], sv, slot_t],
-                                         "llm_scale_write")
         return first, lb, outs[1]
 
     def _prefill_into(self, seq: _Seq) -> None:
@@ -925,15 +780,14 @@ class LLMEngine:
 
     def _retag_pool(self) -> None:
         if _mem._ENABLED:
-            _mem.tag(self._tag,
-                     [t._value for t in (*self._pool, *self._scales)],
+            _mem.tag(self._tag, [t._value for t in self._pool],
                      origin="LLMEngine")
 
     # ---- introspection -----------------------------------------------------
 
     def kv_pool_bytes(self) -> int:
         total = 0
-        for t in (*self._pool, *self._scales):
+        for t in self._pool:
             v = t._value
             total += int(getattr(v, "nbytes", 0) or
                          int(np.prod(v.shape)) * v.dtype.itemsize)
@@ -946,7 +800,8 @@ class LLMEngine:
         return {
             "slots": self.config.num_slots, "active": active, "free": free,
             "queued": queued, "buckets": list(self.buckets),
-            "page_len": self._page_len, "kv_pool_bytes": self.kv_pool_bytes(),
+            "page_len": self._pool[0].shape[1],
+            "kv_pool_bytes": self.kv_pool_bytes(),
             "kv_int8": self.config.kv_int8, "quant": self.config.quant,
             "warm_start_ms": self._warm_ms,
             "counters": dict(self._counters),

@@ -15,10 +15,10 @@ import paddle_tpu as paddle
 from benchmarks.reference import brumby as reference
 from paddle_tpu import monitor
 from paddle_tpu.models.brumby import BrumbyForCausalLM, BrumbyModel
+from paddle_tpu.models.ernie import DECODE_BLOCK
 from paddle_tpu.models.gpt import GPTForCausalLM, GPTModel
 from paddle_tpu.serving import LLMConfig, LLMEngine
 from paddle_tpu.serving.engine import ServingError
-from paddle_tpu.serving.llm import _PagedKV
 
 pr = importlib.import_module("paddle_tpu.kernels.power_retention")
 
@@ -295,8 +295,17 @@ def test_init_cache_contract_of_both_models():
     assert all(str(c._value.dtype) == "bfloat16" for c in cache)
     gpt = GPTForCausalLM(GPTModel(vocab_size=64, hidden_size=32, num_layers=3,
                                   num_heads=4, max_seq_len=32, dropout=0.0))
-    pages = _PagedKV(gpt, 2, False).init_cache(5, 18)
-    assert [p.shape for p in pages] == [[5, 18, 32]] * 6
+    # the pages add the decode block's rows to max_len themselves
+    pages = gpt.init_cache(5, 16)
+    assert [p.shape for p in pages] == [[5, 16 + DECODE_BLOCK, 32]] * 6
+    assert all(str(p._value.dtype) == "float32" for p in pages)
+    # int8: the pages, then their dequantisation scales, slot on axis 0
+    cache = gpt.init_cache(5, 16, dtype="int8")
+    assert [c.shape for c in cache] == [[5, 18, 32]] * 6 + [[5]] * 6
+    assert [str(c._value.dtype) for c in cache] \
+        == ["int8"] * 6 + ["float32"] * 6
+    assert all(np.all(np.asarray(c.numpy()) == 1.0) for c in cache[6:])
+    assert GPTForCausalLM.cache_tag == "kv_pool"
     assert BrumbyForCausalLM.cache_tag == "state_pool"
 
 

@@ -138,6 +138,24 @@ def test_on_trace_ready_called_once_at_stop(tmp_path):
     assert p2._export_dir == d
 
 
+def test_no_directory_means_a_fresh_temp_directory(tmp_path, monkeypatch):
+    """A Profiler given no directory writes its device trace under the
+    temp directory, never into the working tree (ROADMAP D13)."""
+    import shutil
+    import tempfile
+    monkeypatch.chdir(tmp_path)
+    prof = Profiler()
+    assert prof._export_dir is None
+    with prof:
+        pass
+    try:
+        assert os.path.isdir(prof._export_dir)
+        assert os.path.dirname(prof._export_dir) == tempfile.gettempdir()
+        assert os.listdir(tmp_path) == []
+    finally:
+        shutil.rmtree(prof._export_dir, ignore_errors=True)
+
+
 class TestDeviceMemory:
     def test_memory_stats_surface(self):
         import paddle_tpu as paddle

@@ -251,7 +251,8 @@ def test_brumby_decode_program_rewrites_the_state_pool_in_place(sds,
     widths (vocabulary cut to 1024 rows: the head is not the subject), 2
     layers, 12 slots, from shapes: the whole state pool is donated and
     aliased out, each layer's update is the named kernel, and the program
-    is one token wide (no `decode_block` broadcast)."""
+    is one token wide (no `llm_decode_block` broadcast: that is
+    `GPTForCausalLM`'s, `models.ernie.DECODE_BLOCK` wide)."""
     import paddle_tpu as paddle
     from paddle_tpu.jit.functional import split_state
     from paddle_tpu.models.brumby import BrumbyForCausalLM, BrumbyModel
