@@ -301,6 +301,12 @@ KERNEL_FULL = ((8, 1024, 12, 64, False), (1, 8192, 12, 64, True),
 # bf16: the tolerance tests/test_flash_attention.py holds the kernel to
 BF16_TOL = 3e-2
 SCAN_FULL = dict(hidden=768, heads=12, ffn=3072, layers=2, batch=8, seq=1024)
+# the decode step's read in `gpt2_large.serve_decode`: 12 slots of a
+# [1026, 20 x 64] float32 page a side, a decode block of 2 query rows, fills
+# from a free slot (0) over block edges to the page's last position
+DECODE_FULL = dict(slots=12, page=1026, heads=20, head_dim=64, rows=2,
+                   positions=(0, 1, 63, 127, 128, 200, 383, 511, 640, 1000,
+                              1023, 0))
 
 
 def _fa():
@@ -459,23 +465,81 @@ def _scan_stack(hidden, heads, ffn, layers, batch, seq, seed, min_kernels,
             "tpu_custom_calls": n_kernels, "rel_err": errs}, setup_s, steady_s
 
 
-def phase_kernel(geometries=KERNEL_FULL, scan=SCAN_FULL, seed=0,
-                 min_kernels=2, tol=BF16_TOL) -> dict:
+def _decode_step(slots, page, heads, head_dim, rows, positions, seed,
+                 min_kernels, tol):
+    """`ErnieSelfAttention.forward_cached` over float32 pages at a decode
+    block's width: as the framework routes it (on a TPU, through
+    `kernels.decode_attention`) against itself held to the dense einsums
+    over the whole page. A serve cell's `correct` never compares a decode
+    step's logits, so this is where the chip checks the ragged read."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.models.ernie import ErnieSelfAttention
+
+    da = importlib.import_module("paddle_tpu.kernels.decode_attention")
+    hidden = heads * head_dim
+    paddle.seed(seed)
+    attn = ErnieSelfAttention(hidden, heads, dropout=0.0, causal=True)
+    attn.eval()
+    rng = np.random.default_rng(seed)
+    x, kc, vc = (jnp.asarray(rng.uniform(-1, 1, shape), jnp.float32)
+                 for shape in ((slots, rows, hidden),
+                               (slots, page, hidden), (slots, page, hidden)))
+    pos = jnp.asarray(positions, jnp.int32)
+
+    def step(x, kc, vc, pos):
+        with paddle.no_grad():
+            out, kc, vc, _, _ = attn.forward_cached(
+                Tensor(x), Tensor(kc), Tensor(vc), Tensor(pos))
+        return out._value, kc._value, vc._value
+
+    _, n_kernels, got, setup_s, steady_s = _run_twice(
+        jax.jit(step), x, kc, vc, pos)
+    _require(n_kernels >= min_kernels,
+             f"kernel: the decode step compiled with {n_kernels} "
+             f"tpu_custom_call, needs {min_kernels}: its read went to the "
+             "dense einsums")
+    engages = da.engages
+    da.engages = lambda *_: False
+    try:
+        want = jax.jit(lambda *a: step(*a))(x, kc, vc, pos)
+    finally:
+        da.engages = engages
+    errs = _check_against("kernel decode-step", got, want,
+                          ("out", "k_page", "v_page"), tol)
+    return {"decode_step": {"slots": slots, "page": page, "heads": heads,
+                            "head_dim": head_dim, "rows": rows,
+                            "positions": list(positions)},
+            "tpu_custom_calls": n_kernels, "rel_err": errs}, setup_s, steady_s
+
+
+def phase_kernel(geometries=KERNEL_FULL, scan=SCAN_FULL, decode=DECODE_FULL,
+                 seed=0, min_kernels=2, tol=BF16_TOL) -> dict:
     """The long-sequence attention the framework selects by itself: each
     geometry through `nn.functional` attention in bf16, and the scanned
-    ErnieLayer, forward and backward against `_reference_bhsd`.
-    `min_kernels` is 2 on the chip (a forward and a backward kernel in the
-    compiled text); only the CPU rehearsal, which interprets, passes 0."""
+    ErnieLayer, forward and backward against `_reference_bhsd`; then the
+    serve path's decode step (float32 pages, forward only) against its
+    dense read. `min_kernels` is 2 on the chip (a forward and a backward
+    kernel in the compiled text; the decode step needs one); only the CPU
+    rehearsal, which interprets, passes 0."""
     rows, setup_s, steady_s = [], 0.0, 0.0
     for i, (b, s, h, d, causal) in enumerate(geometries):
         row, su, st = _attention_geometry(b, s, h, d, causal, seed + i,
                                           min_kernels, tol)
         rows.append(row)
         setup_s, steady_s = setup_s + su, steady_s + st
-    row, su, st = _scan_stack(**scan, seed=seed, min_kernels=min_kernels,
-                              tol=tol)
-    rows.append(row)
-    return _report("kernel", setup_s + su, steady_s + st,
+    for row, su, st in (
+            _scan_stack(**scan, seed=seed, min_kernels=min_kernels, tol=tol),
+            _decode_step(**decode, seed=seed,
+                         min_kernels=min(min_kernels, 1), tol=tol)):
+        rows.append(row)
+        setup_s, steady_s = setup_s + su, steady_s + st
+    return _report("kernel", setup_s, steady_s,
                    {"tolerance": tol, "dtype": "bfloat16", "paths": rows})
 
 

@@ -123,10 +123,17 @@ class ErnieSelfAttention(nn.Layer):
         decode). k_cache/v_cache: [B, L, nh*hd] with L fixed (the slot
         page) — fp32, or int8 for the weight-only KV arm. positions: [B]
         int32, tokens already cached per row; the block's K/V are written
-        at positions[b]..positions[b]+T-1 and attention runs over the
-        whole page under a validity mask (key j visible to query i iff
-        j <= positions[b]+i), so every (B, T, L) signature is ONE
-        executable regardless of how full each row is.
+        at positions[b]..positions[b]+T-1 and key j is visible to query i
+        iff j <= positions[b]+i, so every (B, T, L) signature is ONE
+        executable regardless of how full each row is. How the page is
+        read follows T: a prompt attends over the whole page under that
+        validity mask with dense einsums; a decode block (on a TPU, over
+        floating-point pages, at the default matmul precision:
+        `kernels.decode_attention.engages`) reads of each row's page only
+        the row blocks below positions[b]+T, through one Pallas kernel
+        keyed on `positions`. Both are the same function of (x, pages,
+        positions); the kernel has no derivative (the serve path asks
+        for none).
 
         A cached position is ONE row of nh*hd values, heads folded into
         the row, because of where the TPU puts a page in memory: of a
@@ -152,6 +159,7 @@ class ErnieSelfAttention(nn.Layer):
         import jax
         import jax.numpy as jnp
 
+        from ..kernels import decode_attention as ragged
         from ..ops._dispatch import run_op
         from ..ops.math import _precision
 
@@ -189,6 +197,11 @@ class ErnieSelfAttention(nn.Layer):
 
             kc = jax.vmap(upd)(kc, kw.reshape(b, t, -1), pos)
             vc = jax.vmap(upd)(vc, vw.reshape(b, t, -1), pos)
+            if ragged.engages(t, kc.dtype):
+                # a decode block: read each page up to its own fill only
+                out = ragged.decode_attention(qa.reshape(b, t, -1), kc, vc,
+                                              pos, self.num_heads)
+                return out.reshape(qa.shape), kc, vc
             kr = kc.reshape(b, -1, self.num_heads, self.head_dim)
             vr = vc.reshape(b, -1, self.num_heads, self.head_dim)
             if quant:

@@ -698,6 +698,12 @@ class LLMEngine:
                 # a step reads and rewrites every state, live or free
                 _monitor.count("llm.decode.state_bytes",
                                self.kv_pool_bytes())
+            elif self._tag == "kv_pool":
+                # rows of a page a step has to read (a row's cached
+                # prefix and the token it writes), of the rows held
+                _monitor.count("llm.decode.kv_rows_live",
+                               int(pos.sum()) + len(rows))
+                _monitor.count("llm.decode.kv_rows_pool", s * cfg.max_len)
         return outs[0], rows
 
     def _collect(self, tokens: Tensor, rows) -> None:

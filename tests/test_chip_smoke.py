@@ -92,14 +92,28 @@ def test_kernel_phase_interprets_the_kernel_on_a_cpu(monkeypatch):
     from paddle_tpu.nn.functional import attention
     monkeypatch.setattr(attention, "jax", types.SimpleNamespace(
         default_backend=lambda: "tpu", nn=jax.nn, random=jax.random))
+    import importlib
+    da = importlib.import_module("paddle_tpu.kernels.decode_attention")
+    monkeypatch.setattr(da, "_on_tpu", lambda: True)
+    routed = []
+    attend = da.decode_attention
+    monkeypatch.setattr(da, "decode_attention", lambda *a: routed.append(
+        a[0].shape) or attend(*a))
     line = chip_smoke.phase_kernel(
         geometries=((1, 1024, 2, 64, True),),
         scan=dict(hidden=128, heads=2, ffn=256, layers=2, batch=1, seq=256),
+        decode=dict(slots=3, page=34, heads=2, head_dim=16, rows=2,
+                    positions=(0, 31, 9)),
         min_kernels=0)
-    attn, scan = line["check"]["paths"]
+    attn, scan, decode = line["check"]["paths"]
     assert (attn["forward"], attn["backward"]) == ("pallas", "fused")
     assert attn["tpu_custom_calls"] == scan["tpu_custom_calls"] == 0
     assert max(attn["rel_err"].values()) <= chip_smoke.BF16_TOL
+    # the decode step went through the (interpreted) kernel once, and the
+    # dense read it is compared with did not
+    assert routed == [(3, 2, 32)]
+    assert decode["rel_err"]["k_page"] == decode["rel_err"]["v_page"] == 0
+    assert decode["rel_err"]["out"] <= 1e-5
 
 
 def test_kernel_phase_refuses_a_program_without_the_kernel():

@@ -134,6 +134,26 @@ class TestCachedForwardBitIdentity:
     (`init_cache` / `forward_cached(..., lengths=)`) are called as
     `LLMEngine` calls them."""
 
+    def test_decode_through_the_ragged_kernel_is_the_full_forward(
+            self, monkeypatch):
+        """The same agreement with the decode step's read routed through
+        `kernels.decode_attention` (its backend test answers "a TPU"; the
+        kernel itself still sees a CPU and interprets, operands in
+        float32; like Brumby's state update it has no derivative, and the
+        serve path asks for none). The prompts stay on the dense einsums:
+        `engages` is asked with T = the prompt's length, past a decode
+        block."""
+        import importlib
+        da = importlib.import_module("paddle_tpu.kernels.decode_attention")
+        asked = []
+        engages = da.engages
+        monkeypatch.setattr(da, "_on_tpu", lambda: True)
+        monkeypatch.setattr(da, "engages", lambda t, dtype: asked.append(
+            (t, engages(t, dtype))) or asked[-1][1])
+        with paddle.no_grad():      # as the engine runs it: no tape
+            self.test_decode_bit_identical_to_full_forward(False, (12, 2, 57))
+        assert set(asked) == {(12, False), (57, False), (2, True)}
+
     @pytest.mark.parametrize("lengths", [(4,), (2, 57)],
                              ids=["one_row", "rows_near_0_and_near_the_end"])
     @pytest.mark.parametrize("lazy", [False, True],

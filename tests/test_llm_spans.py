@@ -76,10 +76,11 @@ def served(tmp_path_factory):
     paddle.set_flags({"FLAGS_monitor": True})
     eng = _engine()
     try:
-        assert eng.submit([9, 2]).result(timeout=120.0)[0] == "done"  # warm
+        prompts = [[9, 2], [9, 2, 3], [1, 2]]
+        assert eng.submit(prompts[0]).result(timeout=120.0)[0] == "done"  # warm
 
         def body():
-            streams = [eng.submit([9, 2, 3]), eng.submit([1, 2])]
+            streams = [eng.submit(p) for p in prompts[1:]]
             assert all(s.result(timeout=120.0)[0] == "done" for s in streams)
             time.sleep(0.1)     # the scheduler parks (20 ms naps) meanwhile
         lines = _trace(tmp_path_factory.mktemp("llm_trace"), body)
@@ -89,7 +90,8 @@ def served(tmp_path_factory):
         paddle.set_flags({"FLAGS_monitor": False})
         monitor.reset()
     holders = [ln for ln in lines if any(e[0] == "llm.step" for e in ln)]
-    return {"holders": holders, "snap": snap,
+    return {"holders": holders, "snap": snap, "prompts": prompts,
+            "pool_rows": eng.config.num_slots * eng.config.max_len,
             "events": [e for e in holders[0]
                        if e[0].startswith(("llm.", "jit."))]}
 
@@ -153,6 +155,46 @@ def test_read_starts_when_dispatch_has_returned(served):
 def test_span_count_equals_the_counter_of_its_boundary(served, span, counter):
     counters = served["snap"]["counters"]
     assert counters[f"span.{span}.count"] == counters[counter] > 0
+
+
+def test_kv_row_counters_are_the_live_prefixes_over_the_pool(served):
+    """`llm.decode.kv_rows_live`: over every row dispatched, the rows of
+    its page the step has to read (the cached prefix and the token being
+    written: position + 1); `llm.decode.kv_rows_pool`: the rows the pool
+    holds, once a step. No EOS is set, so each of the three requests
+    decodes max_new_tokens - 1 = 5 steps from its prompt's length on,
+    however the scheduler overlapped them."""
+    counters = served["snap"]["counters"]
+    assert counters["llm.decode.discarded"] == 0
+    assert counters["llm.decode.kv_rows_live"] == sum(
+        len(p) + k + 1 for p in served["prompts"] for k in range(5))
+    assert counters["llm.decode.kv_rows_pool"] == \
+        counters["llm.decode.steps"] * served["pool_rows"]
+
+
+def test_kv_row_counters_are_absent_under_a_state_pool():
+    """A model that keeps recurrent states has no rows to leave unread:
+    its step is priced by `llm.decode.state_bytes`."""
+    from paddle_tpu.models.brumby import BrumbyForCausalLM, BrumbyModel
+    paddle.seed(3)
+    lm = BrumbyForCausalLM(BrumbyModel(
+        dtype="float32", vocab_size=64, hidden_size=32, num_layers=2,
+        num_heads=4, num_kv_heads=2, head_dim=8, intermediate_size=64,
+        gate_bias=3.0))
+    lm.eval()
+    monitor.reset()
+    paddle.set_flags({"FLAGS_monitor": True})
+    eng = LLMEngine(lm, LLMConfig(num_slots=2, max_len=16,
+                                  max_new_tokens=4)).start()
+    try:
+        assert eng.submit([9, 2, 3]).result(timeout=120.0)[0] == "done"
+    finally:
+        eng.stop()
+        counters = monitor.snapshot()["counters"]
+        paddle.set_flags({"FLAGS_monitor": False})
+        monitor.reset()
+    assert counters["llm.decode.state_bytes"] > 0
+    assert not [k for k in counters if k.startswith("llm.decode.kv_rows")]
 
 
 def test_step_lasts_at_least_as_long_as_its_three_children(served):

@@ -154,7 +154,10 @@ def test_ernie_scan_layer_step_compiles(sds, monkeypatch):
     assert _kernels_in(jax.grad(loss, argnums=(0, 1)), x, wl) == 2
 
 
-def test_llm_decode_program_updates_the_pool_in_place(sds):
+@pytest.mark.parametrize("on_tpu", [False, True],
+                         ids=["dense_read", "ragged_kernel"])
+def test_llm_decode_program_updates_the_pool_in_place(sds, monkeypatch,
+                                                      on_tpu):
     """The serving engine's decode program at GPT-2-large widths (hidden
     1280, 20 heads, 12 slots, page 1026) cut to 2 layers, from shapes: the
     donated pool is aliased to the pool outputs, no synchronous copy of a
@@ -163,7 +166,10 @@ def test_llm_decode_program_updates_the_pool_in_place(sds):
     heads and head_dim as two axes the compiler lays the positions along
     the lanes and a row's write touches the slot's whole page). The
     per-slot write is still the scatter's loop, one per pool array: what
-    it costs is a chip's to say (PERF.md section 5)."""
+    it costs is a chip's to say (PERF.md section 5). As the chip lowers it
+    (`on_tpu`: the backend test answers "tpu") each layer reads its pages
+    through the `decode_attention` kernel, which takes the float32 page
+    itself: no page is converted to bfloat16 or copied for it."""
     import re
 
     import paddle_tpu as paddle
@@ -191,10 +197,14 @@ def test_llm_decode_program_updates_the_pool_in_place(sds):
     jitted = static._get_jitted(
         tuple(l.training for l in net.sublayers(include_self=True)),
         list(trainable), list(frozen), {}, False, donated)
-    compiled = jitted.lower(*static._call_args(
-        [sds(tuple(t.shape), t._value.dtype) for t in trainable.values()],
-        [sds(tuple(t.shape), t._value.dtype) for t in frozen.values()],
-        sds((), jax.random.key(0).dtype), inputs, donated)).compile()
+    if on_tpu:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with paddle.no_grad():
+        compiled = jitted.lower(*static._call_args(
+            [sds(tuple(t.shape), t._value.dtype)
+             for t in trainable.values()],
+            [sds(tuple(t.shape), t._value.dtype) for t in frozen.values()],
+            sds((), jax.random.key(0).dtype), inputs, donated)).compile()
     assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
     text = compiled.as_text()
     assert text.startswith("HloModule jit_llm_decode")
@@ -203,6 +213,11 @@ def test_llm_decode_program_updates_the_pool_in_place(sds):
     assert set(re.findall(re.escape(pool_shape) + r"\{([\d,]+):", entry)) \
         == {"2,1,0"}
     assert text.count(" while(") == 2 * layers
+    da = importlib.import_module("paddle_tpu.kernels.decode_attention")
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    assert kernels == (layers if on_tpu else 0)
+    assert (f"%{da.KERNEL}" in text) == on_tpu  # the name a trace reader finds
+    assert ("bf16" + pool_shape[3:] in text) != on_tpu
 
 
 def test_power_retention_step_kernel_compiles_in_place(sds, monkeypatch):
