@@ -30,16 +30,9 @@ the pool in the memory census: a recurrent state is `state_pool`):
 """
 from __future__ import annotations
 
-import contextlib
-import functools
-import math
-
-import jax
 import jax.numpy as jnp
 
 from .. import nn
-from ..core import random as rnd
-from ..core.dtype import get_default_dtype, set_default_dtype
 from ..framework.param_attr import ParamAttr
 from ..kernels import power_retention as _pr
 from ..nn import functional as F
@@ -47,43 +40,8 @@ from ..nn import initializer as I
 from ..ops._dispatch import run_op
 from ..ops.creation import arange
 from ..ops.manipulation import reshape, unsqueeze
-
-
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _draw(key, shape, dtype, std):
-    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
-class _Normal(I.Initializer):
-    """N(0, std) (std None: Xavier, sqrt(2 / (fan_in + fan_out)), as
-    `nn.Linear` starts) drawn by ONE program straight into the parameter's
-    dtype. The eager initializers hold up to three float32 copies of what
-    they draw: 9 GB for a [151936, 5120] table that is 1.6 GB in
-    bfloat16, beside the rest of a model that fills half the chip."""
-
-    def __init__(self, std=None):
-        self.std = std
-
-    def _generate(self, shape, dtype):
-        std = self.std or math.sqrt(2.0 / (shape[0] + shape[1]))
-        return _draw(rnd.next_key(), tuple(shape), jnp.dtype(dtype), std)
-
-
-def _linear(n_in, n_out, bias_attr=False):
-    return nn.Linear(n_in, n_out, weight_attr=ParamAttr(initializer=_Normal()),
-                     bias_attr=bias_attr)
-
-
-@contextlib.contextmanager
-def _parameters_in(dtype):
-    """Layers built inside create their parameters in `dtype` (a 14B
-    model built in float32 and cast would not fit beside itself)."""
-    was = get_default_dtype()
-    set_default_dtype(dtype)
-    try:
-        yield
-    finally:
-        set_default_dtype(was)
+from ._decoder import SwiGLU as BrumbyMLP
+from ._decoder import _linear, _logits, _Normal, _parameters_in, _rows_at
 
 
 class BrumbyRetention(nn.Layer):
@@ -151,17 +109,6 @@ class BrumbyRetention(nn.Layer):
             y, state, z = run_op(step, [q, k, v, log_g, state, z],
                                  "power_retention_step")
         return self._out(y), state, z
-
-
-class BrumbyMLP(nn.Layer):
-    def __init__(self, hidden_size, intermediate_size):
-        super().__init__()
-        self.gate_proj = _linear(hidden_size, intermediate_size)
-        self.up_proj = _linear(hidden_size, intermediate_size)
-        self.down_proj = _linear(intermediate_size, hidden_size)
-
-    def forward(self, x):
-        return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
 
 
 class BrumbyLayer(nn.Layer):
@@ -243,28 +190,12 @@ class BrumbyForCausalLM(nn.Layer):
         with _parameters_in(brumby.norm.weight.dtype):
             self.lm_head = _linear(hidden, vocab)
 
-    def _logits(self, h):
-        """Final norm and the untied head; logits in float32."""
-        return run_op(
-            lambda a, w: jnp.matmul(a, w, preferred_element_type=jnp.float32),
-            [self.brumby.norm(h), self.lm_head.weight], "lm_head")
-
-    @staticmethod
-    def _rows_at(h, index):
-        """h [B, T, hidden], index [B] or [B, P] -> h[b, index[b]] as
-        [B, hidden] or [B, P, hidden]."""
-        def f(a, i):
-            i = i.astype(jnp.int32)
-            if i.ndim == 1:
-                return jnp.take_along_axis(a, i[:, None, None], axis=1)[:, 0]
-            return jnp.take_along_axis(a, i[..., None], axis=1)
-        return run_op(f, [h, index], "llm_last_hidden")
-
     def forward(self, input_ids, at=None):
         """Logits [B, T, vocab]; with `at` [B] or [B, P], those of the
         positions `at[b]` only, [B, vocab] or [B, P, vocab]."""
         h = self.brumby(input_ids)
-        return self._logits(h if at is None else self._rows_at(h, at))
+        return _logits(self.brumby.norm, self.lm_head,
+                       h if at is None else _rows_at(h, at))
 
     def init_cache(self, batch_size, max_len=None, dtype="float32"):
         return self.brumby.init_cache(batch_size, max_len, dtype)
@@ -272,5 +203,5 @@ class BrumbyForCausalLM(nn.Layer):
     def forward_cached(self, input_ids, cache, positions, lengths=None):
         h, cache = self.brumby.forward_cached(input_ids, cache, positions,
                                               lengths)
-        last = h[:, 0] if lengths is None else self._rows_at(h, lengths - 1)
-        return self._logits(last), cache
+        last = h[:, 0] if lengths is None else _rows_at(h, lengths - 1)
+        return _logits(self.brumby.norm, self.lm_head, last), cache

@@ -20,5 +20,8 @@ from .loss import (  # noqa: F401
     triplet_margin_loss, square_error_cost, sigmoid_focal_loss, ctc_loss,
     margin_cross_entropy, dice_loss, log_loss, npair_loss, hsigmoid_loss,
 )
-from .attention import rotary_embedding, scaled_dot_product_attention  # noqa: F401
+from .attention import (  # noqa: F401
+    latent_attention_decode, latent_attention_prompt, latent_page_write,
+    rotary_embedding, scaled_dot_product_attention,
+)
 from .vision import grid_sample, affine_grid, temporal_shift  # noqa: F401

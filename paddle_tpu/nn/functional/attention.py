@@ -80,11 +80,13 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
     return run_op(f, [q, k, v], "scaled_dot_product_attention")
 
 
-def rotary_embedding(x, positions, theta=10000.0, name=None):
+def rotary_embedding(x, positions, theta=10000.0, name=None,
+                     interleaved=False):
     """Rotary position embedding, half-split (the Qwen / GPT-NeoX
     convention): with x = [x1, x2] the two halves of the last axis and
     angle[i] = position * theta^(-2i/d), the result is
-    [x1 cos - x2 sin, x2 cos + x1 sin].
+    [x1 cos - x2 sin, x2 cos + x1 sin]. `interleaved` pairs the entries
+    (2i, 2i + 1) instead (the GPT-J / DeepSeek convention).
 
     x: [batch, seq, heads, head_dim]; positions: [batch, seq] integers, a
     position per row and token (a cached decode step passes each row's own
@@ -101,8 +103,122 @@ def rotary_embedding(x, positions, theta=10000.0, name=None):
         angle = pos.astype(jnp.float32)[..., None, None] * inv   # [B,T,1,d/2]
         cos, sin = jnp.cos(angle), jnp.sin(angle)
         a32 = a.astype(jnp.float32)
+        if interleaved:
+            x1, x2 = a32[..., 0::2], a32[..., 1::2]
+            return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                             axis=-1).reshape(a.shape).astype(a.dtype)
         x1, x2 = a32[..., :half], a32[..., half:]
         return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                                axis=-1).astype(a.dtype)
 
     return run_op(f, [x, positions], "rotary_embedding")
+
+
+LATENT_ROW_BLOCK = 512     # rows of scores held at once over a prompt
+
+
+def _split_up(w_ukv, heads, nope, v_dim):
+    """[latent, heads * (nope + v)] -> W_uk [latent, heads, nope], W_uv
+    [latent, heads, v]."""
+    w = w_ukv.reshape(w_ukv.shape[0], heads, nope + v_dim)
+    return w[..., :nope], w[..., nope:]
+
+
+def latent_attention_prompt(q_nope, q_rope, latent, k_rope, w_ukv, lengths=None,
+                            name=None):
+    """Multi-head latent attention (DeepSeek-V2) over a prompt, EXPANDED:
+    every head's keys and values are built from the latent rows, then
+    causal softmax attention over nope + rope score dimensions.
+
+    q_nope [B, T, H, nope], q_rope [B, T, H, rope] (rotated), latent [B, T,
+    L] (normed), k_rope [B, T, rope] (rotated, shared by the heads), w_ukv
+    [L, H * (nope + v)], lengths [B] or None (keys >= lengths masked).
+    Returns [B, T, H, v] in latent's dtype. Scores in blocks of rows,
+    float32 statistics."""
+    tensors = [ensure_tensor(a) for a in (q_nope, q_rope, latent, k_rope,
+                                          w_ukv)]
+    if lengths is not None:
+        tensors.append(ensure_tensor(lengths))
+
+    def f(qn, qr, c, kr, w, *rest):
+        b, t, h, nope = qn.shape
+        v_dim = w.shape[1] // h - nope
+        w_uk, w_uv = _split_up(w, h, nope, v_dim)
+        kn = jnp.einsum("btl,lhd->bthd", c, w_uk)
+        v = jnp.einsum("btl,lhd->bthd", c, w_uv)
+        scale = 1.0 / math.sqrt(nope + qr.shape[-1])
+        cols = jnp.arange(t)
+        real = cols[None, :] < rest[0][:, None] if rest else None
+        out = []
+        for r0 in range(0, t, LATENT_ROW_BLOCK):
+            r1 = min(r0 + LATENT_ROW_BLOCK, t)
+            # a later key is never read: the slice is causal by construction
+            s = (jnp.einsum("bthd,bjhd->bhtj", qn[:, r0:r1], kn[:, :r1],
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("bthd,bjd->bhtj", qr[:, r0:r1], kr[:, :r1],
+                              preferred_element_type=jnp.float32)) * scale
+            keep = jnp.arange(r0, r1)[:, None] >= cols[None, :r1]
+            if real is not None:
+                keep = keep[None] & real[:, None, :r1]
+                keep = keep[:, None]
+            p = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+            out.append(jnp.einsum("bhtj,bjhd->bthd", p.astype(v.dtype),
+                                  v[:, :r1],
+                                  preferred_element_type=jnp.float32))
+        y = jnp.concatenate(out, axis=1) if len(out) > 1 else out[0]
+        return y.astype(c.dtype)
+
+    return run_op(f, tensors, "latent_attention_prompt")
+
+
+def latent_attention_decode(q_nope, q_rope, page, positions, w_ukv, name=None):
+    """One decode step of latent attention with the up-projection ABSORBED
+    into the query: a step reads the page's latent rows and never builds a
+    head's keys or values.
+
+        qc^h = W_uk^h^T q_nope^h;  score_j = qc^h . c_j + q_rope^h . kr_j
+        y^h  = W_uv^h (sum_j a_j c_j)
+
+    q_nope [B, H, nope], q_rope [B, H, rope] (rotated), page [B, max_len,
+    W], W >= L + rope (a row is [latent; rotary key; zeros]), with this
+    step's row ALREADY written at `positions` [B]; rows beyond `positions`
+    are masked. w_ukv [L, H * (nope + v)]. Returns [B, H, v] in the page's
+    dtype. The page is read whole and never sliced: a slice of its minor
+    axis is a copy of it."""
+    tensors = [ensure_tensor(a) for a in (q_nope, q_rope, page, positions,
+                                          w_ukv)]
+
+    def f(qn, qr, page, pos, w):
+        b, h, nope = qn.shape
+        rope, lat = qr.shape[-1], w.shape[0]
+        w_uk, w_uv = _split_up(w, h, nope, w.shape[1] // h - nope)
+        qc = jnp.einsum("bhd,lhd->bhl", qn, w_uk,
+                        preferred_element_type=jnp.float32)
+        q = jnp.concatenate(
+            [qc.astype(page.dtype), qr.astype(page.dtype),
+             jnp.zeros((b, h, page.shape[-1] - lat - rope), page.dtype)],
+            axis=-1)
+        s = jnp.einsum("bhl,bjl->bhj", q, page,
+                       preferred_element_type=jnp.float32)
+        s = s * (1.0 / math.sqrt(nope + rope))
+        keep = jnp.arange(page.shape[1])[None, :] <= pos[:, None]
+        p = jax.nn.softmax(jnp.where(keep[:, None], s, -1e30), axis=-1)
+        ctx = jnp.einsum("bhj,bjl->bhl", p.astype(page.dtype), page,
+                         preferred_element_type=jnp.float32)[..., :lat]
+        y = jnp.einsum("bhl,lhd->bhd", ctx.astype(w.dtype), w_uv,
+                       preferred_element_type=jnp.float32)
+        return y.astype(page.dtype)
+
+    return run_op(f, tensors, "latent_attention_decode")
+
+
+def latent_page_write(page, rows, positions, name=None):
+    """page [B, max_len, W] with rows [B, <= W] (zeros after them) written
+    at positions [B]."""
+    def f(page, rows, pos):
+        rows = jnp.pad(rows.astype(page.dtype),
+                       ((0, 0), (0, page.shape[-1] - rows.shape[-1])))
+        return page.at[jnp.arange(page.shape[0]), pos.astype(jnp.int32)].set(
+            rows, unique_indices=True)
+    return run_op(f, [ensure_tensor(page), ensure_tensor(rows),
+                      ensure_tensor(positions)], "latent_page_write")

@@ -7,6 +7,7 @@ from .pooling import *  # noqa: F401,F403
 from .loss import *  # noqa: F401,F403
 from .container import Sequential, LayerList, LayerDict, ParameterList  # noqa: F401
 from .rnn import *  # noqa: F401,F403
+from .routed_experts import RoutedExperts  # noqa: F401
 from .transformer import (  # noqa: F401
     MultiHeadAttention, TransformerEncoderLayer, TransformerEncoder,
     TransformerDecoderLayer, TransformerDecoder, Transformer,

@@ -29,14 +29,14 @@ from .topology import get_mesh
 
 
 def _constrain(arr, *spec):
-    """Apply a sharding constraint when tracing under a mesh (GSPMD regime)."""
+    """Apply a sharding constraint when tracing under a mesh (GSPMD
+    regime). A constraint the mesh cannot take (an axis it lacks, a rank
+    the spec does not fit) raises: swallowed, the layer would run
+    replicated and say nothing."""
     mesh = get_mesh()
     if mesh is None or not isinstance(arr, jax.core.Tracer):
         return arr
-    try:
-        return lax.with_sharding_constraint(arr, NamedSharding(mesh, P(*spec)))
-    except Exception:
-        return arr
+    return lax.with_sharding_constraint(arr, NamedSharding(mesh, P(*spec)))
 
 
 class ColumnParallelLinear(Layer):

@@ -14,7 +14,17 @@ wastes nearly all decode FLOPs and fixed batches idle between stragglers.
   `cache`; `cache_tag`, the pool's tag in the memory census (`kv_pool`:
   `GPTForCausalLM`'s K/V pages, `models/gpt.py`; `state_pool`:
   `BrumbyForCausalLM`'s recurrent state, `models/brumby.py`, where
-  `llm.decode.state_bytes` counts what a step rewrites). The engine builds
+  `llm.decode.state_bytes` counts what a step rewrites), or a tuple of
+  them, one tag an array of `init_cache`'s list, for a model that keeps
+  both kinds (`LingForCausalLM`, `models/ling.py`: a recurrent state and
+  a convolution's rows per linear-attention layer, a latent page per
+  attention layer): each group is tagged and counted by its own rule.
+  `forward_cached` may return MORE arrays than `init_cache` gave: the
+  first `len(init_cache(...))` are the cache, what follows is the model's
+  own report of the call (`LingForCausalLM`: the experts every routed
+  layer chose, a row and position), which both programs hand out after
+  the cache and the engine neither keeps nor reads.
+  The engine builds
   the list once with `batch = num_slots` (the pool), hands ALL of it
   donated into `jit_llm_decode` (`to_static(..., donate_inputs=...)`),
   which updates the buffers it was given and aliases them to its outputs
@@ -117,7 +127,12 @@ class LLMConfig:
     Brumby-14B's widths and 8 layers, whatever `max_len` is (which only
     bounds a sequence's positions there). `LLMEngine.kv_pool_bytes()`
     reports the real figure of either and the census publishes it as
-    `mem.kv_pool.bytes` / `mem.state_pool.bytes`. That is what the pool
+    `mem.kv_pool.bytes` / `mem.state_pool.bytes`. A mixed pool
+    (`LingForCausalLM`): bytes a slot = the parts that do not grow
+    (per linear-attention layer heads * head_dim^2 * 4 of state and 3 *
+    3 * heads * head_dim * itemsize of convolution rows) + the page
+    (attention layers * max_len * (latent + rope) * itemsize); each
+    group is published under its own tag. That is what the pool
     costs on the device: the decode program takes it donated, so beside
     the weights a deployment budgets the pool once (the TPU pads a
     page's position axis to its tile of 8 rows in fp32: 1026 positions
@@ -294,12 +309,22 @@ class LLMEngine:
             quant_weight_only(self.lm)
         elif cfg.quant not in ("", "off"):
             raise ServingError(f"unknown llm quant arm {cfg.quant!r}")
-        self._tag = model.cache_tag
         self._dtype = "int8" if cfg.kv_int8 else "float32"
         self.buckets = _prefill_ladder(cfg.max_len, cfg.prefill_buckets)
 
         # what the model says a sequence keeps, slot on axis 0
         self._pool: List[Tensor] = self._zero_pool()
+        # one tag an array; a string stands for every array
+        tags = model.cache_tag
+        self._tags: Tuple[str, ...] = ((tags,) * len(self._pool)
+                                       if isinstance(tags, str)
+                                       else tuple(tags))
+        if len(self._tags) != len(self._pool) or \
+                set(self._tags) - {"kv_pool", "state_pool"}:
+            raise ServingError(
+                f"cache_tag {model.cache_tag!r} does not tag the "
+                f"{len(self._pool)} arrays of init_cache as kv_pool or "
+                "state_pool")
         self._prefill = _PrefillNet(model, cfg.max_len, self._dtype)
         self._decode = _DecodeNet(model)
         from ..jit import to_static
@@ -342,8 +367,8 @@ class LLMEngine:
         still on the device and possibly not computed yet (it is never
         donated, so the caller may read it afterwards). Returns (outs,
         donated): outs[0] the greedy tokens, outs[1] the logits, then the
-        pool; donated is whether the old buffers were really given away
-        (a host attribute read). Nothing here waits for the device. A
+        pool, then whatever else the model reports; donated is whether
+        the old buffers were really given away (a host attribute read). Nothing here waits for the device. A
         dispatch that fails after taking the pool leaves a zero pool
         behind; every sequence is lost with it either way."""
         import jax.numpy as jnp
@@ -357,7 +382,7 @@ class LLMEngine:
             if any(t._value.is_deleted() for t in self._pool):
                 self._pool = self._zero_pool()
             raise
-        self._pool = list(outs[2:])
+        self._pool = list(outs[2:2 + len(self._pool)])
         return outs, old.is_deleted()
 
     # ---- lifecycle ---------------------------------------------------------
@@ -570,7 +595,8 @@ class LLMEngine:
         its bucket, through that bucket's program, and the cache it
         returns written into `slot` of the pool, one
         `dynamic_update_slice` on axis 0 an array. Returns (first greedy
-        token, bucket, last-position logits [1, V])."""
+        token, bucket, last-position logits [1, V], what the model reports
+        beside its cache)."""
         import jax
         import jax.numpy as jnp
 
@@ -590,20 +616,20 @@ class LLMEngine:
             return jax.lax.dynamic_update_slice(
                 pool, row, (s,) + (0,) * (pool.ndim - 1))
 
-        rows = outs[2:]
+        rows = outs[2:2 + len(self._pool)]
         with _monitor.span("llm.slot_write", request_id=rid,
                            writes=len(rows)):
             slot_t = Tensor(jnp.asarray(slot, jnp.int32))
             for i, row in enumerate(rows):
                 self._pool[i] = run_op(_row, [self._pool[i], row, slot_t],
                                        "llm_slot_write")
-        return first, lb, outs[1]
+        return first, lb, outs[1], outs[2 + len(self._pool):]
 
     def _prefill_into(self, seq: _Seq) -> None:
         cfg = self.config
         plen = int(seq.prompt.size)
-        first, lb, _ = self._prefill_slot(seq.prompt, seq.slot,
-                                          seq.stream.request_id)
+        first, lb, *_ = self._prefill_slot(seq.prompt, seq.slot,
+                                           seq.stream.request_id)
         with _monitor.span("llm.emit"):
             now = time.monotonic()
             seq.pos = plen
@@ -694,11 +720,12 @@ class LLMEngine:
                 _monitor.count("llm.decode.ahead")
             if donated:
                 _monitor.count("llm.decode.pool_donated")
-            if self._tag == "state_pool":
+            _monitor.count("llm.decode.rows", len(rows))
+            if "state_pool" in self._tags:
                 # a step reads and rewrites every state, live or free
                 _monitor.count("llm.decode.state_bytes",
-                               self.kv_pool_bytes())
-            elif self._tag == "kv_pool":
+                               self.kv_pool_bytes("state_pool"))
+            if "kv_pool" in self._tags:
                 # rows of a page a step has to read (a row's cached
                 # prefix and the token it writes), of the rows held
                 _monitor.count("llm.decode.kv_rows_live",
@@ -786,14 +813,19 @@ class LLMEngine:
 
     def _retag_pool(self) -> None:
         if _mem._ENABLED:
-            _mem.tag(self._tag, [t._value for t in self._pool],
-                     origin="LLMEngine")
+            for tag in sorted(set(self._tags)):
+                _mem.tag(tag, [t._value for t, mine in
+                               zip(self._pool, self._tags) if mine == tag],
+                         origin="LLMEngine")
 
     # ---- introspection -----------------------------------------------------
 
-    def kv_pool_bytes(self) -> int:
+    def kv_pool_bytes(self, tag: Optional[str] = None) -> int:
+        """Bytes of the pool, or of its arrays tagged `tag`."""
         total = 0
-        for t in self._pool:
+        for t, mine in zip(self._pool, self._tags):
+            if tag is not None and mine != tag:
+                continue
             v = t._value
             total += int(getattr(v, "nbytes", 0) or
                          int(np.prod(v.shape)) * v.dtype.itemsize)
@@ -803,10 +835,13 @@ class LLMEngine:
         with self._lock:
             active, free, queued = (len(self._active), len(self._free),
                                     len(self._pending))
+        # a page's positions: absent where the model keeps no page
+        pages = [t.shape[1] for t, tag in zip(self._pool, self._tags)
+                 if tag == "kv_pool" and len(t.shape) > 1]
         return {
             "slots": self.config.num_slots, "active": active, "free": free,
             "queued": queued, "buckets": list(self.buckets),
-            "page_len": self._pool[0].shape[1],
+            **({"page_len": pages[0]} if pages else {}),
             "kv_pool_bytes": self.kv_pool_bytes(),
             "kv_int8": self.config.kv_int8, "quant": self.config.quant,
             "warm_start_ms": self._warm_ms,

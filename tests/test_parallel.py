@@ -338,3 +338,45 @@ class TestInterleavedPipeline:
         out_pipe = engine.eval_batch([x], compute_loss=False)
         np.testing.assert_allclose(out_pipe.numpy(), out_seq.numpy(),
                                    rtol=1e-5, atol=1e-5)
+
+
+class TestShardingConstraintOnFourDevices:
+    """`mp_layers._constrain` on a dp 2 x mp 2 mesh of four devices: the
+    constraint reaches the compiled program, and one the mesh cannot take
+    raises where it used to be swallowed."""
+
+    def _mesh(self):
+        return create_mesh({"dp": 2, "mp": 2}, devices=jax.devices()[:4])
+
+    def test_column_parallel_output_is_sharded_over_mp(self):
+        from paddle_tpu.parallel.topology import set_mesh
+        mesh = self._mesh()
+        try:
+            layer = ColumnParallelLinear(16, 32, gather_output=False)
+            args = [layer.weight._value, layer.bias._value]
+
+            def f(x, w, b):
+                layer.weight._value, layer.bias._value = w, b
+                return layer(paddle.to_tensor(x))._value
+
+            with mesh:
+                out = jax.jit(f)(jnp.asarray(_r(8, 16)), *args)
+            layer.weight._value, layer.bias._value = args
+            assert len(out.sharding.device_set) == 4
+            assert out.sharding.shard_shape(out.shape) == (8, 16)
+            assert out.shape == (8, 32)
+        finally:
+            set_mesh(None)
+
+    @pytest.mark.parametrize("spec", [(None, "tp"), (None, None, "mp")],
+                             ids=["an_axis_the_mesh_lacks",
+                                  "a_rank_the_spec_does_not_fit"])
+    def test_a_constraint_the_mesh_cannot_take_raises(self, spec):
+        from paddle_tpu.parallel.mp_layers import _constrain
+        from paddle_tpu.parallel.topology import set_mesh
+        mesh = self._mesh()
+        try:
+            with mesh, pytest.raises(Exception):
+                jax.jit(lambda a: _constrain(a, *spec))(jnp.ones((4, 8)))
+        finally:
+            set_mesh(None)

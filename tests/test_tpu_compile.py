@@ -307,3 +307,93 @@ def test_brumby_decode_program_rewrites_the_state_pool_in_place(sds,
     assert text.startswith("HloModule jit_llm_decode")
     assert text.count(f"%{pr.STEP_KERNEL}") >= layers
     assert text.count('custom_call_target="tpu_custom_call"') == layers
+
+
+def _ling_engine(slots):
+    """A Ling model at the published widths cut to three layers (the dense
+    KDA layer 0, the KDA expert layer 10, the MLA expert layer 11), 16 of
+    512 experts held and 1024 rows of vocabulary (neither is the subject:
+    the kernels' geometry is the widths'), in an engine at the cell's
+    shapes, from shapes."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.ling import LingForCausalLM, LingModel
+    from paddle_tpu.serving import LLMConfig, LLMEngine
+
+    paddle.seed(0)
+    lm = LingForCausalLM(LingModel(vocab_size=1024, layers=[0, 10, 11],
+                                   held=(0, 16), dtype="bfloat16"))
+    eng = LLMEngine(lm, LLMConfig(num_slots=slots, max_len=5120,
+                                  prefill_buckets=(256, 768, 1536, 3072),
+                                  warmup_on_start=False))
+    return lm, eng
+
+
+def _compile_net(net, inputs, donated, sds, monkeypatch):
+    import paddle_tpu as paddle
+    from paddle_tpu.jit.functional import split_state
+
+    static = net.forward
+    trainable, frozen = split_state(net)
+    jitted = static._get_jitted(
+        tuple(l.training for l in net.sublayers(include_self=True)),
+        list(trainable), list(frozen), {}, False, donated)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with paddle.no_grad():
+        return jitted.lower(*static._call_args(
+            [sds(tuple(t.shape), t._value.dtype)
+             for t in trainable.values()],
+            [sds(tuple(t.shape), t._value.dtype) for t in frozen.values()],
+            sds((), jax.random.key(0).dtype), inputs, donated)).compile()
+
+
+def test_ling_decode_program_updates_its_mixed_pool_in_place(sds,
+                                                             monkeypatch):
+    """The engine's decode program over a Ling model, 48 slots: the whole
+    pool (two KDA states, their convolution rows, one latent page of 5120
+    rows of 640: 576 in whole 128 lanes) is donated and aliased out; each KDA layer's update is the named
+    kernel and each expert layer's pass two calls of the grouped matmul
+    under its name."""
+    kda = importlib.import_module("paddle_tpu.kernels.kda")
+    gm = importlib.import_module("paddle_tpu.kernels.grouped_matmul")
+    slots = 48
+    lm, eng = _ling_engine(slots)
+    assert lm.cache_tag == ("state_pool",) * 4 + ("kv_pool",)
+    inputs = [sds((slots,), jnp.int32), sds((slots,), jnp.int32)] + [
+        sds(tuple(t.shape), t._value.dtype) for t in eng._pool]
+    pool_bytes = sum(t._value.nbytes for t in eng._pool)
+    assert pool_bytes == slots * (2 * (32 * 128 * 128 * 4 + 3 * 12288 * 2)
+                                  + 5120 * 640 * 2)
+    eng._pool = []                      # shapes are all the compile needs
+    donated = eng._decode.forward._donated(len(inputs))
+    assert donated == tuple(range(2, 7))
+    compiled = _compile_net(eng._decode, inputs, donated, sds, monkeypatch)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # no copy of the page: its rows are whole 128-lane tiles (at 576 the
+    # compiler held it in another order and copied it in and out: 0.33 GB)
+    assert mem.temp_size_in_bytes < pool_bytes // 16
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_llm_decode")
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 + 2 * 2
+    assert text.count(f"%{kda.STEP_KERNEL}") >= 2
+    assert text.count(f"%{gm.KERNEL}") >= 4
+
+
+def test_ling_prefill_program_compiles_at_the_largest_bucket(sds,
+                                                             monkeypatch):
+    """One prompt at the 3072 bucket: the chunked KDA form, expanded latent
+    attention in blocks of rows and the grouped matmul at its tile of 128
+    rows, in under 1.5 GB of temporaries; it returns a slot's whole
+    cache."""
+    gm = importlib.import_module("paddle_tpu.kernels.grouped_matmul")
+    lm, eng = _ling_engine(4)
+    eng._pool = []
+    compiled = _compile_net(
+        eng._prefill, [sds((1, 3072), jnp.int32), sds((1,), jnp.int32)], (),
+        sds, monkeypatch)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5 * 2 ** 30
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_llm_prefill")
+    assert text.count(f"%{gm.KERNEL}") >= 4
+    assert "bf16[1,5120,640]" in text         # the page, whole
