@@ -24,20 +24,20 @@ wastes nearly all decode FLOPs and fixed batches idle between stragglers.
   own report of the call (`LingForCausalLM`: the experts every routed
   layer chose, a row and position), which both programs hand out after
   the cache and the engine neither keeps nor reads.
-  The engine builds
-  the list once with `batch = num_slots` (the pool), hands ALL of it
-  donated into `jit_llm_decode` (`to_static(..., donate_inputs=...)`),
-  which updates the buffers it was given and aliases them to its outputs
-  (`self._pool` is the program's outputs from the moment the dispatch
-  returns; `llm.decode.pool_donated` counts the steps that gave the old
-  ones away), and writes a prefilled sequence into its slot with one
-  `dynamic_update_slice` on axis 0 per array: it never looks inside.
-- **Two kinds of program, fixed shapes.** Sequences borrow a slot for
+  The engine builds the list once with `batch = num_slots` (the pool)
+  and hands ALL of it donated (`to_static(..., donate_inputs=...)`) to
+  the two programs that change it, `jit_llm_decode` and
+  `jit_llm_slot_write`: each updates the buffers it was given and aliases
+  them out (`self._pool` is the program's outputs from the moment the
+  dispatch returns; `llm.decode.pool_donated` and
+  `llm.slot_write.donated` count the steps and the admissions that gave
+  the old ones away). The engine never looks inside an array.
+- **Three kinds of program, fixed shapes.** Sequences borrow a slot for
   their lifetime; shapes never depend on which slots are live, so steady
-  state runs one prefill executable per length bucket and one decode
+  state runs one prefill executable per length bucket, one slot write
+  (every array's row block at `slot`, which is data) and one decode
   executable, with ZERO steady-state compiles (the `jit.*` retrace
-  counters stay flat; tests assert it). `llm.prefill.tokens_real` /
-  `llm.prefill.tokens_bucket` count what the buckets' padding costs.
+  counters stay flat). `llm.prefill.tokens_real` / `tokens_bucket`: padding.
 - **Continuous scheduler, one decode step ahead.** Every turn admits
   queued sequences into free slots and evicts on EOS/length/deadline,
   streaming each token to the caller the moment the host holds it (and
@@ -67,8 +67,8 @@ wastes nearly all decode FLOPs and fixed batches idle between stragglers.
 
 Reference parity: this is the Paddle-Serving deployment role (PAPER.md
 §1 row 8) taken to continuous batching over a paged KV cache: the
-vLLM-style iteration-level scheduler, built TPU-first (fixed shapes, two
-executables, zero steady-state compiles) instead of kernel-first.
+vLLM-style iteration-level scheduler, built TPU-first (fixed shapes, three
+kinds of executable, no steady-state compiles) instead of kernel-first.
 """
 from __future__ import annotations
 
@@ -133,11 +133,11 @@ class LLMConfig:
     3 * heads * head_dim * itemsize of convolution rows) + the page
     (attention layers * max_len * (latent + rope) * itemsize); each
     group is published under its own tag. That is what the pool
-    costs on the device: the decode program takes it donated, so beside
-    the weights a deployment budgets the pool once (the TPU pads a
-    page's position axis to its tile of 8 rows in fp32: 1026 positions
-    occupy 1032), plus one more pool array (one layer's K, V or state)
-    while an admission writes its slot out of place."""
+    costs on the device: the decode step and the slot write take it
+    donated, so beside the weights a deployment budgets the pool once
+    (the TPU pads a page's position axis to its tile of 8 rows in fp32:
+    1026 positions occupy 1032), plus one sequence's fresh cache between
+    an admission's prefill and its write."""
 
     num_slots: int = 8
     max_len: int = 256
@@ -279,6 +279,28 @@ class _DecodeNet(nn.Layer):
         return (cast(argmax(last, axis=-1), "int32"), last, *cache)
 
 
+class _SlotWriteNet(nn.Layer):
+    """THE slot write of an admission: (slot [] int32, *pool, *rows) ->
+    the pool with every array's row block at `slot` replaced by its
+    `rows` array (a prefill's fresh cache, `[1, ...]` each), one
+    `dynamic_update_slice` on axis 0 an array. `slot` is data: one
+    executable serves every slot and every prefill bucket."""
+
+    def forward(self, slot, *arrays):
+        import jax
+
+        from ..ops._dispatch import nondiff_op
+
+        n = len(arrays) // 2
+
+        def write(s, *a):
+            return tuple(jax.lax.dynamic_update_slice(
+                pool, row, (s,) + (0,) * (pool.ndim - 1))
+                for pool, row in zip(a[:n], a[n:]))
+
+        return nondiff_op(write, [slot, *arrays])
+
+
 class LLMEngine:
     """Continuous-batching scheduler over a slot-paged pool of whatever
     the model keeps a sequence: K/V pages or a recurrent state.
@@ -327,14 +349,18 @@ class LLMEngine:
                 "state_pool")
         self._prefill = _PrefillNet(model, cfg.max_len, self._dtype)
         self._decode = _DecodeNet(model)
+        self._slot_write = _SlotWriteNet()
         from ..jit import to_static
-        # the programs' names in a trace: jit_llm_prefill, jit_llm_decode.
-        # The engine owns the pool, so it alone may give it away: the
-        # decode program takes the pool (its inputs after tokens and
-        # positions) donated, writes it in place and aliases it out.
+        # the programs' names in a trace: jit_llm_prefill, jit_llm_decode,
+        # jit_llm_slot_write. The engine owns the pool, so it alone may
+        # give it away: the decode program (its inputs after tokens and
+        # positions) and the slot write (after the slot) take the pool
+        # donated, write it in place and alias it out.
         to_static(self._prefill, name="llm_prefill")
         to_static(self._decode, name="llm_decode",
                   donate_inputs=slice(2, 2 + len(self._pool)))
+        to_static(self._slot_write, name="llm_slot_write",
+                  donate_inputs=slice(1, 1 + len(self._pool)))
 
         self._free: List[int] = list(range(cfg.num_slots))
         self._active: Dict[int, _Seq] = {}
@@ -358,32 +384,47 @@ class LLMEngine:
         return list(self.lm.init_cache(cfg.num_slots, cfg.max_len,
                                        dtype=self._dtype))
 
-    def _decode_pool(self, tokens, positions):
-        """Run the decode program on (tokens, positions) and the pool,
-        which it consumes: `self._pool` is its output arrays from the
-        moment the dispatch returns, so no other thread and no later line
-        ever holds a deleted array. `tokens` is a host array, or a
-        `Tensor` that is handed on as it is: an earlier step's `outs[0]`,
-        still on the device and possibly not computed yet (it is never
-        donated, so the caller may read it afterwards). Returns (outs,
-        donated): outs[0] the greedy tokens, outs[1] the logits, then the
-        pool, then whatever else the model reports; donated is whether
-        the old buffers were really given away (a host attribute read). Nothing here waits for the device. A
-        dispatch that fails after taking the pool leaves a zero pool
-        behind; every sequence is lost with it either way."""
-        import jax.numpy as jnp
+    def _run_on_pool(self, program, head, tail=(), at=0):
+        """Run `program` on (*head, *pool, *tail). It consumes the pool:
+        `self._pool` is its output arrays (from `at` on) from the moment
+        the dispatch returns, so no other thread and no later line ever
+        holds a deleted array. Returns (outs, donated): donated is whether
+        the old buffers were really given away (a host attribute read).
+        Nothing here waits for the device. A dispatch that fails after
+        taking the pool leaves a zero pool behind; every sequence is lost
+        with it either way."""
         old = self._pool[0]._value
-        if not isinstance(tokens, Tensor):
-            tokens = Tensor(jnp.asarray(tokens))
         try:
-            outs = self._decode(tokens, Tensor(jnp.asarray(positions)),
-                                *self._pool)
+            outs = program(*head, *self._pool, *tail)
         except BaseException:
             if any(t._value.is_deleted() for t in self._pool):
                 self._pool = self._zero_pool()
             raise
-        self._pool = list(outs[2:2 + len(self._pool)])
+        self._pool = list(outs[at:at + len(self._pool)])
         return outs, old.is_deleted()
+
+    def _decode_pool(self, tokens, positions):
+        """The decode program on (tokens, positions) and the pool
+        (`_run_on_pool`). `tokens` is a host array, or a `Tensor` that is
+        handed on as it is: an earlier step's `outs[0]`, still on the
+        device and possibly not computed yet (it is never donated, so the
+        caller may read it afterwards). Returns (outs, donated): outs[0]
+        the greedy tokens, outs[1] the logits, then the pool, then
+        whatever else the model reports."""
+        import jax.numpy as jnp
+        if not isinstance(tokens, Tensor):
+            tokens = Tensor(jnp.asarray(tokens))
+        return self._run_on_pool(
+            self._decode, (tokens, Tensor(jnp.asarray(positions))), at=2)
+
+    def _write_slot(self, slot: int, rows: Sequence[Tensor]) -> bool:
+        """The slot-write program on the pool (`_run_on_pool`): `rows`, a
+        prefill's fresh cache, lands in `slot` of every array, in place.
+        Returns whether the old buffers were given away."""
+        import jax.numpy as jnp
+        return self._run_on_pool(
+            self._slot_write, (Tensor(jnp.asarray(slot, jnp.int32)),),
+            tuple(rows))[1]
 
     # ---- lifecycle ---------------------------------------------------------
 
@@ -398,25 +439,28 @@ class LLMEngine:
         return self
 
     def _warmup(self) -> None:
-        """Trace+compile every prefill bucket and the decode step up
-        front so steady-state serving performs zero compiles."""
+        """Trace+compile every prefill bucket, the slot write and the
+        decode step up front so steady-state serving performs zero
+        compiles."""
         import jax.numpy as jnp
         t0 = time.monotonic()
         with no_grad():
             for lb in self.buckets:
-                self._prefill(Tensor(jnp.zeros((1, lb), jnp.int32)),
-                              Tensor(jnp.ones((1,), jnp.int32)))
+                fresh = self._prefill(Tensor(jnp.zeros((1, lb), jnp.int32)),
+                                      Tensor(jnp.ones((1,), jnp.int32)))
             s = self.config.num_slots
-            # the pool is zeros and no slot is live: the junk rows this
-            # writes at position 0 are never read. Once on host tokens and
-            # once on the step's own, the two ways the scheduler feeds it
+            # the pool is zeros and no slot is live: what this writes into
+            # slot 0, and the junk rows at position 0, are never read (an
+            # admission replaces its slot whole). The decode step once on
+            # host tokens and once on its own, the two ways it is fed
+            self._write_slot(0, fresh[2:2 + len(self._pool)])
             outs, _ = self._decode_pool(np.zeros((s,), np.int32),
                                         np.zeros((s,), np.int32))
             self._decode_pool(outs[0], np.zeros((s,), np.int32))
         self._warm_ms = (time.monotonic() - t0) * 1000.0
         if _monitor._ENABLED:
             _monitor.gauge_set("llm.warm_start_ms", self._warm_ms)
-            _monitor.count("llm.warmup_runs", len(self.buckets) + 2)
+            _monitor.count("llm.warmup_runs", len(self.buckets) + 3)
 
     def stop(self, drain: bool = True, timeout: float = 30.0) -> None:
         if drain and self._thread is not None:
@@ -444,7 +488,7 @@ class LLMEngine:
         # model weights and KV pool become collectable once the engine
         # is dropped (the cycle runs through C-level jit wrappers the
         # garbage collector cannot traverse).
-        for net in (self._prefill, self._decode):
+        for net in (self._prefill, self._decode, self._slot_write):
             fwd = getattr(net, "forward", None)
             if hasattr(fwd, "release"):
                 fwd.release()
@@ -587,20 +631,25 @@ class LLMEngine:
         # one span per call that admits: the stall every stream sees
         with _monitor.span("llm.admit"):
             while seq is not None:
-                self._prefill_into(seq)
+                try:
+                    self._prefill_into(seq)
+                except Exception as e:
+                    # as for a step that raises (`_turn`): the write may
+                    # have taken the pool, so every sequence is lost with
+                    # this one, the engine and its pool serve on
+                    with self._lock:
+                        self._active[seq.slot] = seq
+                    self._evict_all("error", f"{type(e).__name__}: {e}")
                 seq = self._next_admission()
 
     def _prefill_slot(self, prompt: np.ndarray, slot: int, rid: int = 0):
         """The device's part of an admission: the prompt, right-padded to
         its bucket, through that bucket's program, and the cache it
-        returns written into `slot` of the pool, one
-        `dynamic_update_slice` on axis 0 an array. Returns (first greedy
-        token, bucket, last-position logits [1, V], what the model reports
+        returns written into `slot` of the pool by ONE program that takes
+        the pool donated (`_write_slot`). Returns (first greedy token,
+        bucket, last-position logits [1, V], what the model reports
         beside its cache)."""
-        import jax
         import jax.numpy as jnp
-
-        from ..ops._dispatch import run_op
 
         plen = int(prompt.size)
         lb = next(b for b in self.buckets if b >= plen)
@@ -612,18 +661,13 @@ class LLMEngine:
                                  Tensor(jnp.full((1,), plen, jnp.int32)))
             first = int(np.asarray(outs[0].numpy())[0])
 
-        def _row(pool, row, s):
-            return jax.lax.dynamic_update_slice(
-                pool, row, (s,) + (0,) * (pool.ndim - 1))
-
         rows = outs[2:2 + len(self._pool)]
         with _monitor.span("llm.slot_write", request_id=rid,
                            writes=len(rows)):
-            slot_t = Tensor(jnp.asarray(slot, jnp.int32))
-            for i, row in enumerate(rows):
-                self._pool[i] = run_op(_row, [self._pool[i], row, slot_t],
-                                       "llm_slot_write")
-        return first, lb, outs[1], outs[2 + len(self._pool):]
+            donated = self._write_slot(slot, rows)
+        if donated and _monitor._ENABLED:
+            _monitor.count("llm.slot_write.donated")
+        return first, lb, outs[1], outs[2 + len(rows):]
 
     def _prefill_into(self, seq: _Seq) -> None:
         cfg = self.config

@@ -69,9 +69,23 @@ def _serial_generate(eng, prompt, max_new, slot=0, eos=None):
 
 
 def _engine_of(kind, **cfg):
-    """An engine over K/V pages ("fp32", "kv_int8") or over a small
-    Brumby model's recurrent states ("brumby"), not started."""
-    if kind == "brumby":
+    """An engine over K/V pages ("fp32", "kv_int8"), over a small Brumby
+    model's recurrent states ("brumby") or over a small Ling model's mixed
+    pool ("ling": states, convolution rows and a latent page), not
+    started."""
+    if kind == "ling":
+        from paddle_tpu.models.ling import LingForCausalLM, LingModel
+        paddle.seed(5)
+        lm = LingForCausalLM(LingModel(
+            layers=[0, 4, 5], vocab_size=64, hidden_size=32,
+            num_attention_heads=2, head_dim=16, intermediate_size=48,
+            moe_intermediate_size=24, num_experts=16, num_experts_per_tok=4,
+            n_group=4, topk_group=2, moe_shared_expert_intermediate_size=24,
+            held=(4, 8), first_k_dense_replace=1, layer_group_size=3,
+            kv_lora_rank=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, router_bias_std=0.1))
+        lm.eval()
+    elif kind == "brumby":
         from paddle_tpu.models.brumby import BrumbyForCausalLM, BrumbyModel
         paddle.seed(3)
         lm = BrumbyForCausalLM(BrumbyModel(
@@ -823,7 +837,10 @@ class TestDonatedPool:
             snap["llm.decode.steps"]
 
     def test_introspection_from_another_thread_never_sees_a_dead_page(
-            self, kv_int8):
+            self, kv_int8, monitored):
+        """Over decode steps AND admissions, which give the pool away
+        too: three streams of different lengths, then three more that are
+        admitted as slots come free under the streams still live."""
         eng = self._engine(kv_int8, max_new_tokens=24).start()
         want = eng.kv_pool_bytes()
         errors, stop = [], threading.Event()
@@ -840,7 +857,9 @@ class TestDonatedPool:
         t = threading.Thread(target=hammer, daemon=True)
         t.start()
         try:
-            streams = [eng.submit(p) for p in ([5, 6], [1], [9, 9, 9])]
+            streams = [eng.submit(p, max_new_tokens=m) for p, m in
+                       (([5, 6], 6), ([1], 12), ([9, 9, 9], 24))]
+            streams += [eng.submit(p) for p in ([2, 4], [8] * 9, [3])]
             assert all(s.result(timeout=120.0)[0] == "done"
                        for s in streams)
         finally:
@@ -848,6 +867,9 @@ class TestDonatedPool:
             t.join(timeout=10.0)
             eng.stop()
         assert not errors
+        snap = monitor.snapshot()["counters"]
+        assert snap["llm.slot_write.donated"] == 6
+        assert snap["llm.decode.pool_donated"] == snap["llm.decode.steps"]
 
     def test_failure_at_the_read_leaves_a_serving_engine(self, kv_int8):
         """The error surfaces where the host reads a step's tokens, one
@@ -914,4 +936,125 @@ class TestDonatedPool:
                 assert toks == ref
         finally:
             eng._decode = real
+            eng.stop()
+
+
+@pytest.mark.parametrize("kind", ["fp32", "kv_int8", "brumby", "ling"])
+class TestSlotWriteProgram:
+    """An admission writes its slot through ONE program,
+    `jit_llm_slot_write`, that takes the whole pool donated: whatever the
+    model keeps a sequence (pages, int8 pages and their scales, states, a
+    mixed pool), whichever slot and whichever bucket."""
+
+    CFG = dict(num_slots=3, max_len=32, max_new_tokens=6,
+               prefill_buckets=(8, 16, 32))
+
+    @staticmethod
+    def _read(pool):
+        return [np.asarray(t.numpy()) for t in pool]
+
+    @pytest.mark.parametrize("slot", [0, 2])
+    def test_an_admission_writes_its_slot_and_no_other(self, kind, slot,
+                                                       monitored):
+        import jax.numpy as jnp
+
+        from paddle_tpu.core.tensor import Tensor
+        eng = _engine_of(kind, warmup_on_start=False, **self.CFG)
+        n = len(eng._pool)
+        # a pool in which every slot holds something of its own (copied
+        # to the device: a host view of a CPU buffer would pin it)
+        rng = np.random.default_rng(11)
+        before = [rng.integers(-100, 100, size=t.shape).astype(
+            t._value.dtype) for t in eng._pool]
+        eng._pool = [Tensor(jnp.array(a)) for a in before]
+        old = [t._value for t in eng._pool]
+        prompt = np.asarray([5, 9, 2, 7, 1, 1, 8, 3, 4, 6, 2], np.int32)
+        with paddle.no_grad():
+            padded = np.zeros((1, 16), np.int32)
+            padded[0, :prompt.size] = prompt
+            fresh = self._read(eng._prefill(
+                Tensor(jnp.asarray(padded)),
+                Tensor(jnp.full((1,), prompt.size, jnp.int32)))[2:2 + n])
+            _, bucket, *_ = eng._prefill_slot(prompt, slot)
+        assert bucket == 16 and len(eng._pool) == n
+        others = [s for s in range(3) if s != slot]
+        for was, row, now in zip(before, fresh, self._read(eng._pool)):
+            assert row.shape == (1,) + was.shape[1:] and row.dtype == was.dtype
+            assert np.array_equal(now[slot], row[0])
+            assert np.array_equal(now[others], was[others])
+        # the old pool was given away, not copied
+        assert all(a.is_deleted() for a in old)
+        snap = monitor.snapshot()["counters"]
+        assert snap["llm.slot_write.donated"] == 1
+        assert snap["span.llm.slot_write.count"] == 1
+
+    def test_one_executable_serves_every_slot_and_bucket(self, kind,
+                                                         monitored):
+        """Warmed at start, the first admission compiles nothing; slots
+        and buckets are data; no eager op is left in an admission."""
+        eng = _engine_of(kind, **self.CFG).start()
+        ledger = eng._slot_write.forward._ledger
+        assert len(ledger.seen_sigs()) == 1          # `_warmup` ran it
+        watched = lambda: {
+            k: v for k, v in monitor.snapshot()["counters"].items()
+            if "compile" in k or "trace" in k or k.startswith("dispatch.op")}
+        c0 = watched()
+        rng = np.random.default_rng(2)
+        prompts = [rng.integers(1, 64, size=m).tolist()
+                   for m in (3, 12, 20, 9, 5)]      # buckets 8, 16, 32
+        try:
+            done = [s.result(timeout=120.0)
+                    for s in [eng.submit(p) for p in prompts]]
+            assert watched() == c0
+        finally:
+            eng.stop()
+        assert [status for status, _ in done] == ["done"] * 5
+        assert len(ledger.seen_sigs()) == 0          # released with the rest
+        snap = monitor.snapshot()["counters"]
+        assert snap["llm.prefill.requests"] == 5
+        assert snap["llm.slot_write.donated"] == 5
+        assert snap["span.llm.slot_write.count"] == 5
+        assert "dispatch.op.llm_slot_write" not in snap
+        assert snap["llm.warmup_runs"] == len(eng.buckets) + 3
+        # what the streams hold is what the serial loop makes of each prompt
+        own = _engine_of(kind, warmup_on_start=False, **self.CFG)
+        assert [toks for _, toks in done] == [
+            _serial_generate(own, p, 6, slot=i % 3)
+            for i, p in enumerate(prompts)]
+        assert len(own._slot_write.forward._ledger.seen_sigs()) == 1
+
+    def test_a_write_that_raises_leaves_a_serving_engine_and_a_zero_pool(
+            self, kind):
+        """The write consumed the pool and the dispatch then raised: the
+        sequence being admitted and every live one are lost, the pool is
+        zeros again, the next request is served."""
+        eng = _engine_of(kind, warmup_on_start=False,
+                         **{**self.CFG, "max_new_tokens": 24})
+        ref = _serial_generate(eng, [4, 2], 24)
+        real = eng._slot_write
+        armed = {"n": 0}
+
+        def failing(*a, **kw):
+            out = real(*a, **kw)
+            if armed["n"]:
+                armed["n"] -= 1
+                raise RuntimeError("device fell over after the write")
+            return out
+
+        eng._slot_write = failing
+        eng.start()
+        try:
+            live = eng.submit([7, 7, 1])
+            assert next(live.iter(timeout=60.0)) is not None  # it is live
+            armed["n"] = 1
+            status, toks = eng.submit([4, 2]).result(timeout=60.0)
+            assert (status, toks) == ("error", [])
+            assert live.result(timeout=60.0)[0] == "error"
+            assert eng.stats()["free"] == 3 and eng.stats()["active"] == 0
+            assert TestDonatedPool._live(eng)
+            assert all(np.array_equal(a, z) for a, z in zip(
+                self._read(eng._pool), self._read(eng._zero_pool())))
+            assert eng.submit([4, 2]).result(timeout=60.0) == ("done", ref)
+        finally:
+            eng._slot_write = real
             eng.stop()

@@ -267,21 +267,26 @@ def _lowered_name(text):
         ",")[0].split(" ")[0]
 
 
-@pytest.mark.parametrize("which", ["llm_decode", "llm_prefill"])
+@pytest.mark.parametrize("which", ["llm_decode", "llm_prefill",
+                                   "llm_slot_write"])
 def test_engine_programs_lower_under_their_names(which):
     from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.jit.functional import split_state
     import jax.numpy as jnp
     eng = LLMEngine(_build_lm(), LLMConfig(num_slots=2, max_len=16,
                                            max_new_tokens=4))
-    net = {"llm_decode": eng._decode, "llm_prefill": eng._prefill}[which]
+    net = {"llm_decode": eng._decode, "llm_prefill": eng._prefill,
+           "llm_slot_write": eng._slot_write}[which]
     if which == "llm_decode":
         inputs = [Tensor(jnp.zeros((2,), jnp.int32)),
                   Tensor(jnp.zeros((2,), jnp.int32)), *eng._pool]
+    elif which == "llm_slot_write":
+        inputs = [Tensor(jnp.asarray(1, jnp.int32)), *eng._pool,
+                  *eng.lm.init_cache(1, 16)]
     else:
         inputs = [Tensor(jnp.zeros((1, 8), jnp.int32)),
                   Tensor(jnp.ones((1,), jnp.int32))]
-    # shapes, taken before the call: the decode program consumes the pool
+    # shapes, taken before the call: both writers consume the pool
     specs = [jax.ShapeDtypeStruct(t.shape, t._value.dtype) for t in inputs]
     with paddle.no_grad():
         net(*inputs)

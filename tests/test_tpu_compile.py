@@ -309,6 +309,53 @@ def test_brumby_decode_program_rewrites_the_state_pool_in_place(sds,
     assert text.count('custom_call_target="tpu_custom_call"') == layers
 
 
+@pytest.mark.parametrize("cell,arrays", [
+    ("gpt2_large", [(12, 1026, 1280)] * 72),
+    ("brumby_14b", [(12, 8, 128, 8320), (12, 8, 8320)] * 8)])
+def test_llm_slot_write_program_writes_the_pool_in_place(sds, monkeypatch,
+                                                         cell, arrays):
+    """The engine's slot-write program at the two cells' pools (GPT-2
+    large: 72 float32 pages; Brumby, 8 layers: a state matrix and its
+    normaliser a layer), from shapes: every pool input is donated and
+    aliased to its output, and nothing the size of a page or a state is
+    left beside the pool (the eager writes copied each array whole). The
+    program is generic over what `init_cache` returns, so a model that
+    keeps 72 or 16 tiny arrays builds it."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu.serving import LLMConfig, LLMEngine
+
+    class Keeps(paddle.nn.Layer):
+        cache_tag = "kv_pool"
+
+        def init_cache(self, batch_size, max_len, dtype="float32"):
+            return [paddle.zeros([batch_size, 1]) for _ in arrays]
+
+        forward_cached = None
+
+    eng = LLMEngine(Keeps(), LLMConfig(num_slots=12, max_len=16,
+                                       warmup_on_start=False))
+    inputs = [sds((), jnp.int32)] \
+        + [sds(a, jnp.float32) for a in arrays] \
+        + [sds((1,) + a[1:], jnp.float32) for a in arrays]
+    donated = eng._slot_write.forward._donated(len(inputs))
+    assert donated == tuple(range(1, 1 + len(arrays)))
+    compiled = _compile_net(eng._slot_write, inputs, donated, sds,
+                            monkeypatch)
+    sizes = [4 * functools.reduce(int.__mul__, a) for a in arrays]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(sizes)   # 1026 rows occupy 1032
+    assert mem.temp_size_in_bytes < min(sizes) // 16
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_llm_slot_write")
+    entry = text.split("\n", 1)[0]
+    assert entry.count("may-alias") == len(arrays)
+    # in place: a pool array is never the result of a plain copy
+    for shape in {"f32[" + ",".join(map(str, a)) + "]" for a in arrays}:
+        assert not re.findall(r"= " + re.escape(shape) + r"\S* copy\(", text)
+
+
 def _ling_engine(slots):
     """A Ling model at the published widths cut to three layers (the dense
     KDA layer 0, the KDA expert layer 10, the MLA expert layer 11), 16 of
