@@ -1,6 +1,7 @@
 """chip_smoke.py — the quickest proof that the main path still runs on the chip.
 
-    python chip_smoke.py             one TPU chip: train, serve, kernel
+    python chip_smoke.py             one TPU chip: train, serve, kernel,
+                                     latent (--phases names a part of them)
     python chip_smoke.py --chips 4   four chips: ONLY the sharded paths
                                      (dp2 x mp2 train step, sp=4 ring
                                      attention) and what they are compared with
@@ -27,6 +28,7 @@ from __future__ import annotations
 import argparse
 import contextlib
 import dataclasses
+import functools
 import gc
 import json
 import sys
@@ -309,6 +311,17 @@ DECODE_FULL = dict(slots=12, page=1026, heads=20, head_dim=64, rows=2,
                               1023, 0))
 
 
+# the decode step's read in `dots_vlm1.serve_long_context`: 16 slots of a
+# [13312, 640] bfloat16 latent page, 128 heads, lengths from a free slot
+# over block edges to the page's last row; and its prompt form at the
+# smallest bucket (the row-block form it is compared with holds 0.8 GB of
+# scores a block there; at 12288 it does not compile in reasonable time)
+LATENT_FULL = dict(slots=16, page=13312, heads=128, latent=512, rope=64,
+                   nope=128, v_dim=128, prompt=3072,
+                   positions=(0, 1, 511, 512, 513, 1535, 3071, 4607, 6143,
+                              8191, 8192, 9215, 12287, 13311, 2000, 0))
+
+
 def _fa():
     """The kernel MODULE (the package re-exports a function of its name)."""
     import importlib
@@ -543,6 +556,103 @@ def phase_kernel(geometries=KERNEL_FULL, scan=SCAN_FULL, decode=DECODE_FULL,
                    {"tolerance": tol, "dtype": "bfloat16", "paths": rows})
 
 
+def _without_the_latent_kernels(fn, *args):
+    """`fn` compiled with latent attention held to its `jax.numpy` forms
+    (the dense read, the row-block prompt): what the kernels are compared
+    with."""
+    import importlib
+
+    import jax
+    md = importlib.import_module("paddle_tpu.kernels.mla_decode")
+    engages = md.engages
+    md.engages = lambda *_: False
+    try:
+        return jax.block_until_ready(jax.jit(lambda *a: fn(*a))(*args))
+    finally:
+        md.engages = engages
+
+
+def phase_latent(slots, page, heads, latent, rope, nope, v_dim, prompt,
+                 positions, seed=0, min_kernels=1, tol=BF16_TOL,
+                 blocks=(128, 256, 512, 1024)) -> dict:
+    """Latent attention as the framework routes it on a TPU, forward only,
+    bfloat16: a decode step's read of the pool (`mla_decode`) against the
+    dense read of the whole page, and a prompt (`mla_prefill`, the flash
+    kernel at score width nope + rope and value width v) against the form
+    that holds a block of scores. Also times the read at several block
+    sizes (smoke timings: what `BLOCK_ROWS` was chosen from)."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.nn import functional as F
+
+    md = importlib.import_module("paddle_tpu.kernels.mla_decode")
+    rng = np.random.default_rng(seed)
+    width = -(-(latent + rope) // 128) * 128
+    bf = lambda *shape: jnp.asarray(rng.uniform(-1, 1, shape), jnp.bfloat16)
+    pool = bf(slots, page, width).at[..., latent + rope:].set(0)
+    w = bf(latent, heads * (nope + v_dim)) * (latent ** -0.5)
+    scale = (nope + rope) ** -0.5
+    pos = jnp.asarray(positions, jnp.int32)
+
+    def step(qn, qr, pool, pos, w):
+        with paddle.no_grad():
+            return F.latent_attention_decode(qn, qr, pool, pos, w,
+                                             scale=scale)._value
+
+    def prefill(qn, qr, c, kr, w):
+        with paddle.no_grad():
+            return F.latent_attention_prompt(qn, qr, c, kr, w,
+                                             scale=scale)._value
+
+    rows, setup_s, steady_s = [], 0.0, 0.0
+    for name, fn, args in (
+            ("decode", step, (bf(slots, heads, nope), bf(slots, heads, rope),
+                              pool, pos, w)),
+            ("prompt", prefill, (bf(1, prompt, heads, nope),
+                                 bf(1, prompt, heads, rope),
+                                 bf(1, prompt, latent), bf(1, prompt, rope),
+                                 w))):
+        text, n_kernels, got, su, st = _run_twice(jax.jit(fn), *args)
+        _require(n_kernels >= min_kernels,
+                 f"latent {name}: compiled with {n_kernels} tpu_custom_call, "
+                 f"needs {min_kernels}: it went to the jax.numpy form")
+        t0 = time.perf_counter()
+        want = _without_the_latent_kernels(fn, *args)
+        dense_s = time.perf_counter() - t0
+        errs = _check_against(f"latent {name}", (got,), (want,), ("out",),
+                              tol)
+        rows.append({name: [int(d) for d in args[2].shape], "heads": heads,
+                     "tpu_custom_calls": n_kernels, "rel_err": errs,
+                     "smoke_seconds": {"kernel": round(st, 5),
+                                       "jnp_form_with_compile":
+                                           round(dense_s, 3)}})
+        setup_s, steady_s = setup_s + su, steady_s + st
+    # the read alone at several block sizes, and the dense read, 20 calls
+    q = bf(slots, heads, width)
+    timed = {}
+    from paddle_tpu.nn.functional.attention import _latent_read_dense
+    forms = {f"block_{b}": jax.jit(functools.partial(
+        md.mla_decode, scale=scale, block_rows=b)) for b in blocks}
+    forms["dense"] = jax.jit(functools.partial(_latent_read_dense,
+                                               scale=scale))
+    for name, fn in forms.items():
+        jax.block_until_ready(fn(q, pool, pos))
+        t0 = time.perf_counter()
+        for _ in range(20):
+            out = fn(q, pool, pos)
+        jax.block_until_ready(out)
+        timed[name] = round((time.perf_counter() - t0) / 20 * 1e3, 4)
+    live = int(np.sum(np.asarray(positions) + 1))
+    return _report("latent", setup_s, steady_s,
+                   {"tolerance": tol, "dtype": "bfloat16", "paths": rows,
+                    "read_ms_smoke": timed, "live_rows": live,
+                    "pool_rows": slots * page})
+
+
 # ---- four chips: the sharded paths, and what they are compared with ---------
 
 def _count_op(hlo_text: str, op: str) -> int:
@@ -717,9 +827,13 @@ def phase_ring(devices: Sequence, geometry=(1, 8192, 12, 64), seed=0,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="1: train, serve, kernel on one chip (default); "
-                         "4: only the sharded paths and their comparison")
+                    help="1: train, serve, kernel, latent on one chip "
+                         "(default); 4: only the sharded paths and their "
+                         "comparison")
+    ap.add_argument("--phases", default="train,serve,kernel,latent",
+                    help="the one-chip phases to run, comma-separated")
     args = ap.parse_args(argv)
+    phases = args.phases.split(",")
 
     import jax
     devices = jax.devices()
@@ -741,11 +855,17 @@ def main(argv=None) -> int:
         phase_ring(devices)
     else:
         lowerings = Lowerings()
-        phase_train(TRAIN_FULL, "tpu", lowerings)
-        gc.collect()
-        phase_serve(SERVE_FULL, "tpu", lowerings)
-        gc.collect()
-        phase_kernel()
+        if "train" in phases:
+            phase_train(TRAIN_FULL, "tpu", lowerings)
+            gc.collect()
+        if "serve" in phases:
+            phase_serve(SERVE_FULL, "tpu", lowerings)
+            gc.collect()
+        if "kernel" in phases:
+            phase_kernel()
+            gc.collect()
+        if "latent" in phases:
+            phase_latent(**LATENT_FULL)
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,
         "count": len(devices)}}), flush=True)

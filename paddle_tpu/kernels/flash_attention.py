@@ -70,10 +70,11 @@ def _padded_bytes(shape, dtype):
 
 
 def _pallas(kernel, args, *, grid, in_specs, out_specs, out_shape, temps,
-            interpret, scratch_shapes=()):
+            interpret, scratch_shapes=(), name=None):
     """`pl.pallas_call` with the VMEM limit derived from its own block
     specs. `temps` is the bytes of tile intermediates live in the kernel
-    body (scores, probabilities, accumulators)."""
+    body (scores, probabilities, accumulators). `name` is the call's name
+    in the program's text and so in a device trace."""
     outs = out_shape if isinstance(out_shape, tuple) else (out_shape,)
     ospecs = out_specs if isinstance(out_specs, tuple) else (out_specs,)
     resident = temps + sum(_padded_bytes(sc.shape, sc.dtype)
@@ -85,7 +86,7 @@ def _pallas(kernel, args, *, grid, in_specs, out_specs, out_shape, temps,
         vmem_limit_bytes=max(_VMEM_DEFAULT, min(2 * resident, _VMEM_MAX)))
     return pl.pallas_call(kernel, out_shape=out_shape, grid=grid,
                           in_specs=in_specs, out_specs=out_specs,
-                          scratch_shapes=scratch_shapes,
+                          scratch_shapes=scratch_shapes, name=name,
                           compiler_params=params, interpret=interpret)(*args)
 
 
@@ -166,7 +167,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k,
             out.append(c)
         return tuple(out)
 
-    d = q_ref.shape[2]
+    d = v_ref.shape[2]              # the values' width is the output's
     carry = tuple((jnp.full((block_q, 1), -1e30, jnp.float32),
                    jnp.zeros((block_q, 1), jnp.float32),
                    jnp.zeros((block_q, d), jnp.float32)) for _ in range(G))
@@ -228,12 +229,16 @@ def _pick_heads(bh, s, d, itemsize, tile_bytes, n_streams=4):
     return 1
 
 
-def _flash_fwd_bhsd(q, k, v, *, causal, block_q, block_k, interpret):
-    """q/k/v: [BH, S, D] -> (out [BH, S, D], lse [BH, S] f32)."""
+def _flash_fwd_bhsd(q, k, v, *, causal, block_q, block_k, interpret,
+                    scale=None, name=None):
+    """q, k: [BH, S, D]; v: [BH, S, Dv] (Dv = D everywhere but latent
+    attention, whose scores run over 192 and whose values over 128) ->
+    (out [BH, S, Dv], lse [BH, S] f32). `scale` defaults to D^-0.5."""
     bh, s, d = q.shape
+    dv = v.shape[2]
     block_q = min(block_q, s)
     block_k = min(block_k, s)
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(d) if scale is None else float(scale)
     tile = _FWD_TILE_BYTES * block_q * block_k
     G = _pick_heads(bh, s, d, q.dtype.itemsize, tile)
     # measured d64/s8192: U=2 beats U=1 (~+6%) and U=4 (VMEM pressure)
@@ -244,20 +249,38 @@ def _flash_fwd_bhsd(q, k, v, *, causal, block_q, block_k, interpret):
     grid = (bh // G, s // block_q)
     return _pallas(
         kernel, (q, k, v),
-        out_shape=(jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, s), jnp.float32)),
         grid=grid,
         in_specs=[
             pl.BlockSpec((G, block_q, d), lambda b, i: (b, i, 0)),
             pl.BlockSpec((G, s, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((G, s, d), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((G, s, dv), lambda b, i: (b, 0, 0)),
         ],
-        out_specs=(pl.BlockSpec((G, block_q, d), lambda b, i: (b, i, 0)),
+        out_specs=(pl.BlockSpec((G, block_q, dv), lambda b, i: (b, i, 0)),
                    pl.BlockSpec((G, 1, block_q), lambda b, i: (b, 0, i))),
         # per head: U unrolled score/probability tiles + the f32 acc carry
-        temps=G * (unroll * tile + _padded_bytes((block_q, d), jnp.float32)),
-        interpret=interpret,
+        temps=G * (unroll * tile + _padded_bytes((block_q, dv), jnp.float32)),
+        interpret=interpret, name=name,
     )
+
+
+def flash_prompt_bhsd(q, k, v, *, scale=None, name=None):
+    """Causal attention of a prompt, FORWARD ONLY, for widths the backward
+    kernels do not know: q, k [BH, S, D], v [BH, S, Dv] -> [BH, S, Dv].
+    Any S: the rows are padded at the END to whole blocks (a causal row
+    never sees a later key, and the padded rows are cut off again), so no
+    length ever falls back to a form that holds the scores. Compiled on a
+    TPU, interpreted elsewhere."""
+    s = q.shape[1]
+    block = DEFAULT_BLOCK_Q if s >= DEFAULT_BLOCK_Q else -(-s // 128) * 128
+    pad = -s % block
+    if pad:
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (q, k, v))
+    out, _ = _flash_fwd_bhsd(q, k, v, causal=True, block_q=block,
+                             block_k=block, scale=scale, name=name,
+                             interpret=jax.default_backend() != "tpu")
+    return out[:, :s] if pad else out
 
 
 def _delta(g, o):

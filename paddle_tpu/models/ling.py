@@ -19,9 +19,10 @@ KDA (u the normed input; heads of `head_dim` for keys and values):
     S, o = the gated delta rule (kernels/kda.py)
     y = W_o concat_h(sigmoid((W_g u)^h) * RMSNorm_d(o^h))
 
-MLA: q = W_q u split a head into nope + rope; [c; kr] = W_dkv u, c normed,
-kr and the query's rope part rotated (interleaved pairs); keys and values
-a head are W_ukv c. What a sequence keeps is the row [c; kr] a position.
+MLA (`_decoder.LatentAttention`, shared with `models/dots.py`): q = W_q u
+split a head into nope + rope; [c; kr] = W_dkv u, c normed, kr and the
+query's rope part rotated (interleaved pairs); keys and values a head are
+W_ukv c. What a sequence keeps is the row [c; kr] a position.
 
 A model may hold a part of the depth (`layers`: the published indices it
 holds, whose kinds follow from the index), a part of the experts (`held`)
@@ -49,12 +50,13 @@ from .. import nn
 from ..core.tensor import Tensor
 from ..framework.param_attr import ParamAttr
 from ..kernels import kda as _kda
-from ..nn import functional as F
 from ..nn import initializer as I
 from ..ops._dispatch import run_op
-from ..ops.creation import arange
 from ..ops.manipulation import concat, reshape, unsqueeze
-from ._decoder import SwiGLU, _linear, _logits, _Normal, _parameters_in, _rows_at
+from ._decoder import (
+    LatentAttention, SwiGLU, _linear, _logits, _Normal, _parameters_in,
+    _rows_at,
+)
 
 
 def layer_kinds(layers, layer_group_size, first_k_dense_replace):
@@ -158,75 +160,6 @@ class LingKDA(nn.Layer):
         return self._out(o, gate, u.dtype), state, rows
 
 
-class LingMLA(nn.Layer):
-    def __init__(self, hidden_size, num_heads, kv_lora_rank, qk_nope_head_dim,
-                 qk_rope_head_dim, v_head_dim, rope_theta, rms_norm_eps):
-        super().__init__()
-        self.num_heads, self.latent = num_heads, kv_lora_rank
-        self.nope, self.rope, self.v_dim = (qk_nope_head_dim,
-                                            qk_rope_head_dim, v_head_dim)
-        self.rope_theta = rope_theta
-        # a page's row, [latent; rotary key], in whole 128 lanes: a minor
-        # axis of 576 is one the TPU holds in another order than it reads
-        # (two copies of the page a step, 1.9 of 12.1 ms: PERF.md, PR 33)
-        self.page_width = -(-(kv_lora_rank + qk_rope_head_dim) // 128) * 128
-        self.q_proj = _linear(hidden_size, num_heads * (self.nope + self.rope))
-        self.kv_down = _linear(hidden_size, kv_lora_rank + self.rope)
-        self.kv_norm = nn.RMSNorm(kv_lora_rank, rms_norm_eps)
-        self.kv_up = _linear(kv_lora_rank, num_heads * (self.nope + self.v_dim))
-        self.o_proj = _linear(num_heads * v_head_dim, hidden_size)
-
-    def _project(self, u, positions):
-        """u [B, T, hidden], positions [B, T] -> q_nope [B, T, H, nope],
-        q_rope [B, T, H, rope] rotated, the page's rows [B, T, latent +
-        rope] ([normed latent; rotated key])."""
-        b, t = u.shape[0], u.shape[1]
-        q = reshape(self.q_proj(u), [b, t, self.num_heads,
-                                     self.nope + self.rope])
-        q_rope = F.rotary_embedding(q[..., self.nope:], positions,
-                                    self.rope_theta, interleaved=True)
-        down = self.kv_down(u)
-        k_rope = F.rotary_embedding(
-            unsqueeze(down[..., self.latent:], 2), positions, self.rope_theta,
-            interleaved=True)
-        rows = concat([self.kv_norm(down[..., :self.latent]),
-                       reshape(k_rope, [b, t, self.rope])], axis=-1)
-        return q[..., :self.nope], q_rope, rows
-
-    def _out(self, y):
-        return self.o_proj(reshape(y, y.shape[:2] + [-1]))
-
-    def forward_cached(self, u, page, positions, lengths, step):
-        """As `LingKDA.forward_cached`; a prompt returns its rows [B, T,
-        latent + rope] padded with zeros to the page it was given (`page`
-        names the length only), a step the page with its row written at
-        `positions` [B]. Returns (out, page)."""
-        t = u.shape[1]
-        start = positions if positions is not None else \
-            Tensor(jnp.zeros((u.shape[0],), jnp.int32))
-        pos = unsqueeze(start, 1) + unsqueeze(arange(t, dtype="int32"), 0)
-        q_nope, q_rope, rows = self._project(u, pos)
-        if not step:
-            y = F.latent_attention_prompt(
-                q_nope, q_rope, rows[..., :self.latent],
-                rows[..., self.latent:], self.kv_up.weight, lengths)
-            if page is not None:
-                # the slot's page whole: what follows the prompt is zeros
-                rows = run_op(
-                    lambda r, p: jnp.pad(r, ((0, 0), (0, p.shape[1]
-                                                      - r.shape[1]),
-                                             (0, p.shape[2] - r.shape[2]))),
-                    [rows, page], "latent_page_fill")
-            return self._out(y), rows
-        if t != 1:
-            raise ValueError("a decode step through a latent page is one "
-                             f"token wide, got {t}")
-        page = F.latent_page_write(page, rows[:, 0], positions)
-        y = F.latent_attention_decode(q_nope[:, 0], q_rope[:, 0], page,
-                                      positions, self.kv_up.weight)
-        return self._out(unsqueeze(y, 1)), page
-
-
 class LingLayer(nn.Layer):
     def __init__(self, mixer, ffn, cfg):
         super().__init__()
@@ -240,10 +173,10 @@ class LingLayer(nn.Layer):
                                  cfg["kda_lower_bound"],
                                  cfg["kda_decay_bias"], eps)
         else:
-            self.mixer = LingMLA(hidden, cfg["num_attention_heads"],
-                                 cfg["kv_lora_rank"], cfg["qk_nope_head_dim"],
-                                 cfg["qk_rope_head_dim"], cfg["v_head_dim"],
-                                 cfg["rope_theta"], eps)
+            self.mixer = LatentAttention(
+                hidden, cfg["num_attention_heads"], cfg["kv_lora_rank"],
+                cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
+                cfg["v_head_dim"], cfg["rope_theta"], eps)
         self.post_norm = nn.RMSNorm(hidden, eps)
         if ffn == "dense":
             self.mlp = SwiGLU(hidden, cfg["intermediate_size"])

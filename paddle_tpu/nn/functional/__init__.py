@@ -22,6 +22,7 @@ from .loss import (  # noqa: F401
 )
 from .attention import (  # noqa: F401
     latent_attention_decode, latent_attention_prompt, latent_page_write,
-    rotary_embedding, scaled_dot_product_attention,
+    rotary_embedding, scaled_dot_product_attention, yarn_inv_freq,
+    yarn_mscale,
 )
 from .vision import grid_sample, affine_grid, temporal_shift  # noqa: F401

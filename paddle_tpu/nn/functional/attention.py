@@ -80,8 +80,38 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.
     return run_op(f, [q, k, v], "scaled_dot_product_attention")
 
 
+def yarn_inv_freq(dim, theta, factor, original, beta_fast=32, beta_slow=1):
+    """YaRN's blended rotary frequencies (arXiv:2309.00071, as DeepSeek-V3
+    applies them), [dim // 2] float32. A frequency that makes more than
+    `beta_fast` turns over the `original` positions is kept, one that makes
+    fewer than `beta_slow` is divided by `factor`, and between the two
+    dimension indices a linear ramp blends them:
+
+        inv_i = theta^(-2i/dim);  d(b) = dim ln(original / (2 pi b)) / (2 ln theta)
+        low, high = floor d(beta_fast), ceil d(beta_slow), in [0, dim - 1]
+        ramp_i = clip((i - low) / (high - low), 0, 1)
+        inv'_i = inv_i / factor * ramp_i + inv_i * (1 - ramp_i)"""
+    import numpy as np
+
+    def turns(b):
+        return dim * math.log(original / (2 * math.pi * b)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), dim - 1)
+    i = np.arange(dim // 2, dtype=np.float64)
+    inv = float(theta) ** (-2.0 * i / dim)
+    ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (inv / factor * ramp + inv * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor, mscale=1.0):
+    """m(s) = 0.1 s ln(factor) + 1 (1 without scaling): YaRN's correction
+    of the attention's temperature."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 def rotary_embedding(x, positions, theta=10000.0, name=None,
-                     interleaved=False):
+                     interleaved=False, inv_freq=None):
     """Rotary position embedding, half-split (the Qwen / GPT-NeoX
     convention): with x = [x1, x2] the two halves of the last axis and
     angle[i] = position * theta^(-2i/d), the result is
@@ -91,6 +121,8 @@ def rotary_embedding(x, positions, theta=10000.0, name=None,
     x: [batch, seq, heads, head_dim]; positions: [batch, seq] integers, a
     position per row and token (a cached decode step passes each row's own
     offset). The rotation is computed in float32 and returned in x's dtype.
+    `inv_freq` [head_dim // 2] gives the frequencies themselves in place of
+    theta's powers (`yarn_inv_freq`).
     """
     x, positions = ensure_tensor(x), ensure_tensor(positions)
     half = x.shape[-1] // 2
@@ -99,7 +131,8 @@ def rotary_embedding(x, positions, theta=10000.0, name=None,
 
     def f(a, pos):
         inv = jnp.asarray(theta, jnp.float32) ** (
-            -jnp.arange(half, dtype=jnp.float32) / half)
+            -jnp.arange(half, dtype=jnp.float32) / half) \
+            if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
         angle = pos.astype(jnp.float32)[..., None, None] * inv   # [B,T,1,d/2]
         cos, sin = jnp.cos(angle), jnp.sin(angle)
         a32 = a.astype(jnp.float32)
@@ -124,8 +157,39 @@ def _split_up(w_ukv, heads, nope, v_dim):
     return w[..., :nope], w[..., nope:]
 
 
+def _latent_kernels(dtype) -> bool:
+    """Whether latent attention runs its two Pallas forms (`mla_prefill`,
+    `mla_decode`): on a TPU over floating-point rows at the default matmul
+    precision; elsewhere the `jax.numpy` forms below, which are also the
+    kernels' references."""
+    from ...kernels import mla_decode
+    return mla_decode.engages(dtype)
+
+
+def latent_prompt_flash(qn, qr, c, kr, w, scale):
+    """The prompt form through `kernels/flash_attention.py` (arrays; shapes
+    as `latent_attention_prompt`'s): every head's keys [nope + rope] and
+    values [v] are built from the latent rows, then ONE causal flash kernel
+    over score width nope + rope and value width v, named `mla_prefill` in
+    the program. No [heads, rows, keys] score array exists at any length.
+    A padded prompt needs no length mask: a real row never sees a later
+    key, and the rows past a length are nobody's."""
+    from ...kernels.flash_attention import flash_prompt_bhsd
+    b, t, h, nope = qn.shape
+    w_uk, w_uv = _split_up(w, h, nope, w.shape[1] // h - nope)
+    kn = jnp.einsum("btl,lhd->bhtd", c, w_uk)
+    v = jnp.einsum("btl,lhd->bhtd", c, w_uv)
+    k = jnp.concatenate(
+        [kn, jnp.broadcast_to(kr[:, None], (b, h, t, kr.shape[-1]))], -1)
+    q = jnp.swapaxes(jnp.concatenate([qn, qr], -1), 1, 2)
+    fold = lambda a: a.astype(c.dtype).reshape(b * h, t, a.shape[-1])
+    y = flash_prompt_bhsd(fold(q), fold(k), fold(v), scale=scale,
+                          name="mla_prefill")
+    return jnp.swapaxes(y.reshape(b, h, t, -1), 1, 2)
+
+
 def latent_attention_prompt(q_nope, q_rope, latent, k_rope, w_ukv, lengths=None,
-                            name=None):
+                            scale=None, name=None):
     """Multi-head latent attention (DeepSeek-V2) over a prompt, EXPANDED:
     every head's keys and values are built from the latent rows, then
     causal softmax attention over nope + rope score dimensions.
@@ -133,20 +197,28 @@ def latent_attention_prompt(q_nope, q_rope, latent, k_rope, w_ukv, lengths=None,
     q_nope [B, T, H, nope], q_rope [B, T, H, rope] (rotated), latent [B, T,
     L] (normed), k_rope [B, T, rope] (rotated, shared by the heads), w_ukv
     [L, H * (nope + v)], lengths [B] or None (keys >= lengths masked).
-    Returns [B, T, H, v] in latent's dtype. Scores in blocks of rows,
-    float32 statistics."""
+    Returns [B, T, H, v] in latent's dtype. `scale` multiplies the scores
+    ((nope + rope)^-0.5 where None). On a TPU the flash kernel
+    (`latent_prompt_flash`); elsewhere scores in blocks of rows, float32
+    statistics: the kernel's reference."""
     tensors = [ensure_tensor(a) for a in (q_nope, q_rope, latent, k_rope,
                                           w_ukv)]
     if lengths is not None:
         tensors.append(ensure_tensor(lengths))
 
+    if scale is None:
+        scale = 1.0 / math.sqrt(tensors[0].shape[-1] + tensors[1].shape[-1])
+    # decided out here: what `f` closes over keys the eager dispatch cache
+    flash = _latent_kernels(tensors[2]._value.dtype)
+
     def f(qn, qr, c, kr, w, *rest):
+        if flash:
+            return latent_prompt_flash(qn, qr, c, kr, w, scale)
         b, t, h, nope = qn.shape
         v_dim = w.shape[1] // h - nope
         w_uk, w_uv = _split_up(w, h, nope, v_dim)
         kn = jnp.einsum("btl,lhd->bthd", c, w_uk)
         v = jnp.einsum("btl,lhd->bthd", c, w_uv)
-        scale = 1.0 / math.sqrt(nope + qr.shape[-1])
         cols = jnp.arange(t)
         real = cols[None, :] < rest[0][:, None] if rest else None
         out = []
@@ -171,7 +243,8 @@ def latent_attention_prompt(q_nope, q_rope, latent, k_rope, w_ukv, lengths=None,
     return run_op(f, tensors, "latent_attention_prompt")
 
 
-def latent_attention_decode(q_nope, q_rope, page, positions, w_ukv, name=None):
+def latent_attention_decode(q_nope, q_rope, page, positions, w_ukv, scale=None,
+                            name=None):
     """One decode step of latent attention with the up-projection ABSORBED
     into the query: a step reads the page's latent rows and never builds a
     head's keys or values.
@@ -183,10 +256,20 @@ def latent_attention_decode(q_nope, q_rope, page, positions, w_ukv, name=None):
     W], W >= L + rope (a row is [latent; rotary key; zeros]), with this
     step's row ALREADY written at `positions` [B]; rows beyond `positions`
     are masked. w_ukv [L, H * (nope + v)]. Returns [B, H, v] in the page's
-    dtype. The page is read whole and never sliced: a slice of its minor
-    axis is a copy of it."""
+    dtype. `scale` multiplies the scores ((nope + rope)^-0.5 where None).
+    On a TPU the page is read by `kernels/mla_decode.py`, each slot's live
+    rows once; elsewhere (and as that kernel's reference) it is read whole,
+    twice, by dense einsums, and never sliced: a slice of its minor axis is
+    a copy of it."""
     tensors = [ensure_tensor(a) for a in (q_nope, q_rope, page, positions,
                                           w_ukv)]
+    if scale is None:
+        scale = 1.0 / math.sqrt(tensors[0].shape[-1] + tensors[1].shape[-1])
+    # decided out here: what `f` closes over keys the eager dispatch cache
+    if _latent_kernels(tensors[2]._value.dtype):
+        from ...kernels.mla_decode import mla_decode as read
+    else:
+        read = _latent_read_dense
 
     def f(qn, qr, page, pos, w):
         b, h, nope = qn.shape
@@ -198,18 +281,24 @@ def latent_attention_decode(q_nope, q_rope, page, positions, w_ukv, name=None):
             [qc.astype(page.dtype), qr.astype(page.dtype),
              jnp.zeros((b, h, page.shape[-1] - lat - rope), page.dtype)],
             axis=-1)
-        s = jnp.einsum("bhl,bjl->bhj", q, page,
-                       preferred_element_type=jnp.float32)
-        s = s * (1.0 / math.sqrt(nope + rope))
-        keep = jnp.arange(page.shape[1])[None, :] <= pos[:, None]
-        p = jax.nn.softmax(jnp.where(keep[:, None], s, -1e30), axis=-1)
-        ctx = jnp.einsum("bhj,bjl->bhl", p.astype(page.dtype), page,
-                         preferred_element_type=jnp.float32)[..., :lat]
+        ctx = read(q, page, pos, scale)[..., :lat]
         y = jnp.einsum("bhl,lhd->bhd", ctx.astype(w.dtype), w_uv,
                        preferred_element_type=jnp.float32)
         return y.astype(page.dtype)
 
     return run_op(f, tensors, "latent_attention_decode")
+
+
+def _latent_read_dense(q, page, pos, scale):
+    """q [B, H, W], page [B, L, W], pos [B] -> sum_j softmax_j(scale q .
+    page_j) page_j over j <= pos[b], [B, H, W] float32: the whole page
+    read twice."""
+    s = jnp.einsum("bhl,bjl->bhj", q, page,
+                   preferred_element_type=jnp.float32) * scale
+    keep = jnp.arange(page.shape[1])[None, :] <= pos[:, None]
+    p = jax.nn.softmax(jnp.where(keep[:, None], s, -1e30), axis=-1)
+    return jnp.einsum("bhj,bjl->bhl", p.astype(page.dtype), page,
+                      preferred_element_type=jnp.float32)
 
 
 def latent_page_write(page, rows, positions, name=None):

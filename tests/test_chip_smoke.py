@@ -116,6 +116,39 @@ def test_kernel_phase_interprets_the_kernel_on_a_cpu(monkeypatch):
     assert decode["rel_err"]["out"] <= 1e-5
 
 
+def test_latent_phase_routes_both_forms_through_their_kernels(monkeypatch):
+    """Tiny, interpreted: the decode step's read goes through `mla_decode`
+    and the prompt through the flash kernel when latent attention engages,
+    and what they are compared with goes through neither."""
+    import importlib
+    md = importlib.import_module("paddle_tpu.kernels.mla_decode")
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    monkeypatch.setattr(md, "engages", lambda dtype: True)
+    routed = []
+    read, flash = md.mla_decode, fa.flash_prompt_bhsd
+    monkeypatch.setattr(md, "mla_decode", lambda *a, **k: routed.append(
+        ("read", a[0].shape)) or read(*a, **k))
+    monkeypatch.setattr(fa, "flash_prompt_bhsd", lambda *a, **k: routed.append(
+        ("prompt", a[0].shape, k["name"])) or flash(*a, **k))
+    line = chip_smoke.phase_latent(
+        slots=3, page=300, heads=4, latent=24, rope=8, nope=16, v_dim=16,
+        prompt=200, positions=(0, 299, 130), min_kernels=0, blocks=(128,))
+    decode, prompt = line["check"]["paths"]
+    assert routed[:2] == [("read", (3, 4, 128)),
+                          ("prompt", (4, 200, 24), "mla_prefill")]
+    assert decode["rel_err"]["out"] <= chip_smoke.BF16_TOL
+    assert prompt["rel_err"]["out"] <= chip_smoke.BF16_TOL
+    assert line["check"]["live_rows"] == 1 + 300 + 131
+    assert set(line["check"]["read_ms_smoke"]) == {"block_128", "dense"}
+
+
+def test_latent_phase_refuses_a_program_without_the_kernel():
+    with pytest.raises(chip_smoke.SmokeFailure, match="tpu_custom_call"):
+        chip_smoke.phase_latent(
+            slots=2, page=64, heads=2, latent=24, rope=8, nope=16, v_dim=16,
+            prompt=64, positions=(0, 63), blocks=())
+
+
 def test_kernel_phase_refuses_a_program_without_the_kernel():
     """What the chip run relies on: with the default `min_kernels` a
     program whose compiled text holds no tpu_custom_call cannot pass."""

@@ -83,9 +83,11 @@ def test_no_token_is_dropped_when_every_row_picks_the_same_experts():
     np.testing.assert_allclose(got, np.repeat(want, 300, 0), atol=2e-5)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
-    """Four ranks of four experts each: their outputs, the shared expert
-    counted once, sum to the reference's uncut layer."""
+@pytest.mark.parametrize("ranks", [4, 8, 16])
+def test_the_shares_add_up_to_the_uncut_layer(ranks):
+    """`ranks` ranks share the 16 experts (four each: a whole group, Ling's
+    deployment; two: half a group, Dots'; one): their outputs, the shared
+    expert counted once, sum to the reference's uncut layer."""
     whole, m = _experts(), _rows(64)
     named = _named(whole)
     want = np.asarray(reference.moe(jnp.asarray(m), named, first=0,
@@ -94,15 +96,16 @@ def test_the_shares_add_up_to_the_uncut_layer():
         F.silu(whole.shared_gate(paddle.to_tensor(m)))
         * whole.shared_up(paddle.to_tensor(m))).numpy())
     total = np.zeros_like(want)
-    for rank in range(4):
-        part = _experts(held=(4 * rank, 4))
+    each = 16 // ranks
+    for rank in range(ranks):
+        part = _experts(held=(each * rank, each))
         for name, p in part.named_parameters():
             src = named[name]
-            p.set_value(src[4 * rank:4 * rank + 4] if name in (
+            p.set_value(src[each * rank:each * (rank + 1)] if name in (
                 "gate_proj", "up_proj", "down_proj") else src)
         mine = part(paddle.to_tensor(m)).numpy()
         ref_part = reference.moe(jnp.asarray(m), _named(part),
-                                 first=4 * rank, **ROUTER)[0]
+                                 first=each * rank, **ROUTER)[0]
         np.testing.assert_allclose(mine, ref_part, atol=2e-5)
         total += mine - shared
     np.testing.assert_allclose(total + shared, want, atol=5e-5)

@@ -70,10 +70,24 @@ def _serial_generate(eng, prompt, max_new, slot=0, eos=None):
 
 def _engine_of(kind, **cfg):
     """An engine over K/V pages ("fp32", "kv_int8"), over a small Brumby
-    model's recurrent states ("brumby") or over a small Ling model's mixed
-    pool ("ling": states, convolution rows and a latent page), not
-    started."""
-    if kind == "ling":
+    model's recurrent states ("brumby"), over a small Ling model's mixed
+    pool ("ling": states, convolution rows and a latent page) or over a
+    small Dots model's latent pages, one a layer ("dots"), not started."""
+    if kind == "dots":
+        from paddle_tpu.models.dots import DotsForCausalLM, DotsModel
+        paddle.seed(7)
+        lm = DotsForCausalLM(DotsModel(
+            layers=[0, 3, 4], vocab_size=64, hidden_size=32,
+            num_attention_heads=2, intermediate_size=48,
+            moe_intermediate_size=24, n_routed_experts=16,
+            num_experts_per_tok=4, n_group=4, topk_group=2, held=(4, 8),
+            q_lora_rank=20, kv_lora_rank=24, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, router_bias_std=0.1,
+            rope_scaling=dict(type="yarn", factor=40, beta_fast=32,
+                              beta_slow=1, mscale=1, mscale_all_dim=1,
+                              original_max_position_embeddings=16)))
+        lm.eval()
+    elif kind == "ling":
         from paddle_tpu.models.ling import LingForCausalLM, LingModel
         paddle.seed(5)
         lm = LingForCausalLM(LingModel(
@@ -430,7 +444,7 @@ class TestRunAhead:
     device, before it reads step n: the same tokens as the serial loop,
     none after an EOS, none lost to an admission, no row past a budget."""
 
-    @pytest.mark.parametrize("kind", ["fp32", "kv_int8", "brumby"])
+    @pytest.mark.parametrize("kind", ["fp32", "kv_int8", "brumby", "dots"])
     def test_streams_are_the_serial_loops_token_for_token(self, kind):
         """More requests than slots, prompts and budgets of mixed lengths,
         submitted while the engine decodes: slots are reused, admissions
@@ -939,7 +953,8 @@ class TestDonatedPool:
             eng.stop()
 
 
-@pytest.mark.parametrize("kind", ["fp32", "kv_int8", "brumby", "ling"])
+@pytest.mark.parametrize("kind", ["fp32", "kv_int8", "brumby", "ling",
+                                  "dots"])
 class TestSlotWriteProgram:
     """An admission writes its slot through ONE program,
     `jit_llm_slot_write`, that takes the whole pool donated: whatever the

@@ -398,10 +398,11 @@ def test_ling_decode_program_updates_its_mixed_pool_in_place(sds,
     """The engine's decode program over a Ling model, 48 slots: the whole
     pool (two KDA states, their convolution rows, one latent page of 5120
     rows of 640: 576 in whole 128 lanes) is donated and aliased out; each KDA layer's update is the named
-    kernel and each expert layer's pass two calls of the grouped matmul
-    under its name."""
+    kernel, each expert layer's pass two calls of the grouped matmul
+    under its name, and the latent page is read by `mla_decode`."""
     kda = importlib.import_module("paddle_tpu.kernels.kda")
     gm = importlib.import_module("paddle_tpu.kernels.grouped_matmul")
+    md = importlib.import_module("paddle_tpu.kernels.mla_decode")
     slots = 48
     lm, eng = _ling_engine(slots)
     assert lm.cache_tag == ("state_pool",) * 4 + ("kv_pool",)
@@ -421,17 +422,18 @@ def test_ling_decode_program_updates_its_mixed_pool_in_place(sds,
     assert mem.temp_size_in_bytes < pool_bytes // 16
     text = compiled.as_text()
     assert text.startswith("HloModule jit_llm_decode")
-    assert text.count('custom_call_target="tpu_custom_call"') == 2 + 2 * 2
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 + 2 * 2 + 1
     assert text.count(f"%{kda.STEP_KERNEL}") >= 2
     assert text.count(f"%{gm.KERNEL}") >= 4
+    assert text.count(f"%{md.KERNEL}") >= 1
 
 
 def test_ling_prefill_program_compiles_at_the_largest_bucket(sds,
                                                              monkeypatch):
     """One prompt at the 3072 bucket: the chunked KDA form, expanded latent
-    attention in blocks of rows and the grouped matmul at its tile of 128
-    rows, in under 1.5 GB of temporaries; it returns a slot's whole
-    cache."""
+    attention through the flash kernel (`mla_prefill`) and the grouped
+    matmul at its tile of 128 rows, in under 1.5 GB of temporaries; it
+    returns a slot's whole cache."""
     gm = importlib.import_module("paddle_tpu.kernels.grouped_matmul")
     lm, eng = _ling_engine(4)
     eng._pool = []
@@ -443,4 +445,105 @@ def test_ling_prefill_program_compiles_at_the_largest_bucket(sds,
     text = compiled.as_text()
     assert text.startswith("HloModule jit_llm_prefill")
     assert text.count(f"%{gm.KERNEL}") >= 4
+    assert "%mla_prefill" in text
     assert "bf16[1,5120,640]" in text         # the page, whole
+
+
+def _dots_engine(slots):
+    """A Dots model at the published widths cut to ONE expert layer
+    (published layer 3), 2 of 256 experts held and 1024 rows of vocabulary
+    (neither is the subject: the kernels' geometry is the widths'), in an
+    engine at the cell's shapes, from shapes."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.dots import DotsForCausalLM, DotsModel
+    from paddle_tpu.serving import LLMConfig, LLMEngine
+
+    paddle.seed(0)
+    lm = DotsForCausalLM(DotsModel(
+        vocab_size=1024, layers=[3], held=(0, 2), dtype="bfloat16",
+        rope_scaling=dict(type="yarn", factor=40, beta_fast=32, beta_slow=1,
+                          mscale=1, mscale_all_dim=1,
+                          original_max_position_embeddings=4096)))
+    eng = LLMEngine(lm, LLMConfig(num_slots=slots, max_len=13312,
+                                  prefill_buckets=(3072, 6144, 12288),
+                                  warmup_on_start=False))
+    return lm, eng
+
+
+def test_mla_decode_kernel_compiles_at_the_cells_pool(sds):
+    """The read alone at `dots_vlm1.serve_long_context`'s shapes: 16 slots
+    of a [13312, 640] bfloat16 page, 128 heads, no temporaries."""
+    md = importlib.import_module("paddle_tpu.kernels.mla_decode")
+    fn = functools.partial(md._attend, scale=0.1, block_rows=md.BLOCK_ROWS,
+                           mxu=jnp.bfloat16, interpret=False)
+    compiled = jax.jit(fn).lower(
+        sds((16, 128, 640)), sds((16, 13312, 640)),
+        sds((16,), jnp.int32)).compile()
+    assert f"%{md.KERNEL}" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("bucket", [3072, 12288])
+def test_latent_prompt_form_compiles_without_the_scores(sds, monkeypatch,
+                                                        bucket):
+    """128 heads at score width 192 and value width 128 through the flash
+    kernel: what is held beside the inputs is queries, keys, values and
+    the output ([128, bucket, 192 / 128] each), never [heads, rows, keys]
+    (at 12288: 77 GB in float32)."""
+    attention = importlib.import_module(
+        "paddle_tpu.nn.functional.attention")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn = lambda qn, qr, c, kr, w: attention.latent_prompt_flash(
+        qn, qr, c, kr, w, 0.1)
+    compiled = jax.jit(fn).lower(
+        sds((1, bucket, 128, 128)), sds((1, bucket, 128, 64)),
+        sds((1, bucket, 512)), sds((1, bucket, 64)),
+        sds((512, 128 * 256))).compile()
+    assert "%mla_prefill" in compiled.as_text()
+    per_row = 128 * (192 + 192 + 128 + 128) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * bucket * per_row
+
+
+def test_dots_decode_program_reads_its_pages_through_the_kernel(sds,
+                                                                monkeypatch):
+    """The engine's decode program over a Dots layer, 16 slots of 13,312
+    positions: the page is donated and aliased out, read by `mla_decode`,
+    and the expert pass is two calls of the grouped matmul."""
+    gm = importlib.import_module("paddle_tpu.kernels.grouped_matmul")
+    md = importlib.import_module("paddle_tpu.kernels.mla_decode")
+    slots = 16
+    lm, eng = _dots_engine(slots)
+    assert lm.cache_tag == "kv_pool"
+    inputs = [sds((slots,), jnp.int32), sds((slots,), jnp.int32)] + [
+        sds(tuple(t.shape), t._value.dtype) for t in eng._pool]
+    pool_bytes = sum(t._value.nbytes for t in eng._pool)
+    assert pool_bytes == slots * 13312 * 640 * 2
+    eng._pool = []                      # shapes are all the compile needs
+    donated = eng._decode.forward._donated(len(inputs))
+    assert donated == (2,)
+    compiled = _compile_net(eng._decode, inputs, donated, sds, monkeypatch)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 4
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_llm_decode")
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 + 2
+    assert text.count(f"%{md.KERNEL}") >= 1
+    assert text.count(f"%{gm.KERNEL}") >= 2
+
+
+def test_dots_prefill_program_compiles_at_the_largest_bucket(sds,
+                                                             monkeypatch):
+    """One prompt at the 12288 bucket through a Dots expert layer: the
+    flash prompt form and the grouped matmul, in under 4 GB of
+    temporaries; it returns a slot's whole page."""
+    lm, eng = _dots_engine(2)
+    eng._pool = []
+    compiled = _compile_net(
+        eng._prefill, [sds((1, 12288), jnp.int32), sds((1,), jnp.int32)], (),
+        sds, monkeypatch)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_llm_prefill")
+    assert "%mla_prefill" in text
+    assert "bf16[1,13312,640]" in text        # the page, whole
