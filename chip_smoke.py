@@ -310,6 +310,11 @@ DECODE_FULL = dict(slots=12, page=1026, heads=20, head_dim=64, rows=2,
                    positions=(0, 1, 63, 127, 128, 200, 383, 511, 640, 1000,
                               1023, 0))
 
+# the expert pass of a decode step in `ling3_flash_vl.serve_long_answer`:
+# 48 rows x 8 routes over the 128 experts held of 512, hidden 2560, expert
+# width 768, bfloat16; every other row carries no sequence (`live`)
+ROUTED_FULL = dict(rows=48, top_k=8, held=128, total=512, hidden=2560,
+                   width=768)
 
 # the decode step's read in `dots_vlm1.serve_long_context`: 16 slots of a
 # [13312, 640] bfloat16 latent page, 128 heads, lengths from a free slot
@@ -531,15 +536,92 @@ def _decode_step(slots, page, heads, head_dim, rows, positions, seed,
             "tpu_custom_calls": n_kernels, "rel_err": errs}, setup_s, steady_s
 
 
+def _routed_step(rows, top_k, held, total, hidden, width, seed, min_kernels,
+                 tol):
+    """`nn.RoutedExperts`' expert pass (`experts_pass`: the grouped layout
+    and two calls of the grouped matmul, on a TPU the Pallas kernel) with
+    every other row routed nowhere, as the layer routes the rows a caller
+    says carry no sequence: against `lax.ragged_dot` over the same layout;
+    the live rows against the pass with every row live, to the bit (a
+    tile's rows do not mix); and the tiles that hold rows, which are the
+    experts read. Both serve cells' `correct` drives every slot live, so
+    this is where the chip checks the masked pass."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    gm = importlib.import_module("paddle_tpu.kernels.grouped_matmul")
+    routed = importlib.import_module("paddle_tpu.nn.layer.routed_experts")
+    rng = np.random.default_rng(seed)
+    bf = lambda *shape: jnp.asarray(rng.uniform(-1, 1, shape), jnp.bfloat16)
+    m = bf(rows, hidden)
+    gate, up = (bf(held, hidden, width) * hidden ** -0.5 for _ in range(2))
+    down = bf(held, width, hidden) * width ** -0.5
+    experts = np.stack([rng.permutation(total)[:top_k] for _ in range(rows)])
+    w = jnp.asarray(rng.uniform(0.1, 1, (rows, top_k)), jnp.float32)
+    live = np.arange(rows) % 2 == 0
+    every = np.where(experts < held, experts, held).astype(np.int32)
+    local = {"all_live": jnp.asarray(every),
+             "half_masked": jnp.asarray(np.where(live[:, None], every, held))}
+    tile = gm.tile_rows_for(rows * top_k)
+    active = {k: int(gm.layout(v.reshape(-1), held, tile)[2][0])
+              for k, v in local.items()}
+    _require(0 < active["half_masked"] * 10 <= active["all_live"] * 7,
+             f"routed: masking half the rows left {active['half_masked']} "
+             f"of {active['all_live']} tiles: dead rows still reach experts")
+
+    # a fresh function object, here and below: jit hands a function it has
+    # seen its cached trace, whatever `gm.grouped_matmul` is by then
+    step = jax.jit(lambda *a: routed.experts_pass(*a))
+    _, n_kernels, got, setup_s, steady_s = _run_twice(
+        step, m, local["half_masked"], w, gate, up, down)
+    _require(n_kernels >= 2 * min(min_kernels, 1),
+             f"routed: the expert pass compiled with {n_kernels} "
+             "tpu_custom_call, needs 2: it went to lax.ragged_dot")
+    full = np.asarray(step(m, local["all_live"], w, gate, up, down),
+                      np.float32)
+    masked = np.asarray(got, np.float32)
+    _require(bool(np.array_equal(masked[live], full[live])),
+             "routed: a live row's numbers moved when other rows were masked")
+    _require(not masked[~live].any(),
+             "routed: a row that was routed nowhere has a sum")
+    kernel = gm.grouped_matmul
+    gm.grouped_matmul = lambda x, tile_group, active, tiles_of, ws, tile: \
+        gm._ragged(x, tiles_of, ws, tile)
+    try:
+        want = jax.block_until_ready(jax.jit(
+            lambda *a: routed.experts_pass(*a))(
+                m, local["half_masked"], w, gate, up, down))
+    finally:
+        gm.grouped_matmul = kernel
+    errs = _check_against("routed", (got,), (want,), ("out",), tol)
+    timed = {}
+    for name, routes in local.items():
+        t0 = time.perf_counter()
+        for _ in range(20):
+            out = step(m, routes, w, gate, up, down)
+        jax.block_until_ready(out)
+        timed[name] = round((time.perf_counter() - t0) / 20 * 1e3, 4)
+    return {"routed_step": {"rows": rows, "top_k": top_k, "held": held,
+                            "total": total, "hidden": hidden, "width": width,
+                            "tile_rows": tile, "live_rows": int(live.sum())},
+            "tpu_custom_calls": n_kernels, "rel_err": errs,
+            "active_tiles": active, "pass_ms_smoke": timed}, setup_s, steady_s
+
+
 def phase_kernel(geometries=KERNEL_FULL, scan=SCAN_FULL, decode=DECODE_FULL,
-                 seed=0, min_kernels=2, tol=BF16_TOL) -> dict:
+                 routed=ROUTED_FULL, seed=0, min_kernels=2,
+                 tol=BF16_TOL) -> dict:
     """The long-sequence attention the framework selects by itself: each
     geometry through `nn.functional` attention in bf16, and the scanned
     ErnieLayer, forward and backward against `_reference_bhsd`; then the
     serve path's decode step (float32 pages, forward only) against its
-    dense read. `min_kernels` is 2 on the chip (a forward and a backward
-    kernel in the compiled text; the decode step needs one); only the CPU
-    rehearsal, which interprets, passes 0."""
+    dense read, and the routed expert pass with half its rows routed
+    nowhere against `lax.ragged_dot`. `min_kernels` is 2 on the chip (a
+    forward and a backward kernel in the compiled text; the decode step
+    needs one, the expert pass its two); only the CPU rehearsal, which
+    interprets, passes 0."""
     rows, setup_s, steady_s = [], 0.0, 0.0
     for i, (b, s, h, d, causal) in enumerate(geometries):
         row, su, st = _attention_geometry(b, s, h, d, causal, seed + i,
@@ -549,7 +631,9 @@ def phase_kernel(geometries=KERNEL_FULL, scan=SCAN_FULL, decode=DECODE_FULL,
     for row, su, st in (
             _scan_stack(**scan, seed=seed, min_kernels=min_kernels, tol=tol),
             _decode_step(**decode, seed=seed,
-                         min_kernels=min(min_kernels, 1), tol=tol)):
+                         min_kernels=min(min_kernels, 1), tol=tol),
+            _routed_step(**routed, seed=seed, min_kernels=min_kernels,
+                         tol=tol)):
         rows.append(row)
         setup_s, steady_s = setup_s + su, steady_s + st
     return _report("kernel", setup_s, steady_s,

@@ -19,7 +19,7 @@ from ..framework.param_attr import ParamAttr
 from ..core.tensor import Tensor
 from ..nn import functional as F
 from ..nn import initializer as I
-from ..ops._dispatch import run_op
+from ..ops._dispatch import nondiff_op, run_op
 from ..ops.creation import arange
 from ..ops.manipulation import concat, reshape, unsqueeze
 
@@ -205,3 +205,16 @@ def _rows_at(h, index):
             return jnp.take_along_axis(a, i[:, None, None], axis=1)[:, 0]
         return jnp.take_along_axis(a, i[..., None], axis=1)
     return run_op(f, [h, index], "llm_last_hidden")
+
+
+def _live_rows(positions, lengths, t):
+    """The rows [B, T] of a cached call that carry a token anyone reads,
+    bool: of a step (`lengths` None, T 1) those at a position past 0 (a
+    sequence's first step is at its prompt's length, at least 1, so a row
+    at position 0 is a free slot's or one left out), of a prompt those
+    before the row's length (the rest is the bucket's padding)."""
+    if lengths is None:
+        return nondiff_op(lambda p: (p > 0)[:, None], [positions])
+    return nondiff_op(
+        lambda n: jnp.arange(t, dtype=jnp.int32)[None, :] < n[:, None],
+        [lengths])

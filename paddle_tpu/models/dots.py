@@ -24,7 +24,9 @@ tags every page `kv_pool`; `forward_cached(tokens, cache, positions,
 lengths=None)` as `models/ling.py`'s: with `lengths` a prompt from an EMPTY
 cache, else one token a row through `cache`. After the cache's arrays it
 returns what the call reports, an expert layer each: the experts chosen
-`[B, T, top_k]`.
+`[B, T, top_k]`. As there, a step's row at position 0 carries no sequence
+and its output is unspecified, as is a prompt's row past its length: the
+expert layers route those rows nowhere (`_decoder._live_rows`).
 """
 from __future__ import annotations
 
@@ -34,8 +36,8 @@ from .. import nn
 from ..core.tensor import Tensor
 from ..framework.param_attr import ParamAttr
 from ._decoder import (
-    LatentAttention, SwiGLU, _linear, _logits, _Normal, _parameters_in,
-    _rows_at,
+    LatentAttention, SwiGLU, _linear, _live_rows, _logits, _Normal,
+    _parameters_in, _rows_at,
 )
 
 
@@ -65,17 +67,19 @@ class DotsLayer(nn.Layer):
                 bias_attr=ParamAttr(initializer=_Normal(
                     cfg["router_bias_std"])))
 
-    def forward_cached(self, x, page, positions, lengths, step, scores=None):
+    def forward_cached(self, x, page, positions, lengths, step, scores=None,
+                       live=None):
         """Returns (x, the page, the experts an expert layer chose [B, T,
         top_k] or None); `scores` (a list) gains an expert layer's biased
-        scores [B, T, n_routed_experts]."""
+        scores [B, T, n_routed_experts]; `live` [B, T] bool or None: the
+        rows an expert layer routes."""
         a, page = self.mixer.forward_cached(self.input_norm(x), page,
                                             positions, lengths, step)
         x = x + a
         m = self.post_norm(x)
         if self.ffn_kind != "moe":
             return x + self.mlp(m), page, None
-        y, experts, biased = self.mlp(m, return_choice=True)
+        y, experts, biased = self.mlp(m, return_choice=True, live=live)
         if scores is not None:
             scores.append(biased)
         return x + y, page, experts
@@ -127,12 +131,14 @@ class DotsModel(nn.Layer):
         """`cache` None: the full forward. Returns (hidden states, the new
         pages, every expert layer's chosen experts [B, T, top_k] int32)."""
         step = cache is not None and lengths is None
+        live = None if cache is None else _live_rows(
+            positions, lengths, input_ids.shape[1])
         x = self.embed_tokens(input_ids)
         pages, routes = [], []
         for i, layer in enumerate(self.layers):
             x, page, experts = layer.forward_cached(
                 x, None if cache is None else cache[i], positions, lengths,
-                step, scores)
+                step, scores, live)
             pages.append(page)
             if experts is not None:
                 routes.append(experts)

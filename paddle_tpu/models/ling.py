@@ -38,6 +38,13 @@ latent + rope, in whole 128s]`; `cache_tag` is a tuple, one tag an array
 `models/brumby.py`'s: with `lengths` a prompt from an EMPTY cache, else one
 token a row through `cache`. After the cache's arrays it returns what the
 call reports, an expert layer each: the experts chosen `[B, T, top_k]`.
+
+**A step's row at position 0 carries no sequence and its output is
+unspecified** (a sequence's first step is at its prompt's length, at least
+1; `serving.LLMEngine` marks free slots and left-out rows so), as is a
+prompt's row past its length: `_live_rows` says which rows those are and
+the expert layers route them nowhere (`nn.RoutedExperts(live=)`), so a
+step reads the experts its live rows reach. Their choice is still reported.
 """
 from __future__ import annotations
 
@@ -54,8 +61,8 @@ from ..nn import initializer as I
 from ..ops._dispatch import run_op
 from ..ops.manipulation import concat, reshape, unsqueeze
 from ._decoder import (
-    LatentAttention, SwiGLU, _linear, _logits, _Normal, _parameters_in,
-    _rows_at,
+    LatentAttention, SwiGLU, _linear, _live_rows, _logits, _Normal,
+    _parameters_in, _rows_at,
 )
 
 
@@ -206,10 +213,11 @@ class LingLayer(nn.Layer):
                  "kv_pool")]
 
     def forward_cached(self, x, cache, positions, lengths, step,
-                       scores=None):
+                       scores=None, live=None):
         """cache: this layer's arrays. Returns (x, new arrays, the experts
         an expert layer chose [B, T, top_k] or None); `scores` (a list)
-        gains an expert layer's biased scores [B, T, num_experts]."""
+        gains an expert layer's biased scores [B, T, num_experts]; `live`
+        [B, T] bool or None: the rows an expert layer routes."""
         u = self.input_norm(x)
         if self.mixer_kind == "kda":
             a, *new = self.mixer.forward_cached(u, *cache, lengths, step)
@@ -220,7 +228,7 @@ class LingLayer(nn.Layer):
         m = self.post_norm(x)
         if self.ffn_kind != "moe":
             return x + self.mlp(m), new, None
-        y, experts, biased = self.mlp(m, return_choice=True)
+        y, experts, biased = self.mlp(m, return_choice=True, live=live)
         if scores is not None:
             scores.append(biased)
         return x + y, new, experts
@@ -278,14 +286,16 @@ class LingModel(nn.Layer):
         """`cache` None: the full forward. Returns (hidden states, the new
         cache, every expert layer's chosen experts [B, T, top_k] int32)."""
         step = cache is not None and lengths is None
+        live = None if cache is None else _live_rows(
+            positions, lengths, input_ids.shape[1])
         x = self.embed_tokens(input_ids)
         new, routes, at = [], [], 0
         for layer in self.layers:
             n = 2 if layer.mixer_kind == "kda" else 1
             mine = [None] * n if cache is None else cache[at:at + n]
             at += n
-            x, kept, experts = layer.forward_cached(x, mine, positions,
-                                                    lengths, step, scores)
+            x, kept, experts = layer.forward_cached(
+                x, mine, positions, lengths, step, scores, live)
             new += kept
             if experts is not None:
                 routes.append(experts)

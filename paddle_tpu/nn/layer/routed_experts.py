@@ -21,6 +21,12 @@ grouped matmul a projection (`kernels/grouped_matmul.py`, `moe_experts`
 in a device trace): no capacity, no dropped token, the same code for a
 prompt's thousands of rows and a decode step's few. `parallel/moe.py` is
 the capacity-bucket layer (GShard) and stays what it is.
+
+A caller that knows which rows carry a token anyone will read says so
+(`live`): the others (a free slot's row in a decode step, a prompt
+bucket's padding) are routed NOWHERE, as a route to another rank is, so
+the step reads the experts its live rows reach and a dead row's output is
+the shared expert's alone. The router still reports every row's choice.
 """
 from __future__ import annotations
 
@@ -136,23 +142,31 @@ class RoutedExperts(Layer):
                          self.topk_group, self.scaling)
         return run_op(f, [m, self.router, self.router_bias], "moe_route")
 
-    def forward(self, x, return_choice=False):
+    def forward(self, x, return_choice=False, live=None):
         """x [..., hidden] -> the held experts' part of the sum plus the
         shared expert, in x's dtype; with `return_choice` also the chosen
         experts [..., top_k] and the biased scores s' [..., num_experts]
-        they were chosen by."""
+        they were chosen by. `live` (bool, x's leading dimensions): the
+        rows whose output anyone reads; the others reach no held expert
+        (their output is the shared expert's alone) but their choice is
+        reported all the same."""
         lead = list(x.shape[:-1])
         m = x.reshape([-1, x.shape[-1]])
         experts, w, scores = self.choose(m)
         first, count = self.held
 
-        def f(m, experts, w, gate, up, down):
+        def f(m, experts, w, gate, up, down, *live):
             local = experts - first
             local = jnp.where((local >= 0) & (local < count), local, count)
+            if live:
+                local = jnp.where(live[0].reshape(-1, 1), local, count)
             return experts_pass(m, local, w, gate, up, down).astype(m.dtype)
 
+        masked = [] if live is None else [live]
+        if masked and _monitor._ENABLED:
+            _monitor.count("moe.masked_traces")
         y = run_op(f, [m, experts, w, self.gate_proj, self.up_proj,
-                       self.down_proj], "moe_experts")
+                       self.down_proj] + masked, "moe_experts")
         if self.shared_gate is not None:
             y = y + self.shared_down(F.silu(self.shared_gate(m))
                                      * self.shared_up(m))

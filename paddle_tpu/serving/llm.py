@@ -38,6 +38,16 @@ wastes nearly all decode FLOPs and fixed batches idle between stragglers.
   (every array's row block at `slot`, which is data) and one decode
   executable, with ZERO steady-state compiles (the `jit.*` retrace
   counters stay flat). `llm.prefill.tokens_real` / `tokens_bucket`: padding.
+  A slot without a sequence still has a row in the step, and what the
+  model is told of it is its position: **a step's row at position 0
+  carries no sequence and its output is unspecified.** An empty prompt is
+  refused (`submit`), so a live row's position is at least 1, and
+  `_dispatch` leaves the position of a free slot and of a row it leaves
+  out 0. A model may spend nothing on such a row: the page reads
+  (`decode_attention`, `mla_decode`) read a block of it, the routed models
+  send it to no expert (`nn.RoutedExperts(live=)`;
+  `llm.decode.rows_dead` counts those rows beside `llm.decode.rows`), and
+  a prompt's rows past its `lengths` are the same to them.
 - **Continuous scheduler, one decode step ahead.** Every turn admits
   queued sequences into free slots and evicts on EOS/length/deadline,
   streaming each token to the caller the moment the host holds it (and
@@ -51,8 +61,8 @@ wastes nearly all decode FLOPs and fixed batches idle between stragglers.
   while the chip runs, not between two programs. The depth is exactly
   one step and not a setting. What the host knows ahead it uses: a
   sequence whose budget or page ends with the step in flight is left out
-  of the next one (its slot rides along as a junk row, as free slots
-  do). EOS and a deadline are learnt at the read: that sequence has one
+  of the next one (its row is at position 0, as a free slot's is:
+  above). EOS and a deadline are learnt at the read: that sequence has one
   more row in the step already in flight, whose token is discarded,
   never emitted (`llm.decode.discarded`), and its slot is free at once
   (the next prefill's slot write depends on the pool the step in flight
@@ -263,8 +273,10 @@ class _PrefillNet(nn.Layer):
 class _DecodeNet(nn.Layer):
     """THE decode executable: one fixed-shape step for the whole pool.
     (tokens [S], positions [S], *pool) -> (next greedy token [S], logits
-    [S, V], the updated pool). Free slots ride along as junk rows —
-    occupancy never changes the signature."""
+    [S, V], the updated pool). Occupancy never changes the signature: a
+    free slot has a row, at position 0, which carries no sequence and
+    whose outputs are unspecified (module docstring), so the model may
+    leave its work out (the page reads and the routed experts do)."""
 
     def __init__(self, lm):
         super().__init__()
@@ -718,7 +730,9 @@ class LLMEngine:
         or, with nothing in flight, the host's `last_token`s: the two
         never mix, since an admission drains the pipeline first and so
         every sequence that is live under a flight has a row in it. A
-        sequence whose last token is in flight is left out (a junk row)."""
+        sequence whose last token is in flight is left out: its row, like
+        a free slot's, stays at position 0, which tells the model that it
+        carries no sequence."""
         cfg = self.config
         now = time.monotonic()
         with self._lock:
@@ -765,6 +779,7 @@ class LLMEngine:
             if donated:
                 _monitor.count("llm.decode.pool_donated")
             _monitor.count("llm.decode.rows", len(rows))
+            _monitor.count("llm.decode.rows_dead", s - len(rows))
             if "state_pool" in self._tags:
                 # a step reads and rewrites every state, live or free
                 _monitor.count("llm.decode.state_bytes",

@@ -27,6 +27,10 @@ _TINY_ERNIE = dict(vocab_size=512, hidden_size=64, num_hidden_layers=2,
                    max_position_embeddings=64)
 
 
+# 24 rows x 2 routes over the 16 experts held of 32: a decode step's tile
+_TINY_ROUTED = dict(rows=24, top_k=2, held=16, total=32, hidden=32, width=128)
+
+
 @pytest.fixture(autouse=True)
 def _restore_process_state():
     """The phases switch the monitor on, pick a device and set a mesh, as
@@ -104,8 +108,9 @@ def test_kernel_phase_interprets_the_kernel_on_a_cpu(monkeypatch):
         scan=dict(hidden=128, heads=2, ffn=256, layers=2, batch=1, seq=256),
         decode=dict(slots=3, page=34, heads=2, head_dim=16, rows=2,
                     positions=(0, 31, 9)),
-        min_kernels=0)
-    attn, scan, decode = line["check"]["paths"]
+        routed=_TINY_ROUTED, min_kernels=0)
+    attn, scan, decode, experts = line["check"]["paths"]
+    assert experts["routed_step"]["live_rows"] == 12
     assert (attn["forward"], attn["backward"]) == ("pallas", "fused")
     assert attn["tpu_custom_calls"] == scan["tpu_custom_calls"] == 0
     assert max(attn["rel_err"].values()) <= chip_smoke.BF16_TOL
@@ -114,6 +119,38 @@ def test_kernel_phase_interprets_the_kernel_on_a_cpu(monkeypatch):
     assert routed == [(3, 2, 32)]
     assert decode["rel_err"]["k_page"] == decode["rel_err"]["v_page"] == 0
     assert decode["rel_err"]["out"] <= 1e-5
+
+
+def test_routed_step_holds_the_masked_pass_to_ragged_dot(monkeypatch):
+    """Tiny, the grouped matmul interpreted as a TPU runs it: the pass with
+    every other row routed nowhere goes through the kernel, what it is
+    compared with through `lax.ragged_dot`, and fewer tiles hold rows."""
+    import importlib
+    gm = importlib.import_module("paddle_tpu.kernels.grouped_matmul")
+    calls = []
+    monkeypatch.setattr(gm, "grouped_matmul", lambda x, g, a, t, ws, tile:
+                        calls.append(len(ws)) or gm._pallas(x, g, a, ws, tile,
+                                                            True))
+    row, _, _ = chip_smoke._routed_step(**_TINY_ROUTED, seed=0, min_kernels=0,
+                                        tol=chip_smoke.BF16_TOL)
+    assert calls == [2, 1]              # one trace: gate and up, then down
+    assert row["rel_err"]["out"] <= 1e-2
+    tiles = row["active_tiles"]
+    assert 0 < tiles["half_masked"] < tiles["all_live"] <= 16
+    assert set(row["pass_ms_smoke"]) == {"all_live", "half_masked"}
+
+
+def test_routed_step_refuses_dead_rows_that_reach_experts(monkeypatch):
+    """The check the chip run relies on: a layout that still gives the
+    masked rows tiles cannot pass."""
+    import importlib
+    gm = importlib.import_module("paddle_tpu.kernels.grouped_matmul")
+    layout = gm.layout
+    monkeypatch.setattr(gm, "layout", lambda group_of, groups, tile: layout(
+        group_of % groups, groups, tile))
+    with pytest.raises(chip_smoke.SmokeFailure, match="still reach experts"):
+        chip_smoke._routed_step(**_TINY_ROUTED, seed=0, min_kernels=0,
+                                tol=chip_smoke.BF16_TOL)
 
 
 def test_latent_phase_routes_both_forms_through_their_kernels(monkeypatch):

@@ -20,6 +20,7 @@ from paddle_tpu.serving import LLMConfig, LLMEngine
 
 attention = importlib.import_module("paddle_tpu.nn.functional.attention")
 kernel = importlib.import_module("paddle_tpu.kernels.mla_decode")
+routed = importlib.import_module("paddle_tpu.nn.layer.routed_experts")
 
 # the published group, at an original length the tiny rows pass
 YARN = dict(type="yarn", factor=40, beta_fast=32, beta_slow=1, mscale=1,
@@ -252,6 +253,72 @@ def test_full_forward_and_cached_path_match_the_reference():
     assert [str(c.dtype) for c in cache] == ["float32"] * 3
 
 
+@pytest.mark.parametrize("form", ["step", "prompt"])
+def test_rows_without_a_sequence_change_nothing_for_the_live_rows(
+        monkeypatch, form):
+    """A step in which a row sits at position 0 (a free slot's) gives the
+    other rows the logits and cache rows of the all-live step; a prompt
+    padded to its bucket gives each row the logits and cache rows of the
+    row alone, unpadded. The expert layers were handed the dead rows as
+    routes to nowhere, and report every row's choice all the same."""
+    lm = _tiny(seed=2)
+    own, count = len(lm.dots.layers), TINY["held"][1]
+    pages = [tag == "kv_pool" for tag in ["kv_pool"] * own]
+    seen = []
+    grouped = routed.experts_pass
+    monkeypatch.setattr(routed, "experts_pass", lambda m, local, *a: seen.append(
+        np.asarray(local)) or grouped(m, local, *a))
+    rng = np.random.default_rng(4)
+    ids = rng.integers(0, 96, (3, 16)).astype(np.int32)
+    n = np.array([11, 5, 16], np.int32)
+
+    def same_rows(got, want, r, alone, upto):
+        for a, b, page in zip(got[:own], want[:own], pages):
+            a, b = a.numpy()[r], b.numpy()[0 if alone else r]
+            if page:
+                a, b = a[:upto], b[:upto]
+            np.testing.assert_allclose(a, b, atol=2e-5)
+
+    with paddle.no_grad():
+        logits, out = lm.forward_cached(
+            paddle.to_tensor(ids), lm.init_cache(3, 24),
+            paddle.zeros([3], dtype="int32"), paddle.to_tensor(n))
+        padded = seen[:]
+        if form == "prompt":
+            for local in padded:
+                dead = (np.arange(16)[None, :] >= n[:, None]).reshape(-1)
+                assert (local[dead] == count).all()
+                assert (local[~dead] < count).any()
+            for r in range(3):
+                alone, out1 = lm.forward_cached(
+                    paddle.to_tensor(ids[r:r + 1, :n[r]]),
+                    lm.init_cache(1, 24), paddle.zeros([1], dtype="int32"),
+                    paddle.to_tensor(n[r:r + 1]))
+                np.testing.assert_allclose(logits.numpy()[r],
+                                           alone.numpy()[0], atol=2e-5)
+                same_rows(out, out1, r, True, n[r])
+                for a, b in zip(out[own:], out1[own:]):
+                    assert np.array_equal(a.numpy()[r, :n[r]], b.numpy()[0])
+            return
+        cache = out[:own]
+        tokens = paddle.to_tensor(rng.integers(0, 96, (3, 1)).astype(np.int32))
+        every, out_e = lm.forward_cached(tokens, cache, paddle.to_tensor(n))
+        del seen[:]
+        at = n.copy()
+        at[1] = 0
+        some, out_s = lm.forward_cached(tokens, cache, paddle.to_tensor(at))
+    assert len(seen) == len(out_s) - own > 0
+    for local in seen:
+        assert (local[1] == count).all() and (local[[0, 2]] < count).any()
+    for r in (0, 2):
+        np.testing.assert_allclose(some.numpy()[r], every.numpy()[r],
+                                   atol=2e-5)
+        same_rows(out_s, out_e, r, False, n[r] + 1)
+    for a, b in zip(out_s[own:], out_e[own:]):
+        assert a.shape == b.shape == [3, 1, 4]
+        assert np.array_equal(a.numpy()[[0, 2]], b.numpy()[[0, 2]])
+
+
 @pytest.fixture
 def monitored():
     was = monitor.enabled()
@@ -300,6 +367,11 @@ def test_engine_streams_the_full_forwards_greedy_tokens(monitored):
     steps = delta("llm.decode.steps")
     assert steps > 0 and delta("llm.decode.pool_donated") == steps
     assert delta("llm.decode.rows") == 3 * (new - 1)
+    # 4 slots, 3 streams: every step has rows that carry no sequence, and
+    # the programs that ran were traced with the mask that routes them
+    # nowhere (the tokens above are the full forward's all the same)
+    assert delta("llm.decode.rows_dead") == steps * 4 - 3 * (new - 1) > 0
+    assert after["moe.masked_traces"] > 0
     # pages only: the page counters run, the state counter does not
     assert "llm.decode.state_bytes" not in after
     assert delta("llm.decode.kv_rows_pool") == steps * 4 * 32

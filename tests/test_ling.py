@@ -111,6 +111,45 @@ def test_the_shares_add_up_to_the_uncut_layer(ranks):
     np.testing.assert_allclose(total + shared, want, atol=5e-5)
 
 
+@pytest.mark.parametrize("t", [40, 8])
+def test_rows_that_carry_no_token_are_routed_nowhere(monkeypatch, t):
+    """`live`: a live row's output is the unmasked call's to the bit, a dead
+    row's is the shared expert's alone, the grouped layout holds the tiles
+    of the live rows' routes and no other (at 8 rows: fewer tiles than all
+    rows' routes take, so fewer experts read), and the router still
+    reports every row's choice."""
+    layer, m = _experts(held=(4, 8)), _rows(t)
+    live = np.arange(t) % 2 == 0
+    seen = []
+    grouped = routed.experts_pass
+    monkeypatch.setattr(routed, "experts_pass", lambda m, local, *a: seen.append(
+        np.asarray(local)) or grouped(m, local, *a))
+    x = paddle.to_tensor(m)
+    full, chosen, scores = layer(x, return_choice=True)
+    got, chosen_m, scores_m = layer(x, return_choice=True,
+                                    live=paddle.to_tensor(live))
+    assert np.array_equal(got.numpy()[live], full.numpy()[live])
+    shared = layer.shared_down(F.silu(layer.shared_gate(x))
+                               * layer.shared_up(x)).numpy()
+    assert np.array_equal(got.numpy()[~live], shared[~live])
+    assert not np.array_equal(full.numpy()[~live], shared[~live])
+    # the choice is every row's, dead or live
+    assert np.array_equal(chosen_m.numpy(), chosen.numpy())
+    assert np.array_equal(scores_m.numpy(), scores.numpy())
+    # what went into the grouped layout: the live rows' routes to the 8
+    # held experts (4..11), everything else in the group that goes nowhere
+    local = chosen.numpy() - 4
+    local = np.where((local >= 0) & (local < 8), local, 8)
+    assert np.array_equal(seen[0], local)
+    assert np.array_equal(seen[1], np.where(live[:, None], local, 8))
+    tile = gm.tile_rows_for(seen[1].size)
+    tiles_of = gm.layout(jnp.asarray(seen[1].reshape(-1)), 8, tile)[3]
+    want = [-(-int((local[live] == e).sum()) // tile) for e in range(8)]
+    assert np.asarray(tiles_of).tolist() == want
+    every = int(gm.layout(jnp.asarray(local.reshape(-1)), 8, tile)[3].sum())
+    assert sum(want) <= every and (t != 8 or sum(want) < every)
+
+
 @pytest.mark.parametrize("rows,tile", [(24, 16), (700, 128)])
 def test_grouped_matmul_kernel_matches_ragged_dot(rows, tile):
     rng = np.random.default_rng(rows)
@@ -333,6 +372,72 @@ def test_full_forward_and_cached_path_match_the_reference():
     assert [str(c.dtype) for c in cache] == ["float32"] * 7
 
 
+@pytest.mark.parametrize("form", ["step", "prompt"])
+def test_rows_without_a_sequence_change_nothing_for_the_live_rows(
+        monkeypatch, form):
+    """A step in which a row sits at position 0 (a free slot's) gives the
+    other rows the logits and cache rows of the all-live step; a prompt
+    padded to its bucket gives each row the logits and cache rows of the
+    row alone, unpadded. The expert layers were handed the dead rows as
+    routes to nowhere, and report every row's choice all the same."""
+    lm = _tiny(seed=2)
+    own, count = len(lm.cache_tag), TINY["held"][1]
+    pages = [tag == "kv_pool" for tag in lm.cache_tag]
+    seen = []
+    grouped = routed.experts_pass
+    monkeypatch.setattr(routed, "experts_pass", lambda m, local, *a: seen.append(
+        np.asarray(local)) or grouped(m, local, *a))
+    rng = np.random.default_rng(4)
+    ids = rng.integers(0, 96, (3, 16)).astype(np.int32)
+    n = np.array([11, 5, 16], np.int32)
+
+    def same_rows(got, want, r, alone, upto):
+        for a, b, page in zip(got[:own], want[:own], pages):
+            a, b = a.numpy()[r], b.numpy()[0 if alone else r]
+            if page:
+                a, b = a[:upto], b[:upto]
+            np.testing.assert_allclose(a, b, atol=2e-5)
+
+    with paddle.no_grad():
+        logits, out = lm.forward_cached(
+            paddle.to_tensor(ids), lm.init_cache(3, 24),
+            paddle.zeros([3], dtype="int32"), paddle.to_tensor(n))
+        padded = seen[:]
+        if form == "prompt":
+            for local in padded:
+                dead = (np.arange(16)[None, :] >= n[:, None]).reshape(-1)
+                assert (local[dead] == count).all()
+                assert (local[~dead] < count).any()
+            for r in range(3):
+                alone, out1 = lm.forward_cached(
+                    paddle.to_tensor(ids[r:r + 1, :n[r]]),
+                    lm.init_cache(1, 24), paddle.zeros([1], dtype="int32"),
+                    paddle.to_tensor(n[r:r + 1]))
+                np.testing.assert_allclose(logits.numpy()[r],
+                                           alone.numpy()[0], atol=2e-5)
+                same_rows(out, out1, r, True, n[r])
+                for a, b in zip(out[own:], out1[own:]):
+                    assert np.array_equal(a.numpy()[r, :n[r]], b.numpy()[0])
+            return
+        cache = out[:own]
+        tokens = paddle.to_tensor(rng.integers(0, 96, (3, 1)).astype(np.int32))
+        every, out_e = lm.forward_cached(tokens, cache, paddle.to_tensor(n))
+        del seen[:]
+        at = n.copy()
+        at[1] = 0
+        some, out_s = lm.forward_cached(tokens, cache, paddle.to_tensor(at))
+    assert len(seen) == len(out_s) - own > 0
+    for local in seen:
+        assert (local[1] == count).all() and (local[[0, 2]] < count).any()
+    for r in (0, 2):
+        np.testing.assert_allclose(some.numpy()[r], every.numpy()[r],
+                                   atol=2e-5)
+        same_rows(out_s, out_e, r, False, n[r] + 1)
+    for a, b in zip(out_s[own:], out_e[own:]):
+        assert a.shape == b.shape == [3, 1, 4]
+        assert np.array_equal(a.numpy()[[0, 2]], b.numpy()[[0, 2]])
+
+
 @pytest.fixture
 def monitored():
     was = monitor.enabled()
@@ -383,6 +488,11 @@ def test_engine_streams_the_full_forwards_greedy_tokens(monitored):
     steps = delta("llm.decode.steps")
     assert steps > 0 and delta("llm.decode.pool_donated") == steps
     assert delta("llm.decode.rows") == 3 * (new - 1)
+    # 4 slots, 3 streams: every step has rows that carry no sequence, and
+    # the programs that ran were traced with the mask that routes them
+    # nowhere (the tokens above are the full forward's all the same)
+    assert delta("llm.decode.rows_dead") == steps * 4 - 3 * (new - 1) > 0
+    assert after["moe.masked_traces"] > 0
     # the three counter groups, each by its own rule
     state = sum(int(np.prod(s)) * 4 for s in
                 [(4, 2, 16, 16), (4, 3, 96)] * 3)
