@@ -1,7 +1,7 @@
 """chip_smoke.py — the quickest proof that the main path still runs on the chip.
 
     python chip_smoke.py             one TPU chip: train, serve, kernel,
-                                     latent (--phases names a part of them)
+                                     latent, ssd (--phases names a part)
     python chip_smoke.py --chips 4   four chips: ONLY the sharded paths
                                      (dp2 x mp2 train step, sp=4 ring
                                      attention) and what they are compared with
@@ -325,6 +325,12 @@ LATENT_FULL = dict(slots=16, page=13312, heads=128, latent=512, rope=64,
                    nope=128, v_dim=128, prompt=3072,
                    positions=(0, 1, 511, 512, 513, 1535, 3071, 4607, 6143,
                               8191, 8192, 9215, 12287, 13311, 2000, 0))
+# the Nemotron cell's widths: 256 slots' states, the 4096 bucket's prompt,
+# its pool of grouped K/V pages; float32 forms agree to their summation order
+SSD_FULL = dict(slots=256, heads=64, head_dim=64, groups=8, state=128,
+                prompt=4096, length=3000, page=5120, q_heads=32, kv_heads=2,
+                kv_dim=128)
+SSD_TOL = 1e-4
 
 
 def _fa():
@@ -737,6 +743,89 @@ def phase_latent(slots, page, heads, latent, rope, nope, v_dim, prompt,
                     "pool_rows": slots * page})
 
 
+def phase_ssd(slots, heads, head_dim, groups, state, prompt, length, page,
+              q_heads, kv_heads, kv_dim, seed=0, min_kernels=1,
+              tol=SSD_TOL, read_tol=BF16_TOL) -> dict:
+    """The state-space scan's two Pallas forms at the widths of the
+    Nemotron cell, float32: the one-token step over every slot's state
+    (`ssd_step`) against its `jax.numpy` form, and a prompt of `prompt`
+    positions padded past `length` (`ssd_chunked`) against the chunked
+    `jax.numpy` form; then a decode step's read of pages of grouped K/V
+    heads (`decode_attention_gqa`, bfloat16) against the dense read. Smoke
+    timings of the step and the read."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    ssd = importlib.import_module("paddle_tpu.kernels.ssd")
+    da = importlib.import_module("paddle_tpu.kernels.decode_attention")
+    from paddle_tpu.models import nemotron
+    interpret = jax.default_backend() != "tpu"
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    a = -jnp.asarray(rng.uniform(1.0, 16.0, heads), jnp.float32)
+
+    def inputs(*lead):
+        return (f(*lead, heads, head_dim),
+                jax.nn.softplus(f(*lead, heads) - 4.6), a,
+                f(*lead, groups, state), f(*lead, groups, state))
+
+    step_in = inputs(slots) + (f(slots, heads, head_dim, state),)
+    prompt_in = inputs(1, prompt) + (jnp.asarray([length], jnp.int32),)
+    rows, setup_s, steady_s = [], 0.0, 0.0
+    for name, kernel, form, args, names in (
+            ("step", jax.jit(functools.partial(ssd._step_pallas,
+                                               interpret=interpret)),
+             jax.jit(ssd._step_jnp), step_in, ("y", "state")),
+            ("chunked", jax.jit(functools.partial(ssd.ssd_chunked,
+                                                  form="pallas")),
+             jax.jit(functools.partial(ssd.ssd_chunked, form="jnp")),
+             prompt_in, ("y", "state"))):
+        text, n_kernels, got, su, st = _run_twice(kernel, *args)
+        _require(n_kernels >= min_kernels,
+                 f"ssd {name}: compiled with {n_kernels} tpu_custom_call, "
+                 f"needs {min_kernels}")
+        want = jax.block_until_ready(form(*args))
+        if name == "chunked":       # rows past the length mean nothing
+            got = (got[0][:, :length], got[1])
+            want = (want[0][:, :length], want[1])
+        rows.append({name: [int(d) for d in args[0].shape],
+                     "tpu_custom_calls": n_kernels,
+                     "rel_err": _check_against(f"ssd {name}", got, want,
+                                               names, tol),
+                     "smoke_seconds": round(st, 5)})
+        setup_s, steady_s = setup_s + su, steady_s + st
+    bf = lambda *shape: jnp.asarray(rng.uniform(-1, 1, shape), jnp.bfloat16)
+    q = bf(slots, q_heads, kv_dim)
+    k_page, v_page = (bf(slots, page, kv_heads * kv_dim) for _ in range(2))
+    pos = jnp.asarray(rng.integers(0, page, slots), jnp.int32)
+    scale = kv_dim ** -0.5
+    read = jax.jit(lambda *a: nemotron._attend_step(*a, scale))
+    text, n_kernels, got, su, st = _run_twice(read, q, k_page, v_page, pos)
+    _require(n_kernels >= min_kernels,
+             f"grouped read: compiled with {n_kernels} tpu_custom_call")
+    # the dense read repeats every K/V head in float32: 16 slots a call
+    engages = da.engages
+    da.engages = lambda *_: False
+    try:
+        dense = jax.jit(lambda *a: nemotron._attend_step(*a, scale))
+        want = jnp.concatenate([jax.block_until_ready(dense(
+            q[i:i + 16], k_page[i:i + 16], v_page[i:i + 16], pos[i:i + 16]))
+            for i in range(0, slots, 16)])
+    finally:
+        da.engages = engages
+    rows.append({"read": [int(d) for d in k_page.shape], "heads": q_heads,
+                 "tpu_custom_calls": n_kernels,
+                 "rel_err": _check_against("grouped read", (got,), (want,),
+                                           ("out",), read_tol),
+                 "smoke_seconds": round(st, 5)})
+    setup_s, steady_s = setup_s + su, steady_s + st
+    return _report("ssd", setup_s, steady_s,
+                   {"tolerance": tol, "read_tolerance": read_tol,
+                    "paths": rows})
+
+
 # ---- four chips: the sharded paths, and what they are compared with ---------
 
 def _count_op(hlo_text: str, op: str) -> int:
@@ -911,10 +1000,10 @@ def phase_ring(devices: Sequence, geometry=(1, 8192, 12, 64), seed=0,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="1: train, serve, kernel, latent on one chip "
+                    help="1: train, serve, kernel, latent, ssd on one chip "
                          "(default); 4: only the sharded paths and their "
                          "comparison")
-    ap.add_argument("--phases", default="train,serve,kernel,latent",
+    ap.add_argument("--phases", default="train,serve,kernel,latent,ssd",
                     help="the one-chip phases to run, comma-separated")
     args = ap.parse_args(argv)
     phases = args.phases.split(",")
@@ -950,6 +1039,9 @@ def main(argv=None) -> int:
             gc.collect()
         if "latent" in phases:
             phase_latent(**LATENT_FULL)
+            gc.collect()
+        if "ssd" in phases:
+            phase_ssd(**SSD_FULL)
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,
         "count": len(devices)}}), flush=True)

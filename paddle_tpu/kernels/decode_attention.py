@@ -170,3 +170,89 @@ def decode_attention(q, k_page, v_page, positions, num_heads):
                    block_k=BLOCK_K,
                    mxu=q.dtype if interpret else jnp.bfloat16,
                    interpret=interpret)
+
+
+# Grouped K/V heads: a page row holds `kv_heads * head_dim` lanes and each
+# K/V head is read by the `group` query heads that share it (grouped-query
+# attention). One decode row a slot; blocks of GQA_BLOCK_K rows, because at
+# hundreds of slots a grid step, skipped or not, costs more than the rows
+# of a short fill (`kernels/mla_decode.py` says why 512).
+GQA_BLOCK_K = 512
+
+
+def _gqa_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+                *, kv_heads, group, head_dim, tk, mxu):
+    b, j = pl.program_id(0), pl.program_id(1)
+    length = len_ref[b]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * tk < length)
+    def _():
+        key = j * tk + jax.lax.broadcasted_iota(jnp.int32, (group, tk), 1)
+        vrow = j * tk + jax.lax.broadcasted_iota(jnp.int32, (tk, head_dim), 0)
+        for g in range(kv_heads):
+            heads = slice(g * group, (g + 1) * group)
+            lanes = slice(g * head_dim, (g + 1) * head_dim)
+            s = jax.lax.dot_general(
+                q_ref[0, heads, :].astype(mxu), k_ref[0, :, lanes].astype(mxu),
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            s = jnp.where(key < length, s * (1.0 / math.sqrt(head_dim)),
+                          MASKED)
+            m_prev = m_ref[heads, :]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[heads, :] = alpha * l_ref[heads, :] + p.sum(axis=1,
+                                                               keepdims=True)
+            v = jnp.where(vrow < length, v_ref[0, :, lanes], 0).astype(mxu)
+            acc_ref[heads, :] = alpha * acc_ref[heads, :] + jnp.dot(
+                p.astype(mxu), v, preferred_element_type=jnp.float32)
+            m_ref[heads, :] = m_new
+
+    @pl.when(j == (length - 1) // tk)
+    def _():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def decode_attention_gqa(q, k_page, v_page, positions):
+    """One decode row a slot over pages of grouped K/V heads. q [B, heads,
+    head_dim]; k_page, v_page [B, L, kv_heads * head_dim] with this step's
+    K/V already written at positions[b]; heads a multiple of kv_heads,
+    query head h reading K/V head h // (heads / kv_heads). Returns [B,
+    heads, head_dim] in q's dtype: softmax(q k^T / sqrt(head_dim)) v over
+    keys 0 .. positions[b]. Each slot's blocks past its fill are skipped
+    as `decode_attention`'s are. Compiled on a TPU (bfloat16 to the MXU),
+    interpreted elsewhere (the operands' own dtype)."""
+    interpret = not _on_tpu()
+    b, heads, head_dim = q.shape
+    kv_heads = k_page.shape[2] // head_dim
+    rows_page = k_page.shape[1]
+    tk = min(GQA_BLOCK_K, rows_page)
+    lengths = jnp.minimum(positions.astype(jnp.int32) + 1, rows_page)
+
+    def page_block(i, j, lens):
+        return i, jnp.minimum(j, (lens[i] - 1) // tk), 0
+
+    page = pl.BlockSpec((1, tk, kv_heads * head_dim), page_block)
+    query = pl.BlockSpec((1, heads, head_dim), lambda i, j, lens: (i, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_gqa_kernel, kv_heads=kv_heads,
+                          group=heads // kv_heads, head_dim=head_dim, tk=tk,
+                          mxu=q.dtype if interpret else jnp.bfloat16),
+        name=KERNEL,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, pl.cdiv(rows_page, tk)),
+            in_specs=[query, page, page], out_specs=query,
+            scratch_shapes=[pltpu.VMEM((heads, 1), jnp.float32),
+                            pltpu.VMEM((heads, 1), jnp.float32),
+                            pltpu.VMEM((heads, head_dim), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(lengths, q, k_page, v_page)

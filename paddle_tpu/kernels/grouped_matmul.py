@@ -20,8 +20,8 @@ experts reached, not the experts held.
 
 On a TPU it is one Pallas kernel a call (`moe_experts` in a trace; bfloat16
 or float32 operands to the matmul unit in their own dtype, float32
-accumulation); `jax.lax.ragged_dot` over the same layout is the CPU path
-and the kernel's reference.
+accumulation; ws (None, w) squares relu(x @ w) after it); `jax.lax.ragged_dot`
+over the same layout is the CPU path and the kernel's reference.
 """
 from __future__ import annotations
 
@@ -145,10 +145,18 @@ def _ragged(x, tiles_of, ws, tile_rows):
 
 
 def grouped_matmul(x, tile_group, active, tiles_of, ws, tile_rows: int):
-    """x [padded_rows, k] in the grouped layout; ws: one stacked weight
-    [groups, k, n] (x @ w) or two (silu(x @ w0) * (x @ w1)). Returns
-    [padded_rows, n] in x's dtype; rows of tiles past `active` are
-    unspecified. The Pallas kernel on a TPU, `lax.ragged_dot` elsewhere."""
+    """x [padded_rows, k] in the grouped layout times `ws` (`_relu2` below
+    for ws (None, w)); rows of tiles past `active` are unspecified."""
+    if ws[0] is None:
+        return _relu2(x, tile_group, active, tiles_of, ws[1], tile_rows)
     if jax.default_backend() == "tpu":
         return _pallas(x, tile_group, active, ws, tile_rows, False)
     return _ragged(x, tiles_of, ws, tile_rows)
+
+
+def _relu2(x, tile_group, active, tiles_of, w, tile_rows):
+    """relu(x @ w)^2 a group, for experts of two projections: the kernel
+    with the one weight, then the square in float32, rounded to x's dtype
+    as the kernel rounds its own output."""
+    h = grouped_matmul(x, tile_group, active, tiles_of, (w,), tile_rows)
+    return jnp.square(jax.nn.relu(h.astype(jnp.float32))).astype(x.dtype)

@@ -21,6 +21,7 @@ from .gpt import (  # noqa: F401
 from .brumby import BrumbyForCausalLM, BrumbyModel  # noqa: F401
 from .ling import LingForCausalLM, LingModel  # noqa: F401
 from .dots import DotsForCausalLM, DotsModel  # noqa: F401
+from .nemotron import NemotronHForCausalLM, NemotronHModel  # noqa: F401
 from .yoloe import PPYOLOE, ppyoloe_l, ppyoloe_m, ppyoloe_s  # noqa: F401
 from .small_nets import (  # noqa: F401
     AlexNet, DenseNet, GoogLeNet, InceptionV3, ShuffleNetV2, SqueezeNet,

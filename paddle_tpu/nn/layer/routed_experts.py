@@ -1,7 +1,7 @@
 """A dropless routed expert layer that is told which experts it holds.
 
 DeepSeek-V3's router (bias-corrected sigmoid scores, a choice limited to
-the best groups) over SwiGLU experts, plus one shared expert:
+the best groups) over SwiGLU or relu^2 experts, plus one shared expert:
 
     s = sigmoid(W_r m)              s' = s + b       (b: for the choice only)
     group score = the sum of a group's 2 largest s'; keep `topk_group` groups
@@ -68,11 +68,11 @@ def route(logits, bias, top_k, n_group, topk_group, scaling):
 def experts_pass(m, local, w, gate, up, down):
     """sum over a row's routes of w * expert(m). m [T, hidden]; local
     [T, top_k] int32, the route's index among the experts held, or their
-    count for a route that lands elsewhere; w [T, top_k] float32; gate, up
-    [count, hidden, width]; down [count, width, hidden]. Returns [T,
-    hidden] float32."""
+    count for a route that lands elsewhere; w [T, top_k] float32; gate
+    (None: relu^2 experts), up [count, hidden, width]; down [count, width,
+    hidden]. Returns [T, hidden] float32."""
     t, k = local.shape
-    count = gate.shape[0]
+    count = up.shape[0]
     tile = _gm.tile_rows_for(t * k)
     place, tile_group, active, tiles_of = _gm.layout(local.reshape(-1),
                                                      count, tile)
@@ -94,7 +94,7 @@ def experts_pass(m, local, w, gate, up, down):
 class RoutedExperts(Layer):
     def __init__(self, hidden, width, num_experts, top_k, n_group=1,
                  topk_group=1, scaling=1.0, held=None, shared_width=0,
-                 weight_attr=None, bias_attr=None):
+                 weight_attr=None, bias_attr=None, activation="swiglu"):
         super().__init__()
         first, count = held if held is not None else (0, num_experts)
         if not (0 <= first and first + count <= num_experts and count > 0):
@@ -115,18 +115,18 @@ class RoutedExperts(Layer):
         stacked = lambda a, b: self.create_parameter(
             [count, a, b], attr=weight_attr,
             default_initializer=I.XavierNormal(fan_in=a, fan_out=b))
-        self.gate_proj = stacked(hidden, width)
+        # "relu2": experts of two projections, W_d relu(W_u m)^2, no gate
+        gated = {"swiglu": True, "relu2": False}[activation]
+        self.gate_proj = stacked(hidden, width) if gated else None
         self.up_proj = stacked(hidden, width)
         self.down_proj = stacked(width, hidden)
+        linear = lambda a, b: Linear(a, b, weight_attr, bias_attr=False)
         if shared_width:
-            self.shared_gate = Linear(hidden, shared_width, weight_attr,
-                                      bias_attr=False)
-            self.shared_up = Linear(hidden, shared_width, weight_attr,
-                                    bias_attr=False)
-            self.shared_down = Linear(shared_width, hidden, weight_attr,
-                                      bias_attr=False)
+            self.shared_gate = linear(hidden, shared_width) if gated else None
+            self.shared_up = linear(hidden, shared_width)
+            self.shared_down = linear(shared_width, hidden)
         else:
-            self.shared_gate = None
+            self.shared_gate = self.shared_up = None
         if _monitor._ENABLED:
             _monitor.gauge_set("moe.experts_held", count)
             _monitor.gauge_set("moe.experts_total", num_experts)
@@ -145,15 +145,15 @@ class RoutedExperts(Layer):
     def forward(self, x, return_choice=False, live=None):
         """x [..., hidden] -> the held experts' part of the sum plus the
         shared expert, in x's dtype; with `return_choice` also the chosen
-        experts [..., top_k] and the biased scores s' [..., num_experts]
-        they were chosen by. `live` (bool, x's leading dimensions): the
-        rows whose output anyone reads; the others reach no held expert
-        (their output is the shared expert's alone) but their choice is
-        reported all the same."""
+        experts [..., top_k] and their biased scores s' [..., num_experts].
+        `live` (bool, x's leading dimensions): the rows whose output anyone
+        reads; the others reach no held expert, their choice is reported."""
         lead = list(x.shape[:-1])
         m = x.reshape([-1, x.shape[-1]])
         experts, w, scores = self.choose(m)
         first, count = self.held
+        # relu^2 experts hold no gate: `experts_pass` is given None for it
+        gate = [] if self.gate_proj is None else [self.gate_proj]
 
         def f(m, experts, w, gate, up, down, *live):
             local = experts - first
@@ -161,15 +161,17 @@ class RoutedExperts(Layer):
             if live:
                 local = jnp.where(live[0].reshape(-1, 1), local, count)
             return experts_pass(m, local, w, gate, up, down).astype(m.dtype)
-
         masked = [] if live is None else [live]
         if masked and _monitor._ENABLED:
             _monitor.count("moe.masked_traces")
-        y = run_op(f, [m, experts, w, self.gate_proj, self.up_proj,
+        fn = f if gate else lambda m, e, w, *rest: f(m, e, w, None, *rest)
+        y = run_op(fn, [m, experts, w, *gate, self.up_proj,
                        self.down_proj] + masked, "moe_experts")
         if self.shared_gate is not None:
             y = y + self.shared_down(F.silu(self.shared_gate(m))
                                      * self.shared_up(m))
+        elif self.shared_up is not None:
+            y = y + self.shared_down(F.relu(self.shared_up(m)) ** 2)
         y = y.reshape(lead + [x.shape[-1]])
         if return_choice:
             return (y, experts.reshape(lead + [self.top_k]),

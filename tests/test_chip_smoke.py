@@ -215,3 +215,32 @@ def test_ring_phase_on_four_virtual_devices():
 def test_ring_phase_refuses_a_ring_without_the_kernel():
     with pytest.raises(chip_smoke.SmokeFailure, match="tpu_custom_call"):
         chip_smoke.phase_ring(jax.devices()[:4], geometry=(1, 512, 2, 64))
+
+
+_TINY_SSD = dict(slots=3, heads=4, head_dim=8, groups=2, state=16, prompt=40,
+                 length=29, page=40, q_heads=4, kv_heads=2, kv_dim=16)
+
+
+def test_ssd_phase_interprets_both_forms_and_the_grouped_read(monkeypatch):
+    """Tiny, interpreted: the step and the chunked prompt through their
+    Pallas forms against the `jax.numpy` ones, and the grouped read
+    through `decode_attention_gqa` when it engages, against the dense
+    read that the comparison takes."""
+    import importlib
+    da = importlib.import_module("paddle_tpu.kernels.decode_attention")
+    monkeypatch.setattr(da, "engages", lambda *a: True)
+    read, seen = da.decode_attention_gqa, []
+    monkeypatch.setattr(da, "decode_attention_gqa", lambda *a: seen.append(
+        a[1].shape) or read(*a))
+    line = chip_smoke.phase_ssd(**_TINY_SSD, min_kernels=0)
+    step, chunked, grouped = line["check"]["paths"]
+    assert step["step"] == [3, 4, 8] and chunked["chunked"] == [1, 40, 4, 8]
+    assert max(step["rel_err"].values()) <= chip_smoke.SSD_TOL
+    assert max(chunked["rel_err"].values()) <= chip_smoke.SSD_TOL
+    assert grouped["rel_err"]["out"] <= chip_smoke.BF16_TOL
+    assert seen == [(3, 40, 32)]
+
+
+def test_ssd_phase_refuses_a_program_without_the_kernel():
+    with pytest.raises(chip_smoke.SmokeFailure, match="tpu_custom_call"):
+        chip_smoke.phase_ssd(**_TINY_SSD)

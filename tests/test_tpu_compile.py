@@ -547,3 +547,68 @@ def test_dots_prefill_program_compiles_at_the_largest_bucket(sds,
     assert text.startswith("HloModule jit_llm_prefill")
     assert "%mla_prefill" in text
     assert "bf16[1,13312,640]" in text        # the page, whole
+
+
+def _nemotron_engine(slots):
+    """A Nemotron-H model at the published widths cut to one block of each
+    kind (published layers 0, 1 and 5: Mamba-2, experts, attention), 2 of
+    128 experts held and 1024 rows of vocabulary, in an engine at the
+    cell's page and buckets."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.nemotron import NemotronHForCausalLM, NemotronHModel
+    from paddle_tpu.serving import LLMConfig, LLMEngine
+
+    paddle.seed(0)
+    lm = NemotronHForCausalLM(NemotronHModel(
+        vocab_size=1024, layers=[0, 1, 5], held=(0, 2), dtype="bfloat16"))
+    eng = LLMEngine(lm, LLMConfig(num_slots=slots, max_len=5120,
+                                  prefill_buckets=(256, 4096),
+                                  warmup_on_start=False))
+    return lm, eng
+
+
+def test_nemotron_decode_program_rewrites_its_states_in_place(sds,
+                                                             monkeypatch):
+    """The engine's decode program over a Nemotron-H model: the Mamba-2
+    state and its convolution rows and the two grouped K/V pages are
+    donated and aliased out; the state update is `ssd_step`, the experts
+    two grouped matmuls, the page read the grouped `decode_attention`."""
+    ssd = importlib.import_module("paddle_tpu.kernels.ssd")
+    gm = importlib.import_module("paddle_tpu.kernels.grouped_matmul")
+    da = importlib.import_module("paddle_tpu.kernels.decode_attention")
+    slots = 8
+    lm, eng = _nemotron_engine(slots)
+    assert lm.cache_tag == ("state_pool",) * 2 + ("kv_pool",) * 2
+    inputs = [sds((slots,), jnp.int32), sds((slots,), jnp.int32)] + [
+        sds(tuple(t.shape), t._value.dtype) for t in eng._pool]
+    pool_bytes = sum(t._value.nbytes for t in eng._pool)
+    assert pool_bytes == slots * (64 * 64 * 128 * 4 + 3 * 6144 * 2
+                                  + 2 * 5120 * 256 * 2)
+    eng._pool = []
+    donated = eng._decode.forward._donated(len(inputs))
+    compiled = _compile_net(eng._decode, inputs, donated, sds, monkeypatch)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 4
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_llm_decode")
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 + 2 + 1
+    assert f"%{ssd.STEP_KERNEL}" in text and f"%{da.KERNEL}" in text
+    assert text.count(f"%{gm.KERNEL}") >= 2
+
+
+def test_nemotron_prefill_program_compiles_at_the_largest_bucket(
+        sds, monkeypatch):
+    """One prompt at the 4096 bucket: the chunked scan's kernel, the flash
+    prompt form over the grouped heads and the grouped matmul; it returns a
+    slot's whole pages."""
+    lm, eng = _nemotron_engine(2)
+    eng._pool = []
+    compiled = _compile_net(
+        eng._prefill, [sds((1, 4096), jnp.int32), sds((1,), jnp.int32)], (),
+        sds, monkeypatch)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_llm_prefill")
+    assert "%ssd_chunked" in text and "%gqa_prefill" in text
+    assert "bf16[1,5120,256]" in text         # a page, whole
