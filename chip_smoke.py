@@ -743,6 +743,42 @@ def phase_latent(slots, page, heads, latent, rope, nope, v_dim, prompt,
                     "pool_rows": slots * page})
 
 
+def _ssd_step_rate(ssd, args, interpret, groups, reps=20) -> dict:
+    """The step kernel over donated states, called back to back as the
+    decode program calls it: its bytes (one Mamba layer's,
+    `benchmarks/nemotron_cost.ssd_step_bytes`), and on a TPU the smoke
+    time of a call (the inputs' small preparation included), GB/s and the
+    share of the device's HBM peak (`benchmarks/peaks.json`)."""
+    import jax
+    import jax.numpy as jnp
+    from benchmarks import flops, nemotron_cost
+
+    x, dt, a, b, c, s = args
+    slots, heads, head_dim, state = s.shape
+    cfg = {"mamba_num_heads": heads, "mamba_head_dim": head_dim,
+           "n_groups": groups, "ssm_state_size": state,
+           "hybrid_override_pattern": "M", "num_hidden_layers": 1}
+    line = {"step_bytes": nemotron_cost.ssd_step_bytes(cfg, slots),
+            "step_ms_smoke": None, "step_gb_per_s": None,
+            "step_hbm_share": None}
+    if interpret:                       # an interpreter's time is no rate
+        return line
+    step = jax.jit(functools.partial(ssd._step_pallas, interpret=False),
+                   donate_argnums=5)
+    s = jax.block_until_ready(step(x, dt, a, b, c, jnp.array(s))[1])
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        s = step(x, dt, a, b, c, s)[1]
+    jax.block_until_ready(s)
+    seconds = (time.perf_counter() - t0) / reps
+    rate = line["step_bytes"] / seconds
+    peak = flops.peak(jax.devices()[0].device_kind)["hbm_bytes_per_s"]
+    line.update(step_ms_smoke=round(seconds * 1e3, 4),
+                step_gb_per_s=round(rate / 1e9, 1),
+                step_hbm_share=round(rate / peak, 4))
+    return line
+
+
 def phase_ssd(slots, heads, head_dim, groups, state, prompt, length, page,
               q_heads, kv_heads, kv_dim, seed=0, min_kernels=1,
               tol=SSD_TOL, read_tol=BF16_TOL) -> dict:
@@ -752,7 +788,8 @@ def phase_ssd(slots, heads, head_dim, groups, state, prompt, length, page,
     positions padded past `length` (`ssd_chunked`) against the chunked
     `jax.numpy` form; then a decode step's read of pages of grouped K/V
     heads (`decode_attention_gqa`, bfloat16) against the dense read. Smoke
-    timings of the step and the read."""
+    timings of the step and the read; the step's GB/s over donated states
+    and its share of the HBM peak (`_ssd_step_rate`)."""
     import importlib
 
     import jax
@@ -796,6 +833,7 @@ def phase_ssd(slots, heads, head_dim, groups, state, prompt, length, page,
                                                names, tol),
                      "smoke_seconds": round(st, 5)})
         setup_s, steady_s = setup_s + su, steady_s + st
+    rows[0].update(_ssd_step_rate(ssd, step_in, interpret, groups))
     bf = lambda *shape: jnp.asarray(rng.uniform(-1, 1, shape), jnp.bfloat16)
     q = bf(slots, q_heads, kv_dim)
     k_page, v_page = (bf(slots, page, kv_heads * kv_dim) for _ in range(2))

@@ -26,11 +26,11 @@ Two forms:
   (`ssd_chunked` in a trace): grid (row, group, chunk), the chunks in
   order with the group's states in VMEM, positions on the lanes, float32
   throughout; the `jax.numpy` form is the CPU path and its reference.
-- `ssd_step`: one token. Every byte of every state is read and rewritten,
-  so the step is bound by memory; on a TPU it is one Pallas kernel
-  (`ssd_step`) that streams the states through VMEM once, in place, in
-  float32 on the vector unit. The `jax.numpy` form is the CPU path and the
-  kernel's reference.
+- `ssd_step`: one token, bound by memory: on a TPU one Pallas kernel
+  (`ssd_step`) streams every state through VMEM once, in place, float32 on
+  the vector unit; exp(Delta A) is one scalar a head, from SMEM, and y is
+  read off the new tile transposed, a sum along its sublanes. The
+  `jax.numpy` form is the CPU path and the kernel's reference.
 
 The causal depthwise convolution in front (kernel 4, with a bias) is
 `kernels/kda.py`'s `short_conv_prompt` / `short_conv_step` plus the bias:
@@ -48,7 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 from . import kda as _kda
 
 CHUNK = 128
-HEAD_BLOCK = 32        # heads of one slot a grid step of the step kernel takes
+HEAD_BLOCK = 64        # heads of one slot a grid step of the step kernel takes
 STEP_KERNEL = "ssd_step"
 CHUNK_KERNEL = "ssd_chunked"
 _EXACT = jax.lax.Precision.HIGHEST
@@ -221,24 +221,24 @@ def _step_jnp(x, dt, a, b, c, s):
     return (new * ch[:, :, None, :]).sum(-1), new
 
 
-def _step_kernel(st_ref, cols_ref, bc_ref, out_ref, y_ref, *, hb, per_group):
-    """`hb` heads of one slot. A head's tile [P, N]: decay it, take the
-    rank-1 product in, store in place, read y off the new tile. cols holds
-    two columns [P, 1] a head (Delta x, and exp(Delta A) repeated), bc the
-    B rows of the block's groups, then their C rows."""
-    groups = hb // per_group
-    p = st_ref.shape[2]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (p, hb), 1)
-    y = jnp.zeros((p, hb), jnp.float32)
-    for j in range(hb):
-        g = j // per_group
-        new = (st_ref[0, j] * cols_ref[0, 0, :, hb + j:hb + j + 1]
-               + cols_ref[0, 0, :, j:j + 1] * bc_ref[0, 0, g:g + 1, :])
-        out_ref[0, j] = new.astype(out_ref.dtype)
-        got = jnp.sum(new * bc_ref[0, 0, groups + g:groups + g + 1, :],
-                      axis=1, keepdims=True)
-        y = jnp.where(lane == j, got, y)
-    y_ref[0, 0] = y
+def _step_kernel(ea_ref, st_ref, dx_ref, b_ref, ct_ref, out_ref, y_ref, *,
+                 hb, per_group):
+    """`hb` heads of one slot. A head's tile [P, N]: decay it by its
+    exp(Delta A), one scalar from SMEM; take the rank-1 product in (Delta x,
+    a column of `dx_ref` [P, hb], against the group's B row); store in
+    place; read y off the new tile transposed, a sum along the sublanes
+    against the group's C column (`ct_ref` [N, groups]), and write it as
+    the head's row of `y_ref` [hb, P]. B and C are read once a group."""
+    p, n = st_ref.shape[2], st_ref.shape[3]
+    for g in range(hb // per_group):
+        b_row = b_ref[0, 0, g:g + 1, :]
+        c_cols = jnp.broadcast_to(ct_ref[0, 0, :, g:g + 1], (n, p))
+        for j in range(g * per_group, (g + 1) * per_group):
+            new = (st_ref[0, j] * ea_ref[0, 0, 0, j]
+                   + dx_ref[0, 0, :, j:j + 1] * b_row)
+            out_ref[0, j] = new.astype(out_ref.dtype)
+            y_ref[0, 0, j:j + 1, :] = jnp.sum(new.T * c_cols, axis=0,
+                                              keepdims=True)
 
 
 def _step_pallas(x, dt, a, b, c, s, interpret=None):
@@ -248,35 +248,33 @@ def _step_pallas(x, dt, a, b, c, s, interpret=None):
     hb = HEAD_BLOCK if h % HEAD_BLOCK == 0 and HEAD_BLOCK % per_group == 0 \
         else h
     nb, groups = h // hb, hb // per_group
-    # columns lie along the sublanes of a state tile, a head a lane
-    cols = jnp.concatenate(
-        [dt[..., None] * x,
-         jnp.broadcast_to(jnp.exp(dt * a)[..., None], x.shape)], axis=1)
-    cols = cols.reshape(bsz, 2, nb, hb, p).transpose(0, 2, 4, 1, 3)
-    cols = cols.reshape(bsz, nb, p, 2 * hb)
-    bc = jnp.stack([b.reshape(bsz, nb, groups, n),
-                    c.reshape(bsz, nb, groups, n)], axis=2
-                   ).reshape(bsz, nb, 2 * groups, n)
+    ea = jnp.exp(dt * a).reshape(bsz, nb, 1, hb)
+    # Delta x as columns along the sublanes of a state tile, a head a lane
+    dx = (dt[..., None] * x).reshape(bsz, nb, hb, p).transpose(0, 1, 3, 2)
+    b_rows = b.reshape(bsz, nb, groups, n)
+    c_cols = c.reshape(bsz, nb, groups, n).transpose(0, 1, 3, 2)
     tile = pl.BlockSpec((1, hb, p, n), lambda i, j: (i, j, 0, 0))
     if interpret is None:
         interpret = not _on_tpu()
     new, y = pl.pallas_call(
         functools.partial(_step_kernel, hb=hb, per_group=per_group),
         name=STEP_KERNEL, grid=(bsz, nb),
-        in_specs=[tile,
-                  pl.BlockSpec((1, 1, p, 2 * hb), lambda i, j: (i, j, 0, 0)),
-                  pl.BlockSpec((1, 1, 2 * groups, n),
-                               lambda i, j: (i, j, 0, 0))],
-        out_specs=(tile, pl.BlockSpec((1, 1, p, hb),
+        in_specs=[pl.BlockSpec((1, 1, 1, hb), lambda i, j: (i, j, 0, 0),
+                               memory_space=pltpu.SMEM),
+                  tile,
+                  pl.BlockSpec((1, 1, p, hb), lambda i, j: (i, j, 0, 0)),
+                  pl.BlockSpec((1, 1, groups, n), lambda i, j: (i, j, 0, 0)),
+                  pl.BlockSpec((1, 1, n, groups), lambda i, j: (i, j, 0, 0))],
+        out_specs=(tile, pl.BlockSpec((1, 1, hb, p),
                                       lambda i, j: (i, j, 0, 0))),
         out_shape=(jax.ShapeDtypeStruct(s.shape, s.dtype),
-                   jax.ShapeDtypeStruct((bsz, nb, p, hb), jnp.float32)),
-        input_output_aliases={0: 0},
+                   jax.ShapeDtypeStruct((bsz, nb, hb, p), jnp.float32)),
+        input_output_aliases={1: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(s, cols, bc)
-    return y.transpose(0, 1, 3, 2).reshape(bsz, h, p), new
+    )(ea, s, dx, b_rows, c_cols)
+    return y.reshape(bsz, h, p), new
 
 
 def ssd_step(x, dt, a, b, c, s):

@@ -236,6 +236,10 @@ def test_ssd_phase_interprets_both_forms_and_the_grouped_read(monkeypatch):
     step, chunked, grouped = line["check"]["paths"]
     assert step["step"] == [3, 4, 8] and chunked["chunked"] == [1, 40, 4, 8]
     assert max(step["rel_err"].values()) <= chip_smoke.SSD_TOL
+    # one Mamba layer's bytes: every state read and written, the inputs
+    assert step["step_bytes"] == 4 * 3 * (2 * 4 * 8 * 16 + 2 * 4 * 8 + 4
+                                          + 2 * 2 * 16)
+    assert step["step_gb_per_s"] is None      # no rate off the chip
     assert max(chunked["rel_err"].values()) <= chip_smoke.SSD_TOL
     assert grouped["rel_err"]["out"] <= chip_smoke.BF16_TOL
     assert seen == [(3, 40, 32)]

@@ -51,16 +51,29 @@ def test_ssd_chunked_matches_the_recurrence_across_chunks(form):
     np.testing.assert_allclose(s, want_s, atol=2e-5)
 
 
-def test_ssd_step_kernel_matches_its_jnp_form():
+@pytest.mark.parametrize("h, g, p, n", [
+    (8, 2, 8, 16),          # the head block does not divide: one block
+    (128, 8, 64, 128),      # two head blocks of four groups each
+    (64, 8, 64, 128),       # the cell's heads: one block, the whole slot
+], ids=["h8-g2", "h128-g8", "h64-g8"])
+def test_ssd_step_kernel_matches_its_jnp_form(h, g, p, n):
+    """The kernel, interpreted, against its `jax.numpy` form at head
+    structures that take one or several head blocks and groups."""
     x, delta, a, b, c = (v[:, 0] if v.ndim > 1 else v
-                         for v in _ssd_inputs(b=3, h=8, g=2))
-    s = jnp.asarray(np.random.default_rng(1).normal(size=(3, 8, 8, 16)),
-                    jnp.float32)
+                         for v in _ssd_inputs(b=3 if h == 8 else 2, h=h, p=p,
+                                              g=g, n=n))
+    s = jnp.asarray(np.random.default_rng(1).normal(
+        size=(x.shape[0], h, p, n)), jnp.float32)
     y, new = ssd._step_jnp(x, delta, a, b, c, s)
     y_k, new_k = ssd._step_pallas(x, delta, a, b, c, s, interpret=True)
     np.testing.assert_allclose(y_k, y, atol=1e-5)
     np.testing.assert_allclose(new_k, new, atol=1e-6)
-    # and the step continues the chunked form: one more position
+
+
+def test_ssd_step_continues_the_chunked_form():
+    """One more position after a chunked prompt: the step's y is the
+    chunked form's at that position."""
+    a = _ssd_inputs(b=3, h=8, g=2)[2]
     xs, ds, _, bs, cs = _ssd_inputs(b=3, h=8, g=2, t=10)
     _, s9 = ssd.ssd_chunked(xs[:, :9], ds[:, :9], a, bs[:, :9], cs[:, :9])
     y10, _ = ssd.ssd_chunked(xs, ds, a, bs, cs)
