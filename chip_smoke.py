@@ -1,7 +1,8 @@
 """chip_smoke.py — the quickest proof that the main path still runs on the chip.
 
     python chip_smoke.py             one TPU chip: train, serve, kernel,
-                                     latent, ssd (--phases names a part)
+                                     latent, ssd, lfm2 (--phases names a
+                                     part)
     python chip_smoke.py --chips 4   four chips: ONLY the sharded paths
                                      (dp2 x mp2 train step, sp=4 ring
                                      attention) and what they are compared with
@@ -331,6 +332,13 @@ SSD_FULL = dict(slots=256, heads=64, head_dim=64, groups=8, state=128,
                 prompt=4096, length=3000, page=5120, q_heads=32, kv_heads=2,
                 kv_dim=128)
 SSD_TOL = 1e-4
+# the LFM2 cell's page read (128 slots of a [5120, 8 x 64] bfloat16 page a
+# side, 32 query heads) and its model at the published widths cut to one
+# block of each kind that follows the first (published layers 1-3: a conv
+# mixer with the dense FFN, attention and a conv mixer with the 64 routed
+# experts), teacher-forced through its cached path against the reference
+LFM2_FULL = dict(slots=128, page=5120, heads=32, kv_heads=8, head_dim=64,
+                 layers=(1, 2, 3), prompts=(600, 300, 130, 64), steps=16)
 
 
 def _fa():
@@ -864,6 +872,93 @@ def phase_ssd(slots, heads, head_dim, groups, state, prompt, length, page,
                     "paths": rows})
 
 
+def _read_ms(read, args, reps=20) -> float:
+    """Smoke ms of one call of the jitted `read`, back to back."""
+    import jax
+    jax.block_until_ready(read(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = read(*args)
+    jax.block_until_ready(out)
+    return round((time.perf_counter() - t0) / reps * 1e3, 4)
+
+
+def phase_lfm2(slots, page, heads, kv_heads, head_dim, layers, prompts,
+               steps, sizes=None, seed=0, min_kernels=1, read_tol=BF16_TOL,
+               logit_tol=None) -> dict:
+    """The LFM2 cell's two new paths on the chip: a decode step's read of
+    pages of 8 K/V heads of 64 (`decode_attention_gqa`, bfloat16, both its
+    forms: each head's lanes sliced, and the query laid over the whole row)
+    against the dense read, with smoke timings; then the model at the
+    configuration's widths (`sizes`, default
+    `benchmarks/configs/lfm2_24b_a2b.json`) holding the published `layers`,
+    built as the benchmark builds it, its cached path (a padded prompt,
+    then `steps` one-token steps fed the given tokens) against the plain
+    reference on that path's own choices."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks import harness
+    from benchmarks.runners import lfm2_serve, ling_serve
+    from paddle_tpu.models import nemotron
+    da = importlib.import_module("paddle_tpu.kernels.decode_attention")
+    logit_tol = ling_serve.LOGIT_TOL if logit_tol is None else logit_tol
+    rng = np.random.default_rng(seed)
+    bf = lambda *shape: jnp.asarray(rng.uniform(-1, 1, shape), jnp.bfloat16)
+    q = bf(slots, heads, head_dim)
+    k_page, v_page = (bf(slots, page, kv_heads * head_dim) for _ in range(2))
+    pos = jnp.asarray(rng.integers(0, page, slots), jnp.int32)
+    engages = da.engages
+    da.engages = lambda *_: False
+    try:
+        dense = jax.jit(lambda *a: nemotron._attend_step(*a, head_dim ** -0.5))
+        want = jnp.concatenate([jax.block_until_ready(dense(
+            q[i:i + 16], k_page[i:i + 16], v_page[i:i + 16], pos[i:i + 16]))
+            for i in range(0, slots, 16)])
+    finally:
+        da.engages = engages
+    rows, setup_s, steady_s = [], 0.0, 0.0
+    for wide in (True, False):
+        read = jax.jit(functools.partial(da._gqa_read, wide=wide))
+        args = (q, k_page, v_page, pos)
+        text, n_kernels, got, su, st = _run_twice(read, *args)
+        _require(n_kernels >= min_kernels,
+                 f"lfm2 read: compiled with {n_kernels} tpu_custom_call")
+        name = "wide" if wide else "sliced"
+        rows.append({"read": [int(d) for d in k_page.shape], "form": name,
+                     "heads": heads, "tpu_custom_calls": n_kernels,
+                     "rel_err": _check_against(f"lfm2 {name} read", (got,),
+                                               (want,), ("out",), read_tol),
+                     "read_ms_smoke": None if jax.default_backend() != "tpu"
+                     else _read_ms(read, args)})
+        setup_s, steady_s = setup_s + su, steady_s + st
+    del q, k_page, v_page, want
+    if sizes is None:
+        sizes = harness.load_json("configs", "lfm2_24b_a2b.json")
+    sizes = dict(sizes, layers_held=list(layers))
+    t0 = time.perf_counter()
+    lm = lfm2_serve.build_model(sizes, seed)
+    n = np.asarray(prompts, np.int32)
+    pad_to = -(-(int(n.max()) + steps) // 128) * 128
+    ids = rng.integers(0, sizes["vocab_size"], (len(n), pad_to)
+                       ).astype(np.int32)
+    got, routes = ling_serve.cached_logits(lm, ids, n, pad_to, steps)
+    found = lfm2_serve.against_reference(
+        lm, sizes, ids, n, np.full(len(n), pad_to, np.int32), steps, got,
+        routes)
+    _require(found["err"] <= logit_tol,
+             f"lfm2 cached path: {found['err']:.4g} from the reference, "
+             f"tolerance {logit_tol}")
+    rows.append({"model_layers": list(layers), "prompts": n.tolist(),
+                 "steps": steps, "rel_err": round(found["err"], 5),
+                 "tolerance": logit_tol,
+                 "smoke_seconds": round(time.perf_counter() - t0, 2)})
+    return _report("lfm2", setup_s, steady_s,
+                   {"read_tolerance": read_tol, "paths": rows})
+
+
 # ---- four chips: the sharded paths, and what they are compared with ---------
 
 def _count_op(hlo_text: str, op: str) -> int:
@@ -1038,10 +1133,10 @@ def phase_ring(devices: Sequence, geometry=(1, 8192, 12, 64), seed=0,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="1: train, serve, kernel, latent, ssd on one chip "
-                         "(default); 4: only the sharded paths and their "
-                         "comparison")
-    ap.add_argument("--phases", default="train,serve,kernel,latent,ssd",
+                    help="1: train, serve, kernel, latent, ssd, lfm2 on "
+                         "one chip (default); 4: only the sharded paths and "
+                         "their comparison")
+    ap.add_argument("--phases", default="train,serve,kernel,latent,ssd,lfm2",
                     help="the one-chip phases to run, comma-separated")
     args = ap.parse_args(argv)
     phases = args.phases.split(",")
@@ -1080,6 +1175,9 @@ def main(argv=None) -> int:
             gc.collect()
         if "ssd" in phases:
             phase_ssd(**SSD_FULL)
+            gc.collect()
+        if "lfm2" in phases:
+            phase_lfm2(**LFM2_FULL)
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,
         "count": len(devices)}}), flush=True)

@@ -219,6 +219,51 @@ def _gqa_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
+def _gqa_wide_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                     acc_ref, *, group, head_dim, tk, mxu):
+    """Heads narrower than a lane tile: every query head scores the WHOLE
+    page row, its query in its K/V head's lanes and zeros elsewhere (the
+    wrapper lays it out so), so one matmul against the block scores every
+    head and no lane is sliced; of `probs @ V` each head keeps its own K/V
+    head's lanes at the end."""
+    b, j = pl.program_id(0), pl.program_id(1)
+    length = len_ref[b]
+    heads, width = q_ref.shape[1], k_ref.shape[2]
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * tk < length)
+    def _():
+        s = jax.lax.dot_general(
+            q_ref[0].astype(mxu), k_ref[0].astype(mxu),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        key = j * tk + jax.lax.broadcasted_iota(jnp.int32, (heads, tk), 1)
+        s = jnp.where(key < length, s * (1.0 / math.sqrt(head_dim)), MASKED)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
+        vrow = j * tk + jax.lax.broadcasted_iota(jnp.int32, (tk, width), 0)
+        v = jnp.where(vrow < length, v_ref[0], 0).astype(mxu)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(mxu), v, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == (length - 1) // tk)
+    def _():
+        lane = jax.lax.broadcasted_iota(jnp.int32, (heads, width), 1)
+        first = jax.lax.broadcasted_iota(jnp.int32, (heads, width),
+                                         0) // group * head_dim
+        mine = (lane >= first) & (lane < first + head_dim)
+        o_ref[0] = jnp.where(mine, acc_ref[...] / l_ref[...],
+                             0.0).astype(o_ref.dtype)
+
+
 def decode_attention_gqa(q, k_page, v_page, positions):
     """One decode row a slot over pages of grouped K/V heads. q [B, heads,
     head_dim]; k_page, v_page [B, L, kv_heads * head_dim] with this step's
@@ -226,8 +271,17 @@ def decode_attention_gqa(q, k_page, v_page, positions):
     query head h reading K/V head h // (heads / kv_heads). Returns [B,
     heads, head_dim] in q's dtype: softmax(q k^T / sqrt(head_dim)) v over
     keys 0 .. positions[b]. Each slot's blocks past its fill are skipped
-    as `decode_attention`'s are. Compiled on a TPU (bfloat16 to the MXU),
-    interpreted elsewhere (the operands' own dtype)."""
+    as `decode_attention`'s are. A head narrower than a lane tile is read
+    by `_gqa_wide_kernel` (the query laid over the whole page row, one
+    matmul a block for every head), a head of whole tiles by `_gqa_kernel`
+    (each K/V head's lanes sliced). Compiled on a TPU (bfloat16 to the
+    MXU), interpreted elsewhere (the operands' own dtype)."""
+    return _gqa_read(q, k_page, v_page, positions,
+                     wide=q.shape[2] % 128 != 0)
+
+
+def _gqa_read(q, k_page, v_page, positions, wide):
+    """`decode_attention_gqa` in the form `wide` names."""
     interpret = not _on_tpu()
     b, heads, head_dim = q.shape
     kv_heads = k_page.shape[2] // head_dim
@@ -239,6 +293,29 @@ def decode_attention_gqa(q, k_page, v_page, positions):
         return i, jnp.minimum(j, (lens[i] - 1) // tk), 0
 
     page = pl.BlockSpec((1, tk, kv_heads * head_dim), page_block)
+    if wide:
+        width, group = kv_heads * head_dim, heads // kv_heads
+        own = (jnp.arange(width)[None, :] // head_dim
+               == jnp.arange(heads)[:, None] // group)
+        qx = jnp.where(own, jnp.tile(q, (1, 1, kv_heads)), 0).astype(q.dtype)
+        row = pl.BlockSpec((1, heads, width), lambda i, j, lens: (i, 0, 0))
+        out = pl.pallas_call(
+            functools.partial(_gqa_wide_kernel, group=group,
+                              head_dim=head_dim, tk=tk,
+                              mxu=q.dtype if interpret else jnp.bfloat16),
+            name=KERNEL,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(b, pl.cdiv(rows_page, tk)),
+                in_specs=[row, page, page], out_specs=row,
+                scratch_shapes=[pltpu.VMEM((heads, 1), jnp.float32),
+                                pltpu.VMEM((heads, 1), jnp.float32),
+                                pltpu.VMEM((heads, width), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct(qx.shape, q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+        )(lengths, qx, k_page, v_page)
+        return out.reshape(b, heads, kv_heads, head_dim).sum(2)
     query = pl.BlockSpec((1, heads, head_dim), lambda i, j, lens: (i, 0, 0))
     return pl.pallas_call(
         functools.partial(_gqa_kernel, kv_heads=kv_heads,

@@ -22,6 +22,7 @@ from .brumby import BrumbyForCausalLM, BrumbyModel  # noqa: F401
 from .ling import LingForCausalLM, LingModel  # noqa: F401
 from .dots import DotsForCausalLM, DotsModel  # noqa: F401
 from .nemotron import NemotronHForCausalLM, NemotronHModel  # noqa: F401
+from .lfm2 import Lfm2MoeForCausalLM, Lfm2MoeModel  # noqa: F401
 from .yoloe import PPYOLOE, ppyoloe_l, ppyoloe_m, ppyoloe_s  # noqa: F401
 from .small_nets import (  # noqa: F401
     AlexNet, DenseNet, GoogLeNet, InceptionV3, ShuffleNetV2, SqueezeNet,

@@ -517,6 +517,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -563,6 +566,12 @@ class Span:
         self.wall = time.time()
         self._t0 = time.perf_counter()
         return self
+
+    def set(self, **attrs):
+        """Attributes learnt inside the block (a value the block read),
+        added to the open range's stats."""
+        self.attrs.update(attrs)
+        self._annotation.set_metadata(**attrs)
 
     def __exit__(self, *exc):
         self.dur = time.perf_counter() - self._t0

@@ -65,12 +65,14 @@ def route(logits, bias, top_k, n_group, topk_group, scaling):
             scaling * w / (w.sum(-1, keepdims=True) + 1e-20), scores)
 
 
-def experts_pass(m, local, w, gate, up, down):
+def experts_pass(m, local, w, gate, up, down, reach=False):
     """sum over a row's routes of w * expert(m). m [T, hidden]; local
     [T, top_k] int32, the route's index among the experts held, or their
     count for a route that lands elsewhere; w [T, top_k] float32; gate
     (None: relu^2 experts), up [count, hidden, width]; down [count, width,
-    hidden]. Returns [T, hidden] float32."""
+    hidden]. Returns [T, hidden] float32; with `reach` also [2] int32 off
+    the layout's group sizes: the held experts some route reached, and the
+    routes that landed on held experts."""
     t, k = local.shape
     count = up.shape[0]
     tile = _gm.tile_rows_for(t * k)
@@ -87,8 +89,12 @@ def experts_pass(m, local, w, gate, up, down):
     mine = y[jnp.minimum(place, total - 1)].reshape(t, k, -1)
     # a route that lands elsewhere reads a row nobody wrote: select, never
     # multiply by zero
-    return jnp.where(here, mine.astype(jnp.float32) * w[..., None], 0.0
-                     ).sum(1)
+    out = jnp.where(here, mine.astype(jnp.float32) * w[..., None], 0.0
+                    ).sum(1)
+    if not reach:
+        return out
+    return out, jnp.stack([(tiles_of > 0).sum(dtype=jnp.int32),
+                           here.sum(dtype=jnp.int32)])
 
 
 class RoutedExperts(Layer):
@@ -142,12 +148,15 @@ class RoutedExperts(Layer):
                          self.topk_group, self.scaling)
         return run_op(f, [m, self.router, self.router_bias], "moe_route")
 
-    def forward(self, x, return_choice=False, live=None):
+    def forward(self, x, return_choice=False, live=None,
+                return_reached=False):
         """x [..., hidden] -> the held experts' part of the sum plus the
         shared expert, in x's dtype; with `return_choice` also the chosen
-        experts [..., top_k] and their biased scores s' [..., num_experts].
-        `live` (bool, x's leading dimensions): the rows whose output anyone
-        reads; the others reach no held expert, their choice is reported."""
+        experts [..., top_k] and their biased scores s' [..., num_experts];
+        with `return_reached` last the pass's [2] int32 counts
+        (`experts_pass`). `live` (bool, x's leading dimensions): the rows
+        whose output anyone reads; the others reach no held expert, their
+        choice is reported."""
         lead = list(x.shape[:-1])
         m = x.reshape([-1, x.shape[-1]])
         experts, w, scores = self.choose(m)
@@ -160,13 +169,19 @@ class RoutedExperts(Layer):
             local = jnp.where((local >= 0) & (local < count), local, count)
             if live:
                 local = jnp.where(live[0].reshape(-1, 1), local, count)
-            return experts_pass(m, local, w, gate, up, down).astype(m.dtype)
+            out = experts_pass(m, local, w, gate, up, down, return_reached)
+            if return_reached:
+                return out[0].astype(m.dtype), out[1]
+            return out.astype(m.dtype)
         masked = [] if live is None else [live]
         if masked and _monitor._ENABLED:
             _monitor.count("moe.masked_traces")
         fn = f if gate else lambda m, e, w, *rest: f(m, e, w, None, *rest)
         y = run_op(fn, [m, experts, w, *gate, self.up_proj,
                        self.down_proj] + masked, "moe_experts")
+        reached = []
+        if return_reached:
+            y, reached = y[0], [y[1]]
         if self.shared_gate is not None:
             y = y + self.shared_down(F.silu(self.shared_gate(m))
                                      * self.shared_up(m))
@@ -175,5 +190,5 @@ class RoutedExperts(Layer):
         y = y.reshape(lead + [x.shape[-1]])
         if return_choice:
             return (y, experts.reshape(lead + [self.top_k]),
-                    scores.reshape(lead + [self.num_experts]))
-        return y
+                    scores.reshape(lead + [self.num_experts]), *reached)
+        return (y, *reached) if reached else y

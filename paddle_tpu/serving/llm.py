@@ -23,7 +23,14 @@ wastes nearly all decode FLOPs and fixed batches idle between stragglers.
   first `len(init_cache(...))` are the cache, what follows is the model's
   own report of the call (`LingForCausalLM`: the experts every routed
   layer chose, a row and position), which both programs hand out after
-  the cache and the engine neither keeps nor reads.
+  the cache and the engine neither keeps nor reads. One part of it the
+  engine does read: a model that names counters in `step_counts`
+  (`Lfm2MoeForCausalLM`: the held experts a step reached) ends a decode
+  step's report with an int32 vector of one count each, which the decode
+  program hands out joined to the step's tokens, so the host reads both
+  in the one copy it makes anyway; each count is added to its counter
+  and set on that read's span (`llm.decode.read`, under the counter's
+  last name).
   The engine builds the list once with `batch = num_slots` (the pool)
   and hands ALL of it donated (`to_static(..., donate_inputs=...)`) to
   the two programs that change it, `jit_llm_decode` and
@@ -273,22 +280,29 @@ class _PrefillNet(nn.Layer):
 class _DecodeNet(nn.Layer):
     """THE decode executable: one fixed-shape step for the whole pool.
     (tokens [S], positions [S], *pool) -> (next greedy token [S], logits
-    [S, V], the updated pool). Occupancy never changes the signature: a
-    free slot has a row, at position 0, which carries no sequence and
-    whose outputs are unspecified (module docstring), so the model may
-    leave its work out (the page reads and the routed experts do)."""
+    [S, V], the updated pool, what the model reports; for a model with
+    `step_counts` last the tokens joined to its counts [S + counts]).
+    Occupancy never changes the signature: a free slot has a row, at
+    position 0, which carries no sequence and whose outputs are
+    unspecified (module docstring), so the model may leave its work out
+    (the page reads and the routed experts do)."""
 
     def __init__(self, lm):
         super().__init__()
         self.lm = lm
+        self._counted = bool(getattr(lm, "step_counts", ()))
 
     def forward(self, tokens, positions, *cache):
-        from ..ops.manipulation import cast, unsqueeze
+        from ..ops.manipulation import cast, concat, unsqueeze
         from ..ops.search import argmax
 
         last, cache = self.lm.forward_cached(unsqueeze(tokens, 1),
                                              list(cache), positions)
-        return (cast(argmax(last, axis=-1), "int32"), last, *cache)
+        nxt = cast(argmax(last, axis=-1), "int32")
+        if not self._counted:
+            return (nxt, last, *cache)
+        # the model's step counts joined to the tokens: one host read
+        return (nxt, last, *cache[:-1], concat([nxt, cache[-1]]))
 
 
 class _SlotWriteNet(nn.Layer):
@@ -361,6 +375,9 @@ class LLMEngine:
                 "state_pool")
         self._prefill = _PrefillNet(model, cfg.max_len, self._dtype)
         self._decode = _DecodeNet(model)
+        # what the model counts a decode step (module docstring)
+        self._counts: Tuple[str, ...] = tuple(
+            getattr(model, "step_counts", ()))
         self._slot_write = _SlotWriteNet()
         from ..jit import to_static
         # the programs' names in a trace: jit_llm_prefill, jit_llm_decode,
@@ -377,9 +394,11 @@ class LLMEngine:
         self._free: List[int] = list(range(cfg.num_slots))
         self._active: Dict[int, _Seq] = {}
         # the decode step dispatched and not yet read (the scheduler
-        # thread's own): its tokens, still on the device, and the (slot,
-        # sequence) rows it computes
-        self._flight: Optional[Tuple[Tensor, List[Tuple[int, _Seq]]]] = None
+        # thread's own): its tokens, still on the device, the (slot,
+        # sequence) rows it computes, and the tokens joined to the model's
+        # step counts (None where the model counts nothing)
+        self._flight: Optional[Tuple[Tensor, List[Tuple[int, _Seq]],
+                                     Optional[Tensor]]] = None
         self._pending: "collections.deque[_Seq]" = collections.deque()
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
@@ -725,7 +744,8 @@ class LLMEngine:
 
     def _dispatch(self, prev):
         """Dispatch the next decode step and return it as a flight
-        (tokens on the device, rows), or None when no sequence goes on.
+        (tokens on the device, rows, the tokens joined to the model's step
+        counts or None), or None when no sequence goes on.
         Its tokens are `prev`'s own, step n's `outs[0]` handed on unread,
         or, with nothing in flight, the host's `last_token`s: the two
         never mix, since an admission drains the pipeline first and so
@@ -790,17 +810,27 @@ class LLMEngine:
                 _monitor.count("llm.decode.kv_rows_live",
                                int(pos.sum()) + len(rows))
                 _monitor.count("llm.decode.kv_rows_pool", s * cfg.max_len)
-        return outs[0], rows
+        return outs[0], rows, (outs[-1] if self._counts else None)
 
-    def _collect(self, tokens: Tensor, rows) -> None:
+    def _collect(self, tokens: Tensor, rows,
+                 readout: Optional[Tensor] = None) -> None:
         """Read a dispatched step's tokens, emit them, evict. A row whose
         sequence ended after the dispatch (EOS, a deadline or a fault,
-        learnt since) is discarded: its token reaches no stream."""
+        learnt since) is discarded: its token reaches no stream.
+        `readout`: the tokens joined to the model's step counts, read in
+        their place."""
         cfg = self.config
         # the wait for the device and the d2h copy of the tokens: the one
         # host sync of the decode loop
-        with _monitor.span("llm.decode.read"):
-            nxt = np.asarray(tokens.numpy())  # tpu-lint: disable=host-sync
+        with _monitor.span("llm.decode.read") as read:
+            got = tokens if readout is None else readout
+            nxt = np.asarray(got.numpy())  # tpu-lint: disable=host-sync
+            if readout is not None and _monitor._ENABLED:
+                counts = nxt[cfg.num_slots:].tolist()
+                for name, n in zip(self._counts, counts):
+                    _monitor.count(name, n)
+                read.set(**{name.rsplit(".", 1)[-1]: n
+                            for name, n in zip(self._counts, counts)})
         with _monitor.span("llm.emit"):
             now = time.monotonic()
             discarded = 0

@@ -248,3 +248,30 @@ def test_ssd_phase_interprets_both_forms_and_the_grouped_read(monkeypatch):
 def test_ssd_phase_refuses_a_program_without_the_kernel():
     with pytest.raises(chip_smoke.SmokeFailure, match="tpu_custom_call"):
         chip_smoke.phase_ssd(**_TINY_SSD)
+
+
+_TINY_LFM2 = dict(slots=3, page=40, heads=8, kv_heads=4, head_dim=16,
+                  layers=(1, 2, 3), prompts=(21, 9), steps=3)
+
+
+def test_lfm2_phase_reads_both_forms_and_follows_the_reference(monkeypatch):
+    """Tiny, interpreted: the grouped read in both its forms against the
+    dense read, then a tiny LFM2 (the rehearsal's configuration) holding
+    one block of each kind through its cached path against the reference.
+    """
+    from benchmarks import harness
+    sizes = harness.load_json("tests", "configs", "tiny_lfm2.json")
+    line = chip_smoke.phase_lfm2(**_TINY_LFM2, sizes=sizes, min_kernels=0)
+    wide, sliced, model = line["check"]["paths"]
+    assert (wide["form"], sliced["form"]) == ("wide", "sliced")
+    for read in (wide, sliced):
+        assert read["read"] == [3, 40, 64]
+        assert read["rel_err"]["out"] <= chip_smoke.BF16_TOL
+        assert read["read_ms_smoke"] is None       # no time off the chip
+    assert model["model_layers"] == [1, 2, 3]
+    assert model["rel_err"] <= model["tolerance"]
+
+
+def test_lfm2_phase_refuses_a_program_without_the_kernel():
+    with pytest.raises(chip_smoke.SmokeFailure, match="tpu_custom_call"):
+        chip_smoke.phase_lfm2(**_TINY_LFM2)

@@ -612,3 +612,83 @@ def test_nemotron_prefill_program_compiles_at_the_largest_bucket(
     assert text.startswith("HloModule jit_llm_prefill")
     assert "%ssd_chunked" in text and "%gqa_prefill" in text
     assert "bf16[1,5120,256]" in text         # a page, whole
+
+
+def _lfm2_engine(slots):
+    """An LFM2-MoE model at the published widths cut to one block of each
+    kind that follows the first (published layers 1-3: a conv mixer with
+    the dense FFN, attention with experts, a conv mixer with experts), 4
+    of 64 experts held and 1024 rows of vocabulary, in an engine at the
+    cell's page and buckets."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.lfm2 import Lfm2MoeForCausalLM, Lfm2MoeModel
+    from paddle_tpu.serving import LLMConfig, LLMEngine
+
+    paddle.seed(0)
+    lm = Lfm2MoeForCausalLM(Lfm2MoeModel(
+        vocab_size=1024, layers=[1, 2, 3], held=(0, 4), dtype="bfloat16"))
+    eng = LLMEngine(lm, LLMConfig(num_slots=slots, max_len=5120,
+                                  prefill_buckets=(256, 4096),
+                                  warmup_on_start=False))
+    return lm, eng
+
+
+@pytest.mark.parametrize("wide", [True, False], ids=["wide", "sliced"])
+def test_grouped_read_compiles_at_head_64_over_the_cells_pool(sds, wide,
+                                                             monkeypatch):
+    """`decode_attention_gqa` over the LFM2 cell's pages, 128 slots of
+    [5120, 8 x 64] bfloat16, 32 query heads, in both forms: each K/V
+    head's 64 lanes sliced out of the 128-lane tile, or the query laid
+    over the whole row."""
+    da = importlib.import_module("paddle_tpu.kernels.decode_attention")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    page = sds((128, 5120, 512))
+    assert _kernels_in(functools.partial(da._gqa_read, wide=wide),
+                       sds((128, 32, 64)), page, page,
+                       sds((128,), jnp.int32)) == 1
+
+
+def test_lfm2_decode_program_updates_its_mixed_pool_in_place(sds,
+                                                             monkeypatch):
+    """The engine's decode program over an LFM2-MoE model: the convolution
+    rows and the two grouped K/V pages are donated and aliased out; the
+    page read is the grouped `decode_attention`, the experts two grouped
+    matmuls a layer; the tokens joined to the step's counts come out
+    beside them."""
+    gm = importlib.import_module("paddle_tpu.kernels.grouped_matmul")
+    da = importlib.import_module("paddle_tpu.kernels.decode_attention")
+    slots = 8
+    lm, eng = _lfm2_engine(slots)
+    assert lm.cache_tag == ("state_pool", "kv_pool", "kv_pool", "state_pool")
+    inputs = [sds((slots,), jnp.int32), sds((slots,), jnp.int32)] + [
+        sds(tuple(t.shape), t._value.dtype) for t in eng._pool]
+    pool_bytes = sum(t._value.nbytes for t in eng._pool)
+    assert pool_bytes == slots * (2 * 2 * 2048 * 2 + 2 * 5120 * 512 * 2)
+    eng._pool = []
+    donated = eng._decode.forward._donated(len(inputs))
+    compiled = _compile_net(eng._decode, inputs, donated, sds, monkeypatch)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 4
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_llm_decode")
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 + 2 * 2
+    assert f"%{da.KERNEL}" in text and text.count(f"%{gm.KERNEL}") >= 4
+    assert f"s32[{slots + 3}]" in text       # the tokens and the counts
+
+
+def test_lfm2_prefill_program_compiles_at_the_largest_bucket(sds,
+                                                             monkeypatch):
+    """One prompt at the 4096 bucket: the gated convolution's prompt form,
+    the flash prompt form over the grouped heads of 64 and the grouped
+    matmul; it returns a slot's whole pages."""
+    lm, eng = _lfm2_engine(2)
+    eng._pool = []
+    compiled = _compile_net(
+        eng._prefill, [sds((1, 4096), jnp.int32), sds((1,), jnp.int32)], (),
+        sds, monkeypatch)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_llm_prefill")
+    assert "%gqa_prefill" in text
+    assert "bf16[1,5120,512]" in text         # a page, whole
